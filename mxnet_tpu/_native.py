@@ -1,27 +1,57 @@
-"""Loader for the native runtime library (``native/mxtpu_runtime.cc``).
+"""Loader for the native libraries (``native/*.cc``): the runtime
+(dependency engine + RecordIO codec) and the image data loader.  This
+module owns the ctypes signatures.
 
-One shared object carries the dependency engine and RecordIO codec; this
-module owns the ctypes signatures.  ``lib()`` returns None when the
-library is missing and cannot be built (callers fall back to pure
-python), so the framework degrades gracefully on hosts without g++.
+``mxnet_tpu/lib/*.so`` are committed and are byte for byte what
+``make -C native`` builds from the committed sources
+(``tests/test_c_api.py`` holds them to that).  ``lib()`` and
+``dataloader_lib()`` return None when a library is missing and cannot
+be built or loaded; callers then take their pure-python path, a warning
+says so, and :func:`loaded` reports which libraries this process has.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 
 FN_T = ctypes.CFUNCTYPE(None, ctypes.c_void_p)
 
-_LIB = None
-_TRIED = False
 _LOCK = threading.Lock()
+_LIBS = {}       # file name -> CDLL, or None once loading it has failed
 
 
-def _lib_path():
-    return os.path.join(os.path.dirname(__file__), "lib",
-                        "libmxtpu_runtime.so")
+def _load(fname, declare):
+    """The declared library ``mxnet_tpu/lib/<fname>``, built from
+    ``native/`` when the file is missing, or None.  Tried once."""
+    if fname in _LIBS:
+        return _LIBS[fname]
+    with _LOCK:
+        if fname in _LIBS:
+            return _LIBS[fname]
+        here = os.path.dirname(os.path.abspath(__file__))
+        path = os.path.join(here, "lib", fname)
+        loaded = None
+        try:
+            if not os.path.exists(path):
+                subprocess.run(
+                    ["make", "-C", os.path.join(os.path.dirname(here),
+                                                "native")],
+                    check=True, capture_output=True)
+            loaded = declare(ctypes.CDLL(path))
+        except (OSError, subprocess.CalledProcessError) as e:
+            logging.getLogger("mxtpu.native").warning(
+                "native library %s unavailable (%s: %s); using the "
+                "pure-python path", fname, type(e).__name__, e)
+        _LIBS[fname] = loaded
+        return loaded
+
+
+def loaded():
+    """{library file name: bool} for every library asked for so far."""
+    return {name: lib is not None for name, lib in sorted(_LIBS.items())}
 
 
 def _declare(lib):
@@ -63,36 +93,8 @@ def _declare(lib):
 
 
 def lib():
-    """The loaded native library, or None if unavailable."""
-    global _LIB, _TRIED
-    if _LIB is not None or _TRIED:
-        return _LIB
-    with _LOCK:
-        if _LIB is not None or _TRIED:
-            return _LIB
-        path = _lib_path()
-        if not os.path.exists(path):
-            src_dir = os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "native")
-            if os.path.exists(os.path.join(src_dir, "Makefile")):
-                try:
-                    subprocess.run(["make", "-C", src_dir], check=True,
-                                   capture_output=True)
-                except Exception:
-                    pass
-        if os.path.exists(path):
-            try:
-                _LIB = _declare(ctypes.CDLL(path))
-            except OSError:
-                _LIB = None
-        _TRIED = True
-        return _LIB
-
-
-# ---------------------------------------------------------------------
-# native image data loader (native/mxtpu_dataloader.cc)
-_DL_LIB = None
-_DL_TRIED = False
+    """The loaded native runtime library, or None if unavailable."""
+    return _load("libmxtpu_runtime.so", _declare)
 
 
 def _dl_declare(lib):
@@ -123,27 +125,4 @@ def _dl_declare(lib):
 
 def dataloader_lib():
     """The native image loader library, or None if unavailable."""
-    global _DL_LIB, _DL_TRIED
-    if _DL_LIB is not None or _DL_TRIED:
-        return _DL_LIB
-    with _LOCK:
-        if _DL_LIB is not None or _DL_TRIED:
-            return _DL_LIB
-        path = os.path.join(os.path.dirname(__file__), "lib",
-                            "libmxtpu_dataloader.so")
-        if not os.path.exists(path):
-            src_dir = os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "native")
-            if os.path.exists(os.path.join(src_dir, "Makefile")):
-                try:
-                    subprocess.run(["make", "-C", src_dir], check=True,
-                                   capture_output=True)
-                except Exception:
-                    pass
-        if os.path.exists(path):
-            try:
-                _DL_LIB = _dl_declare(ctypes.CDLL(path))
-            except OSError:
-                _DL_LIB = None
-        _DL_TRIED = True
-        return _DL_LIB
+    return _load("libmxtpu_dataloader.so", _dl_declare)

@@ -140,8 +140,7 @@ def _trace_into(report, sym, ann, is_train, platform, dtype_policy,
         # Inputs keep their declared dtypes; python-scalar weak types
         # still promote toward the array dtype, so healthy f32 graphs
         # trace identically.
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(train_step if is_train else fwd_only)(
                 args, aux)
     except Exception as e:  # noqa: BLE001 — surface, don't crash the lint
@@ -181,7 +180,7 @@ def step_invar_metadata(trainer, closed, args):
         else _STEP_ARG_LABELS_SENTINEL
     jaxpr, donated, labels, shardings = closed, None, None, None
     eqns = closed.jaxpr.eqns
-    if len(eqns) == 1 and eqns[0].primitive.name == "pjit":
+    if len(eqns) == 1 and eqns[0].primitive.name == "jit":
         jaxpr = eqns[0].params["jaxpr"]
         donated = eqns[0].params.get("donated_invars")
         leaves = jax.tree_util.tree_flatten_with_path(args)[0]

@@ -416,7 +416,7 @@ def extract_liveness(jaxpr, axis_sizes: Optional[Dict[str, int]] = None,
     # unwrap a single top-level pjit (Trainer.step_jaxpr's shape) so
     # the donation metadata lines up with the invars actually walked
     if donated_invars is None and len(jx.eqns) == 1 \
-            and jx.eqns[0].primitive.name == "pjit":
+            and jx.eqns[0].primitive.name == "jit":
         inner = jx.eqns[0].params.get("jaxpr")
         if inner is not None:
             jx = getattr(inner, "jaxpr", inner)

@@ -268,15 +268,14 @@ def bitflip(value, rank: int, bit: int = 12, mesh=None, spec=None,
     if mesh is None or int(dict(mesh.shape).get(axis, 1)) <= 1:
         return jax.jit(_flip)(value)
 
-    from .parallel.mesh import shard_map
     spec = spec if spec is not None else PartitionSpec()
 
     def local(x):
         r = lax.axis_index(axis)
         return jnp.where(r == int(rank), _flip(x), x)
 
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=spec,
-                             out_specs=spec, check_rep=False))(value)
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False))(value)
 
 
 def match_leaf(pattern: str, paths: Sequence[str]) -> Optional[str]:

@@ -42,10 +42,7 @@ __all__ = ["NDArray", "empty", "zeros", "ones", "full", "array", "arange",
 
 def waitall():
     """Block until all async computation finishes (ref ``ndarray.py:95``)."""
-    try:
-        jax.effects_barrier()
-    except Exception:
-        pass
+    jax.effects_barrier()
     (jnp.zeros(()) + 0).block_until_ready()
 
 

@@ -161,7 +161,7 @@ def max_pool_argmax(x, window, strides, padding):
 # ----------------------------------------------------------------------
 # BatchNorm: shared single-pass statistics + fused closed-form backward
 #
-# Cancellation guard (ADVICE round 5, nn.py single-pass variance): the
+# Cancellation guard (nn.py single-pass variance): the
 # shifted-moment form var = E[(x-c)²] - E[x-c]² centered on the running
 # mean c cancels catastrophically when the batch mean sits far from c
 # (first steps after init, distribution shift).  The guard is one scalar

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from .mesh import shard_map as _shard_map
 
 __all__ = ["global_allreduce", "barrier", "psum_over_mesh",
            "broadcast_from_rank0", "lowp_allreduce", "lowp_comm_bytes",
@@ -71,7 +70,7 @@ def global_allreduce(value):
         return jax.lax.psum(x, axis_name="data")
 
     f = jax.jit(
-        _shard_map(_sum, mesh=mesh,
+        jax.shard_map(_sum, mesh=mesh,
                       in_specs=PartitionSpec(*(["data"] + [None] * (value.ndim - 1))),
                       out_specs=PartitionSpec(*([None] * value.ndim))))
     # value is host-local; make it a global sharded array first

@@ -21,37 +21,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 __all__ = ["Mesh", "NamedSharding", "PartitionSpec", "get_mesh",
            "make_mesh", "current_mesh", "data_parallel_mesh",
            "global_data_parallel_mesh", "batch_sharding", "replicated",
-           "zero_spec", "shard_map"]
+           "zero_spec"]
 
-
-def _resolve_shard_map():
-    """``jax.shard_map`` moved (experimental -> top level) and renamed
-    its replication-check kwarg (``check_rep`` -> ``check_vma``) across
-    jax releases; resolve whichever this jax exposes once, here, and
-    translate the kwarg, so every manual-sharding caller (ring
-    attention, pipeline, the bf16 grad-comm backward, global_allreduce)
-    survives both moves."""
-    import inspect
-    sm = getattr(jax, "shard_map", None)
-    if not callable(sm):
-        from jax.experimental.shard_map import shard_map as sm  # noqa: F811
-    try:
-        accepted = set(inspect.signature(sm).parameters)
-    except (TypeError, ValueError):      # pragma: no cover - exotic wrapper
-        return sm
-
-    def compat(*args, **kwargs):
-        for ours, theirs in (("check_vma", "check_rep"),
-                             ("check_rep", "check_vma")):
-            if ours in kwargs and ours not in accepted \
-                    and theirs in accepted:
-                kwargs[theirs] = kwargs.pop(ours)
-        return sm(*args, **kwargs)
-
-    return compat
-
-
-shard_map = _resolve_shard_map()
 
 _LOCAL = threading.local()
 
@@ -126,12 +97,8 @@ def get_mesh(num_devices: Optional[int] = None) -> Mesh:
 
 
 def current_mesh() -> Optional[Mesh]:
-    m = getattr(_LOCAL, "mesh", None)
-    if m is not None:
-        return m
-    # also honor meshes entered via jax's own context manager
-    env = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
-    return None if env is None or not getattr(env, "shape", None) else None
+    """The mesh of the innermost ``with use_mesh(m):`` scope, or None."""
+    return getattr(_LOCAL, "mesh", None)
 
 
 class _MeshScope:

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .mesh import shard_map as _shard_map
 from jax.sharding import PartitionSpec
 
 from .. import envknobs as _envknobs
@@ -212,7 +211,7 @@ def pipeline_apply(stage_fn, stage_params, inputs, mesh, axis="pipe",
                          outs)
         return outs[None]
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(param_spec, PartitionSpec()),
         out_specs=PartitionSpec(axis),

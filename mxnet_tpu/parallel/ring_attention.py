@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from .. import envknobs as _envknobs
-from .mesh import shard_map as _shard_map
 
 __all__ = ["ring_attention", "ring_attention_sharded", "attention_reference"]
 
@@ -124,7 +123,7 @@ def ring_attention_sharded(q, k, v, mesh, axis="seq", causal=False,
     """Apply ring attention to globally-shaped ``[b, t, h, d]`` arrays
     sharded (or shardable) over ``mesh[axis]`` on the time dimension."""
     spec = PartitionSpec(None, axis, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=axis, causal=causal, scale=scale,
                 skip_masked=skip_masked),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -54,13 +54,13 @@ from .base import MXNetError
 from . import _tsan
 from . import obs as _obs
 
-__all__ = ["CompiledProgram", "jit", "cache_dir", "cache_stats",
-           "reset_stats", "stats_delta", "entry_path", "symbol_digest",
-           "PROGRAM_CACHE_VERSION"]
+__all__ = ["CompiledProgram", "jit", "cache_dir", "place_compile_cache",
+           "cache_stats", "reset_stats", "stats_delta", "entry_path",
+           "symbol_digest", "PROGRAM_CACHE_VERSION"]
 
 # bump when the on-disk entry layout changes: older entries become
 # stale misses, never parse errors
-PROGRAM_CACHE_VERSION = 1
+PROGRAM_CACHE_VERSION = 2
 
 # hit/miss/stale accounting in the process-wide metrics registry —
 # always on (the registry is), scraped via obs.snapshot() and reported
@@ -86,6 +86,35 @@ def cache_dir() -> Optional[str]:
     return d
 
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def place_compile_cache(programs: bool = False) -> str:
+    """Keep compiled code between runs; every entry script calls this
+    before its first compile.  Returns the root of JAX's cache.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its
+    persistent cache there and nothing is set here.  Where it is not,
+    the cache is ``<checkout>/.jax_cache``: a fixed path, because the
+    path is part of what a cache entry is found by.
+
+    ``programs=True`` also arms ``MXTPU_PROGRAM_CACHE`` (unless the
+    caller's environment already did) at ``<checkout>/.jax_cache/
+    programs``.  It stays under the checkout even where JAX's cache is
+    placed from outside: the two caches hold every executable twice,
+    and the chip tool drops a placed cache that passes 256 MiB
+    (``chip_smoke.py`` left 353 MB with both under one root)."""
+    local = os.path.join(_CHECKOUT, ".jax_cache")
+    root = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not root:
+        root = local
+        jax.config.update("jax_compilation_cache_dir", root)
+    if programs:
+        os.environ.setdefault("MXTPU_PROGRAM_CACHE",
+                              os.path.join(local, "programs"))
+    return root
+
+
 def _jax_version() -> str:
     """Part of every cache key: an executable serialized by one
     jax/jaxlib must never execute under another (monkeypatched by the
@@ -96,10 +125,9 @@ def _jax_version() -> str:
 
 
 def _backend() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:               # noqa: BLE001 — key must not raise
-        return "?"
+    """Part of every cache key (monkeypatched by the invalidation
+    tests).  A backend that cannot be asked is an error, not a key."""
+    return jax.default_backend()
 
 
 def symbol_digest(symbol) -> str:
@@ -298,11 +326,21 @@ class CompiledProgram:
                     or len(payload) != meta["size"]:
                 raise ValueError("payload CRC/size mismatch")
             from jax.experimental import serialize_executable as _se
+            # onto the devices it was compiled for: left to itself the
+            # loader binds the executable to every device of the default
+            # backend.  On a host with more devices than the program
+            # spans (four chips, the eight-device test mesh) the call
+            # then fails, and a CPU program of a TPU process fails to
+            # parse
+            platform, ids = entry["devices"]
+            by_id = {d.id: d for d in jax.devices(platform)}
+            devices = [by_id[i] for i in ids]
             with _obs.span("compile.load",
                            attrs={"kind": self.kind,
                                   "bytes": len(payload)}):
-                comp = _se.deserialize_and_load(payload, entry["in_tree"],
-                                                entry["out_tree"])
+                comp = _se.deserialize_and_load(
+                    payload, entry["in_tree"], entry["out_tree"],
+                    backend=platform, execution_devices=devices)
         except Exception as e:      # noqa: BLE001 — stale = miss
             _STALE.inc()
             with self._lock:
@@ -334,9 +372,12 @@ class CompiledProgram:
                 self._entry_ident(sig), default=str)),
                 "crc32": zlib.crc32(payload) & 0xFFFFFFFF,
                 "size": len(payload)}
+            devs = compiled._executable._unloaded_executable.device_list
+            devices = (devs[0].platform, [d.id for d in devs])
             blob = pickle.dumps({"meta": meta, "payload": payload,
                                  "in_tree": in_tree,
-                                 "out_tree": out_tree})
+                                 "out_tree": out_tree,
+                                 "devices": devices})
             os.makedirs(directory, exist_ok=True)
             path = os.path.join(directory,
                                 self._entry_key(sig) + ".mxprog")

@@ -300,6 +300,31 @@ def test_wrapper_generator_is_current(tmp_path):
         "tools/gen_cpp_wrappers.py"
 
 
+def test_committed_native_libraries_are_current(tmp_path):
+    """``mxnet_tpu/lib/*.so`` are committed build products: they must be
+    byte for byte what ``make -C native`` builds from the committed
+    sources with this toolchain (an edited ``.cc`` without a rebuild
+    would otherwise load as stale native code)."""
+    import shutil
+    import subprocess
+    if not (shutil.which("make") and shutil.which("g++")):
+        pytest.skip("no make/g++ here")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = {"OUT": "libmxtpu_runtime.so", "CAPI": "libmxtpu_c_api.so",
+             "LOADER": "libmxtpu_dataloader.so"}
+    subprocess.run(
+        ["make", "-C", os.path.join(root, "native")]
+        + ["%s=%s" % (var, tmp_path / name) for var, name in names.items()],
+        check=True, capture_output=True)
+    for name in names.values():
+        with open(tmp_path / name, "rb") as f:
+            fresh = f.read()
+        with open(os.path.join(root, "mxnet_tpu", "lib", name), "rb") as f:
+            assert f.read() == fresh, \
+                "mxnet_tpu/lib/%s is not what native/ builds; run " \
+                "`make -C native` and commit the result" % name
+
+
 def _write_synth_mnist(tmp_path, n=200):
     """MNIST-format files with a learnable rule: the lit quadrant block
     encodes the class (4 classes, labels 0-3)."""

@@ -26,7 +26,7 @@ from mxnet_tpu.analysis import comm_passes
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.parallel.collectives import (collective_wire_bytes,
                                             lowp_comm_bytes)
-from mxnet_tpu.parallel.mesh import shard_map
+from jax import shard_map
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -101,7 +101,7 @@ def test_scan_trip_count_multiplies_wire_bytes():
         return out
 
     fn = shard_map(per_device, mesh=mesh, in_specs=P(),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     jaxpr = jax.make_jaxpr(fn)(
         jax.ShapeDtypeStruct((5, 8, 4), np.float32))
     plan = comm_passes.extract_comm_plan(jaxpr, {"pipe": 2})
@@ -156,7 +156,7 @@ def test_f32_wire_fires_on_f32_data_collective():
         with jax.named_scope("grads"):
             return shard_map(lambda v: lax.psum(v, "data"), mesh=mesh,
                              in_specs=P("data"), out_specs=P(),
-                             check_rep=False)(x)
+                             check_vma=False)(x)
 
     jaxpr = jax.make_jaxpr(prog)(big)
     rep = comm_passes.lint_comm(
@@ -171,7 +171,7 @@ def test_f32_wire_fires_on_f32_data_collective():
         return shard_map(
             lambda v: lax.psum(v.astype(jnp.bfloat16), "data"),
             mesh=mesh, in_specs=P("data"), out_specs=P(),
-            check_rep=False)(x)
+            check_vma=False)(x)
     rep = comm_passes.lint_comm(
         jax.make_jaxpr(prog16)(big), model="crafted",
         axis_sizes={"data": 2}, config={"grad_dtype": "bf16"})
@@ -199,7 +199,7 @@ def _rs_ag_prog(mesh, keep_shard):
 
     out_spec = P("data") if keep_shard else P()
     return shard_map(local, mesh=mesh, in_specs=P(), out_specs=out_spec,
-                     check_rep=False)
+                     check_vma=False)
 
 
 def test_resharding_thrash_fires_on_gather_after_scatter():

@@ -166,9 +166,8 @@ def test_jaxpr_passes_see_inside_shard_map_with_provenance():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental import enable_x64
     from mxnet_tpu.parallel import make_mesh
-    from mxnet_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     mesh = make_mesh({"data": 2}, jax.devices()[:2])
 
@@ -178,10 +177,10 @@ def test_jaxpr_passes_see_inside_shard_map_with_provenance():
     def prog(x):
         with jax.named_scope("commlayer"):
             y = shard_map(body, mesh=mesh, in_specs=P("data"),
-                          out_specs=P("data"), check_rep=False)(x)
+                          out_specs=P("data"), check_vma=False)(x)
         return y.astype(jnp.float32)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(prog)(
             jax.ShapeDtypeStruct((4, 8), np.float32))
     out = list(analysis.get_pass("f64-widening").run(
@@ -240,7 +239,7 @@ def test_donation_pass_flags_undonated_state():
     def ctx_for(fn):
         closed = jax.make_jaxpr(fn)(*args)
         eqn = closed.jaxpr.eqns[0]
-        assert eqn.primitive.name == "pjit"
+        assert eqn.primitive.name == "jit"
         return analysis.PassContext(
             jaxpr=eqn.params["jaxpr"],
             donated_invars=eqn.params["donated_invars"],

@@ -146,6 +146,23 @@ def test_wait_and_context():
     assert a.context.device_type in ("cpu", "tpu", "gpu")
 
 
+@pytest.mark.parametrize("cpu_chosen", [True, False])
+def test_tpu_context_never_hides_the_cpu(monkeypatch, cpu_chosen):
+    """A ``tpu`` context is a host device only where the CPU was chosen
+    outright (as conftest does); otherwise no accelerator is an error,
+    not a quiet CPU."""
+    from mxnet_tpu import base
+    monkeypatch.setattr(base, "_cpu_chosen", lambda: cpu_chosen)
+    if cpu_chosen:
+        assert mx.tpu().jax_device().platform == "cpu"
+        assert mx.gpu(1).jax_device().platform == "cpu"
+    else:
+        with pytest.raises(mx.MXNetError, match="no accelerator"):
+            mx.tpu().jax_device()
+        assert base.default_context() == mx.cpu()
+    assert mx.cpu().jax_device().platform == "cpu"
+
+
 def test_truthiness_raises():
     a = mx.nd.ones((2,))
     with pytest.raises(mx.MXNetError):

@@ -312,3 +312,37 @@ def test_second_process_compiles_nothing(tmp_path):
     assert warm["loads"] == cold["persists"]
     assert warm["warmup_loaded"] > 0      # server skipped its warmups
     assert warm["fingerprints"] == cold["fingerprints"]
+
+
+# ----------------------------------------------------------------------
+# the compile cache that can be placed from outside
+@pytest.mark.parametrize("placed", ["/some/where/else", None])
+def test_place_compile_cache(monkeypatch, placed):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's cache is there and the code
+    sets no other.  Unset: <checkout>/.jax_cache, never a temporary
+    name.  The program cache, when armed, is a fixed directory under
+    the checkout."""
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    local = os.path.join(root, ".jax_cache")
+    monkeypatch.delenv("MXTPU_PROGRAM_CACHE", raising=False)
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert program.place_compile_cache() == (placed or local)
+        assert "MXTPU_PROGRAM_CACHE" not in os.environ
+        assert jax.config.jax_compilation_cache_dir == \
+            (before if placed else local)
+        assert program.place_compile_cache(programs=True) == \
+            (placed or local)
+        assert program.cache_dir() == os.path.join(local, "programs")
+        # a program cache armed from outside stays where it was put
+        monkeypatch.setenv("MXTPU_PROGRAM_CACHE", "/armed/outside")
+        program.place_compile_cache(programs=True)
+        assert program.cache_dir() == "/armed/outside"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        os.environ.pop("MXTPU_PROGRAM_CACHE", None)

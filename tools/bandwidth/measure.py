@@ -82,15 +82,14 @@ def main():
     print("payload: %d arrays, %.1f MB, %d devices"
           % (len(shapes), total_bytes / 2 ** 20, n))
 
-    from jax.experimental.shard_map import shard_map
     specs = tuple(P() for _ in shapes)
 
     @jax.jit
     def allreduce(*grads):
         def body(*gs):
             return tuple(jax.lax.psum(g, "data") for g in gs)
-        return shard_map(body, mesh=mesh, in_specs=specs,
-                         out_specs=specs)(*grads)
+        return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                             out_specs=specs)(*grads)
 
     rng = np.random.RandomState(0)
     grads = tuple(jnp.asarray(rng.normal(0, 1, s).astype(dtype))

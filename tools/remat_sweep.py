@@ -2,8 +2,9 @@
 """Sweep rematerialization policies on the bench model and record the
 throughput + XLA cost-model accounting for each.
 
-The fused ResNet-50 step is HBM-bandwidth-bound (~37% MFU with the MXU
-two-thirds idle — ROOFLINE.json / BENCH_r03): remat trades free MXU
+The fused ResNet-50 step was HBM-bandwidth-bound in the older record
+from before PR 1 (~37% MFU with the MXU two-thirds idle — ROOFLINE.json):
+remat trades free MXU
 flops for scarce HBM bytes by saving fewer residuals and recomputing
 the rest inside backward.  This tool measures each policy end-to-end on
 the real chip and writes ``REMAT_SWEEP.json`` at the repo root — the
@@ -90,6 +91,8 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=256)
     args = ap.parse_args(argv)
 
+    from mxnet_tpu import program
+    program.place_compile_cache()
     rows = []
     for pol in args.policies.split(","):
         print("=== policy %s ===" % pol, file=sys.stderr)

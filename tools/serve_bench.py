@@ -983,6 +983,8 @@ def main(argv=None):
                          "(the INFER_BENCH 'fleet' section)")
     args = ap.parse_args(argv)
 
+    from mxnet_tpu import program
+    program.place_compile_cache()
     buckets = [int(b) for b in args.buckets.split(",")] \
         if args.buckets else None
     section = serving_probe(
