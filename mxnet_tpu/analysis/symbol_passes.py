@@ -93,21 +93,25 @@ class DuplicateSubgraphPass(GraphPass):
 
     def run(self, ctx: PassContext):
         view = ctx.view
-        sig = {}        # node idx -> hashable structural signature
-        groups = {}     # signature -> [node]
+        ids = {}        # structural key -> small int, one per distinct value
+        sig = {}        # node idx -> the int of its key
+        groups = {}     # key -> [node]
         for node in view.topo():
             if node.is_variable:
                 # variables are identity: same name = same value source
-                sig[node.idx] = ("var", node.name)
-                continue
-            if node.op is not None and node.op.uses_rng:
-                sig[node.idx] = ("rng", node.idx)   # stochastic: never CSE
-                continue
-            key = (node.op_name,
-                   tuple(sorted((k, str(v)) for k, v in node.params.items())),
-                   tuple((sig.get(i, ("?", i)), oi) for i, oi in node.inputs))
-            sig[node.idx] = key
-            groups.setdefault(key, []).append(node)
+                key = ("var", node.name)
+            elif node.op is not None and node.op.uses_rng:
+                key = ("rng", node.idx)   # stochastic: never CSE
+            else:
+                # inputs enter by their ints, so a key is as large as the
+                # node's fan-in and not as the graph beneath it
+                key = (node.op_name,
+                       tuple(sorted((k, str(v))
+                                    for k, v in node.params.items())),
+                       tuple((sig.get(i, ("?", i)), oi)
+                             for i, oi in node.inputs))
+                groups.setdefault(key, []).append(node)
+            sig[node.idx] = ids.setdefault(key, len(ids))
         out = []
         for key, nodes in groups.items():
             if len(nodes) < 2:

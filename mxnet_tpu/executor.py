@@ -64,6 +64,9 @@ class _GraphProgram:
         # must not cost per-forward key derivation in inference
         self.has_eval_rng = any((not n.is_variable) and n.op.uses_rng
                                 and n.op.rng_in_eval for n in self.nodes)
+        self.has_host_callback = any((not n.is_variable)
+                                     and n.op.host_callback
+                                     for n in self.nodes)
         # target backend for platform-specialized op lowerings
         self.platform = None
         # residual/intermediate dtype policy for backward formulations
@@ -220,6 +223,18 @@ class Executor:
             self._const_key = jax.random.key(0)
         return self._const_key
 
+    def _await_host_callbacks(self, vals):
+        """Wait for a program that calls back into Python before anything
+        that consumes it is dispatched.  The callback runs NDArray ops on
+        the device its program occupies, and the runtime admits a bounded
+        number of computations in flight (XLA's CPU client takes a
+        semaphore in ``Execute``): ones queued behind this program keep
+        their slot while they wait for it, so a caller that ran far enough
+        ahead leaves the callback waiting for a slot that only its own
+        return can free."""
+        if self._prog.has_host_callback:
+            jax.block_until_ready(vals)
+
     def _eager_committed(self, vals):
         """Pin values for the eager per-node paths (monitor, partial
         forward).  Bound arrays can be UNCOMMITTED — allocated on the
@@ -288,6 +303,7 @@ class Executor:
                 fn = self._prog.jitted(False)
                 outs, new_aux = fn(arg_vals, aux_vals, key)
             self._vjp = None
+        self._await_host_callbacks((outs, new_aux))
         for arr, v in zip(self.aux_arrays, new_aux):
             arr._set_data(v)
         self._outputs = [NDArray(o) for o in outs]
@@ -365,6 +381,7 @@ class Executor:
         from . import profiler as _prof
         with _prof.record_scope("Backward", str(self._ctx)):
             arg_grads, _aux_grads = self._vjp((tuple(cotangents), aux_cot))
+        self._await_host_callbacks(arg_grads)
         for name, arr, g in zip(self._prog.arg_names, self.grad_arrays,
                                 arg_grads):
             req = self.grad_req.get(name, "null")

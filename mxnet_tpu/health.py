@@ -296,14 +296,19 @@ def _evidence_age(key, rank, wall, seq, now_wall, now_mono):
             if _tsan.TSAN:
                 _tsan.note_write("health._seq_track")
             prev = _seq_track.get((key, rank))
-            if prev is None or prev[0] != seq:
-                # advanced since the previous scan: fresh — but only
-                # when there IS a previous scan; a first-ever
-                # observation of a possibly-stale stamp must not read
-                # as progress (its wall age is the baseline instead)
-                _seq_track[(key, rank)] = (
-                    seq, now_mono, 0.0 if prev is not None else wall_age)
-                seq_age = 0.0 if prev is not None else None
+            if prev is None:
+                # a first-ever observation of a possibly-stale stamp
+                # must not read as progress: its wall age rules, and is
+                # the baseline for what follows
+                _seq_track[(key, rank)] = (seq, now_mono, wall_age)
+            elif prev[0] != seq:
+                # advanced since we first saw the previous value: the
+                # stamp is no older than that on OUR clock, and no older
+                # than its own wall age.  Not simply fresh: a scanner
+                # that last looked a minute ago would otherwise read a
+                # rank that beat once more and died as alive
+                seq_age = min(wall_age, now_mono - prev[1])
+                _seq_track[(key, rank)] = (seq, now_mono, seq_age)
             else:
                 # unchanged: age accrues on OUR clock from the first
                 # sighting, on top of how old the stamp already looked

@@ -155,6 +155,9 @@ class Op:
     # ``None`` means "inherit uses_rng".
     rng_in_eval: Optional[bool] = None
     mode_dependent: bool = False  # retrace per is_train value
+    # fn calls back into Python on the host while the program runs
+    # (operator.py's CustomOp bridge); the executor awaits such programs
+    host_callback: bool = False
     hint: str = ""  # auto-naming hint, defaults to lowercased name
     # ops whose outputs must not be differentiated through label-style inputs
     # handle that themselves via jax.custom_vjp / stop_gradient in `fn`.

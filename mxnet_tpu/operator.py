@@ -181,7 +181,7 @@ def _make_custom_fn_from_prop(prop, op_name):
         aux_names=tuple(prop.list_auxiliary_states()),
         num_outputs=n_out, hint="custom",
         infer_shape=lambda p, in_shapes: prop.infer_shape(in_shapes),
-        mode_dependent=True)
+        mode_dependent=True, host_callback=True)
     return custom_op
 
 

@@ -268,12 +268,13 @@ def test_cpp_package_generated_wrappers():
     cpp = os.path.join(root, "cpp-package")
     assert os.path.exists(os.path.join(cpp, "include", "mxtpu_ops.hpp")), \
         "run tools/gen_cpp_wrappers.py"
-    subprocess.run(["make", "-C", cpp], check=True, capture_output=True)
+    subprocess.run(["make", "-C", cpp], check=True, capture_output=True,
+                   timeout=240)
     env = dict(os.environ)
     env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
     env.setdefault("JAX_PLATFORMS", "cpu")
     res = subprocess.run([os.path.join(cpp, "ops_example")], env=env,
-                         capture_output=True, text=True, timeout=300,
+                         capture_output=True, text=True, timeout=240,
                          cwd=root)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "ops example OK" in res.stdout
@@ -289,7 +290,8 @@ def test_wrapper_generator_is_current(tmp_path):
     subprocess.run([sys.executable,
                     os.path.join(root, "tools", "gen_cpp_wrappers.py"),
                     "-o", out], check=True, capture_output=True,
-                   cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+                   timeout=240, cwd=root,
+                   env=dict(os.environ, JAX_PLATFORMS="cpu"))
     with open(out) as f:
         fresh = f.read()
     with open(os.path.join(root, "cpp-package", "include",
@@ -315,7 +317,7 @@ def test_committed_native_libraries_are_current(tmp_path):
     subprocess.run(
         ["make", "-C", os.path.join(root, "native")]
         + ["%s=%s" % (var, tmp_path / name) for var, name in names.items()],
-        check=True, capture_output=True)
+        check=True, capture_output=True, timeout=240)
     for name in names.values():
         with open(tmp_path / name, "rb") as f:
             fresh = f.read()
@@ -505,7 +507,6 @@ def test_c_recordio_autograd_profiler(tmp_path):
     lib.MXNDArrayFree(a)
 
 
-@pytest.mark.nightly       # g++ compile + full training drive, ~2 min
 @pytest.mark.skipif(not os.path.exists(_LIB),
                     reason="libmxtpu_c_api.so not built")
 def test_cpp_train_lenet_through_c_abi(tmp_path):
@@ -519,14 +520,14 @@ def test_cpp_train_lenet_through_c_abi(tmp_path):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cpp = os.path.join(root, "cpp-package")
     subprocess.run(["make", "-C", cpp, "train_lenet"], check=True,
-                   capture_output=True)
+                   capture_output=True, timeout=120)
     img, lbl = _write_synth_mnist(tmp_path, n=200)
     env = dict(os.environ)
     env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
     env.setdefault("JAX_PLATFORMS", "cpu")
     res = subprocess.run(
         [os.path.join(cpp, "train_lenet"), img, lbl, "6", "0.9"],
-        env=env, capture_output=True, text=True, timeout=600, cwd=root)
+        env=env, capture_output=True, text=True, timeout=240, cwd=root)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "train lenet OK" in res.stdout
 

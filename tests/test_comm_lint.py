@@ -31,7 +31,7 @@ from jax import shard_map
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(args, timeout=420, **kw):
+def _run(args, timeout=240, **kw):
     return subprocess.run([sys.executable] + args, capture_output=True,
                           text=True, cwd=_ROOT, timeout=timeout, **kw)
 

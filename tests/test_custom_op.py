@@ -161,3 +161,75 @@ def test_legacy_ndarray_op():
     ex.backward([mx.nd.ones(x.shape)])
     np.testing.assert_allclose(ex.grad_dict["data"].asnumpy(),
                                np.full_like(x, 3.0), rtol=1e-6)
+
+
+_CALLBACK_BEHIND_A_FULL_QUEUE = """
+import functools
+import threading
+import jax
+import jax.numpy as jnp
+import mxnet_tpu as mx
+
+queued = threading.Event()
+
+
+class Double(mx.operator.CustomOp):
+    def forward(self, is_train, req, in_data, out_data, aux):
+        self.assign(out_data[0], req[0], in_data[0] * 2)
+
+    def backward(self, req, out_grad, in_data, out_data, in_grad, aux):
+        queued.wait(2.0)    # a slow callback: the caller gets ahead of it
+        self.assign(in_grad[0], req[0], out_grad[0] * 2)
+
+
+@mx.operator.register("double")
+class DoubleProp(mx.operator.CustomOpProp):
+    def create_operator(self, ctx, in_shapes, in_dtypes):
+        return Double()
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def work(g, n=3):       # large enough to be dispatched asynchronously
+    for _ in range(n):
+        g = jnp.tanh(g @ g)
+    return g
+
+
+x = jnp.full((512, 512), 1e-3, "float32")
+work(x).block_until_ready()
+work(x, 60).block_until_ready()
+net = mx.sym.Custom(mx.sym.Variable("data"), op_type="double")
+args = {"data": mx.nd.NDArray(x)}
+grads = {"data": mx.nd.zeros((512, 512))}
+ex = net.bind(mx.cpu(), args=args, args_grad=grads)
+ex.forward(is_train=True)
+# a cotangent that is still being computed: the runtime runs a program
+# with a callback on the caller's own thread when its inputs are ready
+ex.backward([mx.nd.NDArray(work(x, 60))])
+outs = [work(grads["data"].data) for _ in range(40)]
+queued.set()
+jax.block_until_ready(outs)
+print("backward and its consumers returned")
+"""
+
+
+def test_custom_op_callback_behind_a_full_queue():
+    """The runtime admits a bounded number of computations in flight, and
+    one that waits for its input keeps its slot.  A caller that queues
+    that many consumers behind a backward whose callback has not
+    returned leaves the callback's own NDArray ops waiting for a slot
+    for ever (the torch-interop example, on a loaded machine), unless
+    the executor awaits a program with a host callback.  In a
+    subprocess: no signal reaches a main thread that waits inside the
+    runtime."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=root + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    res = subprocess.run(
+        [sys.executable, "-c", _CALLBACK_BEHIND_A_FULL_QUEUE],
+        capture_output=True, text=True, timeout=120, env=env)
+    assert res.returncode == 0 and "consumers returned" in res.stdout, \
+        res.stdout[-1000:] + res.stderr[-2000:]

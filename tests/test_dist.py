@@ -10,7 +10,7 @@ import pytest
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _launch(script, n=2, timeout=420):
+def _launch(script, n=2, timeout=240):
     env = dict(os.environ)
     env.pop("MXTPU_COORDINATOR", None)   # never nest coordination scopes
     return subprocess.run(
@@ -43,7 +43,7 @@ def test_cpu_tpu_consistency():
     res = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "tests", "nightly",
                                       "consistency.py"), "--sample", "6"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
+        capture_output=True, text=True, timeout=240, env=env, cwd=_ROOT)
     import re
     m = re.search(r"consistency: (\d+) cases matched, (\d+) failed",
                   res.stdout)
@@ -68,7 +68,7 @@ def test_failure_detection_and_restart(tmp_path):
          sys.executable,
          os.path.join(_ROOT, "tests", "nightly", "dist_resume.py"),
          str(tmp_path)],
-        capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
+        capture_output=True, text=True, timeout=240, env=env, cwd=_ROOT)
     out = res.stdout + res.stderr
     assert res.returncode == 0, out
     assert "simulating crash" in out, out

@@ -142,7 +142,8 @@ def test_nonpublisher_waits_for_published_epoch(tmp_path):
     assert err.value.membership.epoch == 2
     assert err.value.membership.world == [0, 1]
     assert 0.3 < time.monotonic() - t0 < 5.0   # waited for the publish
-    timer.join()
+    timer.join(timeout=30)
+    assert not timer.is_alive()
     h0.stop()
     c1.close()
 
@@ -314,8 +315,12 @@ def test_kvstore_without_optimizer_still_refuses(tmp_path):
 # ======================================================================
 # the launcher-driven e2e: n=2 -> host_dead -> shrink to n=1 ->
 # auto-resume -> bit-identical to a fresh 1-process replay from the
-# same checkpoint.  Subprocess-heavy: excluded from the tier-1 window
-# (slow) and run as its own hard-timeout fast-tier CI stage.
+# same checkpoint.  19 s alone, so not slow by the marker's measure; it
+# keeps the mark it had because its last assertion fails on this
+# installation (fc1_weight differs in the last bit between the relaunched
+# survivor and the replay), at the commit before PR 27 as after it, and
+# tier-1 should not gain a known failure by a change of markers.
+# ci/run_tests.sh runs the whole file, unfiltered, in a stage of its own.
 @pytest.mark.slow
 def test_elastic_shrink_resume_e2e(tmp_path):
     workdir = str(tmp_path / "work")
@@ -331,7 +336,7 @@ def test_elastic_shrink_resume_e2e(tmp_path):
          sys.executable,
          os.path.join(_ROOT, "tests", "nightly", "elastic_train.py"),
          workdir],
-        capture_output=True, text=True, timeout=420, env=env, cwd=_ROOT)
+        capture_output=True, text=True, timeout=240, env=env, cwd=_ROOT)
     out = res.stdout + res.stderr
     assert res.returncode == 0, out
     # round 1: the shrink was detected and published
@@ -357,7 +362,7 @@ def test_elastic_shrink_resume_e2e(tmp_path):
         [sys.executable,
          os.path.join(_ROOT, "tests", "nightly", "elastic_train.py"),
          workdir, "--replay", str(resumed_epoch)],
-        capture_output=True, text=True, timeout=300, env=env, cwd=_ROOT)
+        capture_output=True, text=True, timeout=240, env=env, cwd=_ROOT)
     assert res.returncode == 0, res.stdout + res.stderr
     got = np.load(os.path.join(workdir, "final.npz"))
     ref = np.load(os.path.join(workdir, "replay-final.npz"))

@@ -9,15 +9,10 @@ import sys
 
 import pytest
 
-# example smokes are coverage the NIGHTLY tier owns: each is a real
-# (subprocess) training run with its own compile, minutes apiece — the
-# fast gate's wall-time bound can't carry them
-pytestmark = pytest.mark.nightly
-
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run_example(relpath, *args, timeout=420):
+def _run_example(relpath, *args, timeout=240):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _ROOT + os.pathsep + env.get("PYTHONPATH", "")
@@ -35,39 +30,32 @@ def test_numpy_ops_example():
     _run_example("numpy-ops/numpy_softmax.py")
 
 
-@pytest.mark.slow_example
 def test_adversary_example():
     _run_example("adversary/fgsm_toy.py")
 
 
-@pytest.mark.slow_example
 def test_text_cnn_example():
     _run_example("cnn_text_classification/train_text_cnn_toy.py",
                  "--num-epoch", "8")
 
 
-@pytest.mark.slow_example
 def test_autoencoder_example():
     _run_example("autoencoder/train_autoencoder_toy.py",
                  "--pretrain-epoch", "6", "--finetune-epoch", "10")
 
 
-@pytest.mark.slow_example
 def test_neural_style_example():
     _run_example("neural-style/neural_style_toy.py")
 
 
-@pytest.mark.slow_example
 def test_fcnxs_example():
     _run_example("fcn-xs/train_fcnxs_toy.py", "--epochs", "6")
 
 
-@pytest.mark.slow_example
 def test_nce_loss_example():
     _run_example("nce-loss/train_nce_toy.py", "--epochs", "8")
 
 
-@pytest.mark.slow_example
 def test_multi_task_example():
     _run_example("multi-task/train_multi_task_toy.py", "--epochs", "10")
 
@@ -126,17 +114,14 @@ def test_extension_ops_package():
         sys.modules.pop("mxtpu_contrib_ops", None)
 
 
-@pytest.mark.slow_example
 def test_bi_lstm_sort_example():
     _run_example("bi-lstm-sort/train_sort_toy.py", "--epochs", "14")
 
 
-@pytest.mark.slow_example
 def test_stochastic_depth_example():
     _run_example("stochastic-depth/sd_toy.py", "--epochs", "8")
 
 
-@pytest.mark.slow_example
 def test_warpctc_example():
     _run_example("warpctc/toy_ctc.py", "--epochs", "35")
 
@@ -145,7 +130,6 @@ def test_svm_example():
     _run_example("svm_mnist/svm_toy.py", "--epochs", "10")
 
 
-@pytest.mark.slow_example
 def test_matrix_factorization_example():
     _run_example("recommenders/matrix_fact_toy.py", "--epochs", "20")
 
@@ -154,7 +138,6 @@ def test_sgld_example():
     _run_example("bayesian-methods/sgld_toy.py", "--steps", "4000")
 
 
-@pytest.mark.slow_example
 def test_dec_example():
     _run_example("dec/dec_toy.py", "--rounds", "40")
 
@@ -167,7 +150,6 @@ def test_module_mnist_mlp_example():
     _run_example("module/mnist_mlp.py", "--epochs", "4")
 
 
-@pytest.mark.slow_example
 def test_module_python_loss_example():
     _run_example("module/python_loss.py", "--epochs", "6")
 
@@ -176,27 +158,22 @@ def test_profiler_example():
     _run_example("profiler/profiler_matmul.py")
 
 
-@pytest.mark.slow_example
 def test_python_howto_example():
     _run_example("python-howto/howtos.py")
 
 
-@pytest.mark.slow_example
 def test_rnn_time_major_example():
     _run_example("rnn-time-major/rnn_cell_demo.py", "--epochs", "6")
 
 
-@pytest.mark.slow_example
 def test_kaggle_ndsb1_example():
     _run_example("kaggle-ndsb1/train_dsb_toy.py", "--epochs", "4")
 
 
-@pytest.mark.slow_example
 def test_kaggle_ndsb2_example():
     _run_example("kaggle-ndsb2/train_heart_toy.py", "--epochs", "8")
 
 
-@pytest.mark.slow_example
 def test_speech_demo_example():
     _run_example("speech-demo/train_acoustic_toy.py", "--epochs", "5")
 
@@ -205,4 +182,4 @@ def test_torch_interop_example():
     """The plugin/torch analog: a live torch.nn.Module inside the graph,
     its parameters trained by this framework's optimizer."""
     pytest.importorskip("torch")
-    _run_example("torch-interop/torch_module.py", timeout=900)
+    _run_example("torch-interop/torch_module.py")

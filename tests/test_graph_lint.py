@@ -17,7 +17,7 @@ from mxnet_tpu import analysis, models
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(args, timeout=300, **kw):
+def _run(args, timeout=240, **kw):
     return subprocess.run([sys.executable] + args, capture_output=True,
                           text=True, cwd=_ROOT, timeout=timeout, **kw)
 
@@ -109,6 +109,41 @@ def test_duplicate_subgraph_cse():
     dups = _find(rep, "duplicate-subgraph", "info")
     assert len(dups) == 1
     assert set(dups[0].detail["nodes"]) == {"twin_a", "twin_b"}
+
+
+def _unrolled_lstm(steps):
+    cell = mx.rnn.LSTMCell(32, prefix="l_")
+    outs, _ = cell.unroll(steps, inputs=mx.sym.Variable("data"),
+                          merge_outputs=True)
+    return outs
+
+
+def _residual_chain(depth):
+    h = mx.sym.Variable("data")
+    for i in range(depth):      # every sum has two consumers
+        h = h + mx.sym.Activation(h, act_type="relu", name="r%d" % i)
+    return mx.sym.Group([mx.sym.Activation(h, act_type="tanh", name=n)
+                         for n in ("twin_a", "twin_b")])
+
+
+@pytest.mark.parametrize("build,small,large,expect", [
+    (_unrolled_lstm, 4, 32, {"l_begin_state_0", "l_begin_state_1"}),
+    (_residual_chain, 3, 40, {"twin_a", "twin_b"}),
+])
+def test_duplicate_subgraph_on_shared_inputs(build, small, large, expect):
+    """A node's signature holds its inputs' interned ids, not their
+    signatures: with those nested, hashing one walked the graph beneath
+    as a tree, twice as long for every node with two consumers (an
+    unrolled LSTM took 24 s to lint at 10 steps, and every bind lints)."""
+    import time
+    dups = {}
+    for size in (small, large):
+        t0 = time.monotonic()
+        rep = analysis.lint_symbol(build(size), trace=False)
+        assert time.monotonic() - t0 < 5.0
+        dups[size] = [set(f.detail["nodes"])
+                      for f in _find(rep, "duplicate-subgraph", "info")]
+    assert dups[small] == dups[large] == [expect]
 
 
 def test_tpu_layout_misaligned_matmul():

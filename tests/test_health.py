@@ -127,6 +127,31 @@ def test_seq_progress_saves_skewed_behind_clock(tmp_path, monkeypatch):
     assert health.dead_nodes(1, timeout=0.2) == [0]
 
 
+def test_seq_advance_since_an_old_scan_is_not_fresh(tmp_path, monkeypatch):
+    """A scanner that looked once at the start of a job and again after a
+    collective failed: the peer beat a few more times in between and
+    died.  Its sequence number differs from the one last seen, but the
+    stamp is as old as its wall age says (bounded by the time since that
+    first look), not fresh (``tests/nightly/dist_resume.py``: one scan
+    with ``timeout=2`` after the crash has to count the dead rank)."""
+    import os
+    monkeypatch.setenv("MXTPU_HEARTBEAT_DIR", str(tmp_path))
+    health._reset_seq_cache()
+    path = tmp_path / "hb-0"
+
+    def stamp(seq, age):
+        wall = time.time() - age
+        path.write_text("%f %d" % (wall, seq))
+        os.utime(path, (wall, wall))
+    stamp(5, 0.0)
+    assert health.dead_nodes(1, timeout=2.0) == []
+    time.sleep(0.4)
+    stamp(8, 3.0)                       # the last beat, 3 s ago by its clock
+    # no older than our own clock says since the first look: 0.4 s
+    assert health.dead_nodes(1, timeout=2.0) == []
+    assert health.dead_nodes(1, timeout=0.3) == [0]  # was: fresh, alive
+
+
 def test_heartbeat_registered_for_atexit_stop(tmp_path, monkeypatch):
     monkeypatch.setenv("MXTPU_HEARTBEAT_DIR", str(tmp_path))
     h = health.Heartbeat(0, interval=0.05)

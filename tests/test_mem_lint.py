@@ -25,7 +25,7 @@ from mxnet_tpu.base import MXNetError
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(args, timeout=420, **kw):
+def _run(args, timeout=240, **kw):
     return subprocess.run([sys.executable] + args, capture_output=True,
                           text=True, cwd=_ROOT, timeout=timeout, **kw)
 
@@ -286,7 +286,6 @@ def test_serving_mem_budget_admission():
 
 # ======================================================================
 # autotune: memory-feasibility pruning
-@pytest.mark.slow
 def test_train_surrogate_capacity_prunes():
     """A capacity between the micro space's min and max predicted peaks
     marks >=1 config infeasible, sorts it LAST (never adopted, never

@@ -30,7 +30,7 @@ def _have_perl_xs():
                     reason="perl or its CORE headers unavailable")
 def test_perl_binding_trains():
     res = subprocess.run(["make", "-s", "check"], cwd=_PKG,
-                         capture_output=True, text=True, timeout=420)
+                         capture_output=True, text=True, timeout=240)
     assert res.returncode == 0, res.stdout[-2000:] + res.stderr[-2000:]
     assert "PERL BINDING OK" in res.stdout
 

@@ -325,7 +325,12 @@ def test_place_compile_cache(monkeypatch, placed):
     import jax
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     local = os.path.join(root, ".jax_cache")
-    monkeypatch.delenv("MXTPU_PROGRAM_CACHE", raising=False)
+    # set, then deleted: monkeypatch then has what was there before the
+    # test to put back.  A bare delenv of an absent name records nothing,
+    # and the setenv below would then record, and restore into every later
+    # test of this worker, what place_compile_cache itself wrote
+    monkeypatch.setenv("MXTPU_PROGRAM_CACHE", "")
+    monkeypatch.delenv("MXTPU_PROGRAM_CACHE")
     if placed:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
     else:

@@ -489,7 +489,7 @@ def _run_e2e(tmp_path, prefix, num_epoch, resume, fault=None):
     return subprocess.run(
         [sys.executable, str(script), prefix, str(num_epoch),
          "1" if resume else "0"],
-        env=env, cwd=repo, capture_output=True, text=True, timeout=300)
+        env=env, cwd=repo, capture_output=True, text=True, timeout=240)
 
 
 @pytest.mark.parametrize("crashed_save", [3])

@@ -92,8 +92,11 @@ def test_coalesced_batch_parity_and_occupancy():
     """Concurrent requests coalesce into ONE padded batch; each future
     gets exactly its own rows back."""
     sym, args, example = _mlp()
-    # a wide-open coalescing window so the three submits land together
-    with _server(sym, args, example, max_wait_us=150_000) as srv:
+    # the cycle opens when the third submit brings the rows to cap, not
+    # when a window the three have to land in runs out: on a loaded
+    # machine any window is too short once
+    with _server(sym, args, example, max_wait_us=20_000_000,
+                 cap=6) as srv:
         xs = [np.random.RandomState(i).randn(i + 1, *example).astype("f")
               for i in range(3)]                       # rows 1 + 2 + 3 = 6
         futs = [srv.submit(data=x) for x in xs]
@@ -129,7 +132,8 @@ def test_mixed_shape_load_zero_retrace():
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
         st = srv.stats()
         assert st["completed"] == 24 and st["failed"] == 0
         assert st["aot_compiles"] == aot
@@ -166,7 +170,9 @@ def test_poison_request_fails_alone():
     """Error isolation: the poisoned request's future fails; the other
     requests IN THE SAME BATCH complete with correct values."""
     sym, args, example = _mlp()
-    with _server(sym, args, example, max_wait_us=150_000) as srv:
+    # cap = the three rows: the third submit opens the cycle (see above)
+    with _server(sym, args, example, max_wait_us=20_000_000,
+                 cap=3) as srv:
         xs = [np.random.RandomState(i).randn(1, *example).astype("f")
               for i in range(3)]
         with faults.injected("poison_request@request=2"):
@@ -179,7 +185,7 @@ def test_poison_request_fails_alone():
         assert st["batches"] == 1          # ONE batch served all three
         assert st["completed"] == 2 and st["failed"] == 1
         for i in (0, 2):
-            out = futs[i].result()
+            out = futs[i].result(timeout=20)
             assert np.all(np.isfinite(out[0]))
             _close(out[0], _reference(srv, xs[i])[0])
 

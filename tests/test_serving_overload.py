@@ -223,7 +223,7 @@ def test_cancel_frees_queued_rows():
         assert srv.stats()["per_model"]["m"]["queue_depth_rows"] == 5
         assert f1.cancel() is True
         with pytest.raises(ServeCancelled):
-            f1.result()
+            f1.result(timeout=20)
         assert f1.cancel() is False                # already done
         st = srv.stats()
         assert st["cancelled"] == 1

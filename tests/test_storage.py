@@ -97,7 +97,8 @@ def test_storage_concurrent_double_free():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
     # exactly one free must take effect
     assert st.used_memory(ctx) == 0
     assert st.pooled_memory(ctx) == 128  # one 128B bucket entry, not 8

@@ -162,3 +162,30 @@ def test_attn_bench_smoke(tmp_path):
     assert row["seq"] == 128
     assert "flash_fwd_ms" in row and "naive_fwd_ms" in row
     assert "flash_fwdbwd_ms" in row
+
+
+def test_time_limit_fails_a_test_that_outlasts_it(monkeypatch):
+    """The suite's own tool: the limit every test has (tests/conftest.py)
+    fails what outlasts it, by name, and leaves the test's own timer and
+    handler as it found them."""
+    import signal
+    import time
+
+    import conftest
+
+    handler = signal.getsignal(signal.SIGALRM)
+    monkeypatch.setattr(conftest, "TEST_TIME_LIMIT_S", 0.2)
+    with pytest.raises(pytest.fail.Exception) as err:
+        with conftest.time_limit("tests/test_x.py::test_waits_for_ever"):
+            deadline = time.monotonic() + 20
+            while time.monotonic() < deadline:
+                time.sleep(0.05)
+    assert "tests/test_x.py::test_waits_for_ever" in str(err.value)
+    assert "0.2 s" in str(err.value)
+    assert signal.getsignal(signal.SIGALRM) is handler
+    left, _ = signal.getitimer(signal.ITIMER_REAL)
+    assert 200 < left <= 300     # this test's own limit is armed again
+
+    with conftest.time_limit("tests/test_x.py::test_returns_in_time"):
+        pass
+    time.sleep(0.3)              # no stale timer fires after a test
