@@ -387,16 +387,18 @@ class ReplicatedOptStatePass(GraphPass):
 
 @register_pass
 class GatherScatterPass(GraphPass):
-    """Unfused gather/scatter families in the step.
+    """Gather/scatter families in the step.
 
-    ``select_and_scatter_add`` is the autodiff MaxPool backward the
-    byte-diet (PR 1, ``op/bytediet.py``) replaced with an
-    argmax-index scatter-add — its presence means a pooling op fell off
-    the byte-diet path (warn, unless the policy is explicitly
-    ``legacy``).  Plain gather/scatter are legitimate (embeddings,
-    byte-diet pool backward) and are reported as info counts per layer
-    so the byte attribution in ``tools/step_breakdown.py`` has a
-    trace-time cross-check.
+    Max ``Pooling`` differentiates to ``select_and_scatter_add``, XLA's
+    dense window op.  A ``sort`` or ``scatter-add`` traced under a
+    ``Pooling`` node's scope is that backward rewritten as a scatter at
+    saved indices: on the TPU v5e it sorted 51M indices and scattered
+    them serially, 573 of ResNet-50's 698 ms a step (PERF.md, PR 28) —
+    warn.  Which scopes are ``Pooling`` nodes is read from ``ctx.view``;
+    without one nothing can be told apart and nothing warns.  Every
+    other gather/scatter is legitimate (embeddings) and is reported as
+    info counts per layer so the byte attribution in
+    ``tools/step_breakdown.py`` has a trace-time cross-check.
     """
 
     name = "gather-scatter"
@@ -405,31 +407,34 @@ class GatherScatterPass(GraphPass):
     def run(self, ctx: PassContext):
         if ctx.jaxpr is None:
             return []
+        pools = set() if ctx.view is None else {
+            n.name for n in ctx.view.nodes
+            if n.op is not None and n.op.name == "Pooling"}
         out = []
-        sns_layers = []
+        pool_scatters = {}
         counts = {}
         for eqn, prefix, _ in iter_eqns_scoped(ctx.jaxpr):
             pname = eqn.primitive.name
-            if pname in ("select_and_scatter_add", "select_and_scatter"):
-                _, where = _where(eqn, prefix)
-                sns_layers.append(where)
-            elif pname in ("gather", "scatter", "scatter-add",
-                           "scatter_add"):
-                _, where = _where(eqn, prefix)
+            scatters = pname in ("scatter", "scatter-add", "scatter_add")
+            if not scatters and pname not in ("gather", "sort"):
+                continue
+            layer, where = _where(eqn, prefix)
+            if layer in pools and (scatters or pname == "sort"):
+                pool_scatters.setdefault(where, set()).add(pname)
+            elif pname != "sort":
                 counts[where] = counts.get(where, 0) + 1
-        # resolve the EFFECTIVE policy the traced op bodies used: an
-        # unset ctx value falls back to the process default
-        # (MXTPU_DTYPE_POLICY), exactly like OpContext resolution does
-        from ..op import bytediet
-        policy = ctx.dtype_policy or bytediet.default_policy()
-        if sns_layers and policy != "legacy":
+        if pool_scatters:
+            layers = sorted(pool_scatters)
             out.append(Finding(
-                self.name, WARN, sns_layers[0], "select_and_scatter_add",
-                "%d select_and_scatter in the step (layers %s): the "
-                "byte-diet argmax-index pool backward should have "
-                "eliminated these — a pooling op fell off the bytediet "
-                "path" % (len(sns_layers), sorted(set(sns_layers))[:4]),
-                detail={"layers": sorted(set(sns_layers))}))
+                self.name, WARN, layers[0],
+                sorted(pool_scatters[layers[0]])[0],
+                "sort/scatter under a Pooling node (layers %s): a pooling "
+                "backward that scatters at saved indices is sorted and "
+                "serialized on the TPU; differentiate the plain "
+                "reduce_window (select_and_scatter_add) instead"
+                % (layers[:4],),
+                detail={"layers": {l: sorted(p)
+                                   for l, p in pool_scatters.items()}}))
         if counts:
             total = sum(counts.values())
             top = sorted(counts.items(), key=lambda kv: -kv[1])

@@ -64,7 +64,7 @@ def lint_json(json_str: str, shapes: Optional[Dict[str, tuple]] = None,
                         dtype_policy, model or "<json>", config, only)
     if trace and not report.errors():
         from ..symbol import load_json
-        _trace_into(report, load_json(json_str), report.annotation,
+        _trace_into(report, load_json(json_str), view, report.annotation,
                     is_train, platform, dtype_policy, config, only)
     return report
 
@@ -86,16 +86,17 @@ def _lint_view(view, shapes, dtypes, trace, is_train, platform,
                       config=config or {})
     report.extend(run_passes(ctx, "symbol", only))
     if trace and view.symbol is not None and not report.errors():
-        _trace_into(report, view.symbol, ann, is_train, platform,
+        _trace_into(report, view.symbol, view, ann, is_train, platform,
                     dtype_policy, config, only)
     return report
 
 
 # ----------------------------------------------------------------------
-def _trace_into(report, sym, ann, is_train, platform, dtype_policy,
+def _trace_into(report, sym, view, ann, is_train, platform, dtype_policy,
                 config, only):
     """Trace the graph program (fwd, plus vjp when ``is_train``) to a
-    jaxpr and run the jaxpr-level passes into ``report``."""
+    jaxpr and run the jaxpr-level passes into ``report``.  ``view`` goes
+    along so a pass can tell what kind of node a scope names."""
     import jax
     import jax.numpy as jnp
     from ..executor import _GraphProgram
@@ -149,7 +150,7 @@ def _trace_into(report, sym, ann, is_train, platform, dtype_policy,
             "tracing the %s program failed: %s"
             % ("train" if is_train else "eval", e))])
         return
-    ctx = PassContext(jaxpr=closed, platform=prog.platform,
+    ctx = PassContext(view=view, jaxpr=closed, platform=prog.platform,
                       dtype_policy=dtype_policy, is_train=is_train,
                       config=config or {})
     report.extend(run_passes(ctx, "jaxpr", only))
@@ -238,7 +239,8 @@ def lint_trainer(trainer, config: Optional[Dict[str, Any]] = None,
     lint_cfg = dict(config or {})
     lint_cfg.setdefault("data_axis_size", trainer._data_axis_size())
     lint_cfg.setdefault("zero", trainer.zero)
-    ctx = PassContext(jaxpr=jaxpr, donated_invars=donated,
+    ctx = PassContext(view=GraphView.from_symbol(trainer.symbol),
+                      jaxpr=jaxpr, donated_invars=donated,
                       invar_labels=labels, invar_shardings=shardings,
                       platform=trainer.prog.platform,
                       dtype_policy=trainer.dtype_policy, is_train=True,

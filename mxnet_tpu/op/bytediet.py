@@ -6,9 +6,9 @@ byte side), and the round-5 recapture named the residue: three zero-FLOP
 1.2-1.6 GB fusions, a 0.92 GB zero-FLOP ``select_and_scatter`` (MaxPool
 backward) and a family of 0.82 GB zero-FLOP fusions — all *backward-pass
 residual traffic*, not compute.  This module rewrites the backward
-formulations of the three ops that materialize activation-sized
-zero-FLOP tensors, so the cotangent chain reads fewer full-size operands
-per layer:
+formulations of two ops that materialize activation-sized zero-FLOP
+tensors, so the cotangent chain reads fewer full-size operands per
+layer:
 
 * **ReLU** (`relu_save_output`): jax's ``max(x, 0)`` vjp carries the
   saved *input* to backward and re-derives the mask from it.  The output
@@ -16,14 +16,6 @@ per layer:
   saved residual anyway) and the mask is recoverable from it —
   ``dx = where(y > 0, dy, 0)``.  Saving ``y`` instead of ``x`` dedupes
   the residual pair down to one tensor per activation.
-* **MaxPool** (`max_pool_argmax`): XLA's ``select_and_scatter`` re-reads
-  the full input activation in backward to re-locate each window's
-  maximum (operands: x + dy, output: dx — 0.92 GB on the ResNet stem).
-  Here the forward computes value and argmax *in one variadic
-  ``reduce_window`` pass* (first index wins ties — the same tie rule as
-  ``select_and_scatter``'s GE-select), keeps the int32 index map (output
-  resolution, ~¼ the bytes of x) as the only residual, and backward is a
-  pure scatter-add of the cotangent at the saved indices — no x re-read.
 * **BatchNorm** (`bn_train_normalize`): letting autodiff differentiate
   the normalize expression materializes activation-sized temporaries
   (the ``(x - mean)`` chains of the stat broadcasts) in the backward
@@ -31,6 +23,14 @@ per layer:
   reductions of ``dy`` and ``dy·x̂`` plus one fused elementwise pass:
   ``dx = x·A + dy·S + B`` with per-channel f32 scalars A/S/B — every
   activation-sized read fuses into adjacent elementwise work.
+
+MaxPool is not among them.  Its ``select_and_scatter`` was replaced
+here by a scatter-add of the cotangent at an int32 index map the forward
+saved, to spare the 0.92 GB re-read (1.1 ms at the v5e's 819 GB/s).  The
+chip has no fast path for a scatter of 51.4M arbitrary indices: XLA
+sorts them and scatters serially, 573 of the step's 698 ms (PERF.md §6,
+PR 28).  ``op/nn.py: _pooling`` differentiates the plain
+``reduce_window`` again, under either policy.
 
 **Residual/intermediate dtype policy** (``dtype_policy``): the fused
 trainer seeds bf16 cotangents (`parallel/trainer.py`) and these
@@ -60,7 +60,7 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["enabled", "default_policy", "relu_save_output",
-           "max_pool_argmax", "bn_batch_stats", "bn_train_normalize"]
+           "bn_batch_stats", "bn_train_normalize"]
 
 
 def default_policy():
@@ -98,64 +98,6 @@ def _relu_bwd(y, g):
 
 
 relu_save_output.defvjp(_relu_fwd, _relu_bwd)
-
-
-# ----------------------------------------------------------------------
-# MaxPool: argmax-index backward (no select_and_scatter, no x re-read)
-def _argmax_reducer(a, b):
-    av, ai = a
-    bv, bi = b
-    # strict > keeps the FIRST (smallest linear index) maximum on ties —
-    # select_and_scatter's GE-select tie rule
-    pick = (bv > av) | ((bv == av) & (bi < ai))
-    return jnp.where(pick, bv, av), jnp.where(pick, bi, ai)
-
-
-from functools import lru_cache
-
-
-@lru_cache(maxsize=None)
-def _max_pool_vjp(shape, dtype_name, window, strides, padding):
-    """A custom-vjp max pool specialized to one (shape, dtype, geometry)
-    — the specialization keeps the static shape/dtype out of the
-    residual pytree; the cache makes retraces free."""
-    dtype = jnp.dtype(dtype_name)
-    n = int(np.prod(shape))
-
-    @jax.custom_vjp
-    def pool(x):
-        init = np.array(-np.inf, dtype)
-        return lax.reduce_window(x, init, lax.max, window, strides,
-                                 padding)
-
-    def fwd(x):
-        iota = jnp.arange(n, dtype=jnp.int32).reshape(shape)
-        init = (np.array(-np.inf, dtype), np.int32(n))  # n = padding slot
-        y, idx = lax.reduce_window((x, iota), init, _argmax_reducer,
-                                   window, strides, padding)
-        return y, idx        # the int32 index map is the ONLY residual
-
-    def bwd(idx, g):
-        # scatter-add: overlapping windows that picked the same input
-        # position accumulate, all-padding windows carry the
-        # out-of-bounds sentinel index n and are dropped — exactly
-        # select_and_scatter's source accumulation.  dx stays in the
-        # cotangent dtype (bf16 under the fused trainer's policy).
-        flat = jnp.zeros((n,), g.dtype).at[idx.ravel()].add(
-            g.ravel(), mode="drop")
-        return (flat.reshape(shape).astype(dtype),)
-
-    pool.defvjp(fwd, bwd)
-    return pool
-
-
-def max_pool_argmax(x, window, strides, padding):
-    """Max pooling whose backward scatters the cotangent at forward-saved
-    argmax indices instead of lowering to ``select_and_scatter``."""
-    pool = _max_pool_vjp(tuple(x.shape), jnp.dtype(x.dtype).name,
-                         tuple(window), tuple(strides),
-                         tuple(tuple(p) for p in padding))
-    return pool(x)
 
 
 # ----------------------------------------------------------------------

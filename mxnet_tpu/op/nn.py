@@ -281,15 +281,12 @@ def _pooling(p, c, data):
         strides = (1, 1) + stride
         padding = ((0, 0), (0, 0)) + tuple(lo_hi)
     if p["pool_type"] == "max":
+        # one formulation for training and evaluation under either
+        # dtype policy: autodiff's backward of this reduce_window is
+        # XLA's dense select-and-scatter window op (first maximum wins
+        # a tie).  An index-map scatter-add in its place cost the v5e a
+        # 51M-element sort and a serial scatter (PERF.md, PR 28).
         if jnp.issubdtype(data.dtype, jnp.floating):
-            if c.is_train and _bd.enabled(c):
-                # byte-diet backward: forward computes value+argmax in
-                # one variadic reduce_window pass, backward scatter-adds
-                # the cotangent at the saved indices — no
-                # select_and_scatter, no activation re-read
-                # (op/bytediet.py).  Eval traces keep the plain reduce
-                # (no index map to pay for).
-                return _bd.max_pool_argmax(data, window, strides, padding)
             init = np.array(-np.inf, data.dtype)
         else:
             init = np.array(np.iinfo(np.dtype(data.dtype)).min, data.dtype)

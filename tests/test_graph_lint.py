@@ -238,26 +238,72 @@ def test_host_callback_pass():
     assert "pure_callback" in out[0].message
 
 
-def test_select_and_scatter_warns_unless_legacy():
+def _pool_scope_jaxpr(policy, scattered):
+    """The gradient of a 2x2 max pool traced under the scope of the
+    ``Pooling`` node ``pool0``, and that node's graph view: the op's own
+    body under ``policy``, or (``scattered``) a hand-written backward
+    that scatter-adds the cotangent at indices the forward saved."""
     import jax
     import jax.numpy as jnp
+    from mxnet_tpu.op.registry import OpContext, get
 
-    def pool_grad(x):
-        def pooled(y):
-            return jnp.sum(jax.lax.reduce_window(
-                y, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1),
-                "VALID"))
-        return jax.grad(pooled)(x)
+    sym = mx.sym.Pooling(mx.sym.Variable("data"), kernel=(2, 2),
+                         stride=(2, 2), pool_type="max", layout="NHWC",
+                         name="pool0")
+    op = get("Pooling")
+    params = op.parse_params(dict(kernel=(2, 2), stride=(2, 2),
+                                  pool_type="max", layout="NHWC"))
+    ctx = OpContext(is_train=True, dtype_policy=policy)
 
-    jaxpr = jax.make_jaxpr(pool_grad)(np.ones((1, 4, 4, 1), np.float32))
+    def pool(x):
+        return op.apply(params, ctx, x)[0][0]
+
+    @jax.custom_vjp
+    def pool_by_scatter(x):
+        return pool(x)
+
+    def fwd(x):
+        k = jnp.argmax(x.reshape(2, 2, 2, 2).transpose(0, 2, 1, 3)
+                       .reshape(4, 4), axis=-1)       # [window, offset]
+        oh, ow = jnp.divmod(jnp.arange(4), 2)
+        return pool(x), (oh * 2 + k // 2) * 4 + ow * 2 + k % 2
+
+    def bwd(idx, g):
+        return (jnp.zeros((16,), g.dtype).at[idx].add(g.ravel())
+                .reshape(1, 4, 4, 1),)
+
+    pool_by_scatter.defvjp(fwd, bwd)
+
+    def grad(x):
+        def loss(y):
+            with jax.named_scope("pool0"):
+                return jnp.sum((pool_by_scatter if scattered else pool)(y))
+        return jax.grad(loss)(x)
+
+    return (jax.make_jaxpr(grad)(np.ones((1, 4, 4, 1), np.float32)),
+            analysis.GraphView.from_symbol(sym))
+
+
+@pytest.mark.parametrize("policy,scattered", [
+    ("bytediet", False), ("legacy", False), ("bytediet", True)])
+def test_pooling_backward_by_scatter_warns(policy, scattered):
+    """Max ``Pooling``'s backward is ``select_and_scatter_add`` under
+    either dtype policy and is no finding; a scatter-add traced under a
+    ``Pooling`` node's scope is the formulation PR 28 removed."""
+    jaxpr, view = _pool_scope_jaxpr(policy, scattered)
+    prims = {e.primitive.name for e in analysis.jaxpr_passes.iter_eqns(jaxpr)}
+    assert ("select_and_scatter_add" in prims) != scattered
     gs = analysis.get_pass("gather-scatter")
-    out = list(gs.run(analysis.PassContext(jaxpr=jaxpr)))
-    assert any(f.severity == "warn" and "byte-diet" in f.message
-               for f in out)
-    # an explicit legacy policy is a deliberate A/B: no warn
-    legacy = list(gs.run(analysis.PassContext(jaxpr=jaxpr,
-                                              dtype_policy="legacy")))
-    assert not [f for f in legacy if f.severity == "warn"]
+    out = list(gs.run(analysis.PassContext(jaxpr=jaxpr, view=view)))
+    if not scattered:
+        assert not out
+        return
+    assert [f.severity for f in out] == ["warn"]
+    assert out[0].node == "pool0 (bwd)"
+    assert out[0].detail["layers"] == {"pool0 (bwd)": ["scatter-add"]}
+    # without a view a scope is only a name: counted, not judged
+    blind = list(gs.run(analysis.PassContext(jaxpr=jaxpr)))
+    assert [f.severity for f in blind] == ["info"]
 
 
 def test_donation_pass_flags_undonated_state():
@@ -301,10 +347,39 @@ def test_trainer_step_lint_is_clean(monkeypatch):
     # the fused step donates params/aux/opt_state and runs no host
     # callbacks or f64 math: zero error AND zero warn findings
     assert rep.counts()["error"] == 0 and rep.counts()["warn"] == 0
-    # ...and the byte-diet pool backward shows up as attributed
-    # gather/scatter info, proving layer provenance survives the trace
-    infos = _find(rep, "gather-scatter", "info")
-    assert infos and "pooling" in infos[0].node
+    # ...and neither pooling node scatters its gradient
+    assert not _find(rep, "gather-scatter")
+
+
+def test_trainer_step_pools_by_select_and_scatter(monkeypatch):
+    """The fused step of conv-BN-ReLU-maxpool (the ResNet stem's
+    geometry): the pool's backward is the compiler's window op; no sort
+    and no scatter is traced anywhere in the step."""
+    monkeypatch.setenv("MXTPU_MODULE_FUSED", "always")
+    net = mx.sym.Convolution(mx.sym.Variable("data"), kernel=(3, 3),
+                             pad=(1, 1), num_filter=8, no_bias=True,
+                             layout="NHWC", name="conv0")
+    net = mx.sym.BatchNorm(net, fix_gamma=False, axis=3, name="bn0")
+    net = mx.sym.Activation(net, act_type="relu", name="relu0")
+    net = mx.sym.Pooling(net, kernel=(3, 3), stride=(2, 2), pad=(1, 1),
+                         pool_type="max", layout="NHWC", name="pooling0")
+    net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=10,
+                                name="fc1")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    mod = mx.mod.Module(context=mx.cpu(), symbol=net)
+    mod.bind(data_shapes=[("data", (4, 8, 8, 3))],
+             label_shapes=[("softmax_label", (4,))])
+    mod.init_params()
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1})
+    closed = mod._trainer.step_jaxpr()
+    where = {}
+    for eqn, prefix, _ in analysis.jaxpr_passes.iter_eqns_scoped(closed):
+        where.setdefault(eqn.primitive.name, set()).add(
+            analysis.jaxpr_passes.layer_of_eqn(eqn, prefix))
+    assert where["select_and_scatter_add"] == {("pooling0", True)}
+    assert not {"sort", "scatter-add", "scatter"} & set(where)
+    assert not _find(mod._trainer.lint(), "gather-scatter")
 
 
 # ----------------------------------------------------------------------

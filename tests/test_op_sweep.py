@@ -311,8 +311,8 @@ G("Pooling", {"data": distinct(1, 2, 4, 4)},
   {"kernel": (2, 2), "stride": (2, 2), "pool_type": "max"},
   id_suffix="max")
 # OVERLAPPING windows (kernel > stride — the ResNet stem geometry):
-# exercises the byte-diet argmax-index backward where one input
-# position feeds several windows (op/bytediet.py).  eps=1e-2: pooling
+# one input position feeds several windows, whose cotangents
+# select_and_scatter_add must sum there.  eps=1e-2: pooling
 # is piecewise linear (distinct() separates values by 0.37, no argmax
 # flip) and a 1e-3 central difference of the ~1e2-magnitude f32 loss
 # is quantization-limited (ULP ~1.5e-5 vs a ~3e-4 numerator).

@@ -210,6 +210,93 @@ def test_pooling():
     check_symbolic_forward(avg, {"x": a}, [expect], rtol=1e-4, atol=1e-5)
 
 
+def _first_max_pool_grad(x, g, kernel, stride, pad, full):
+    """Max pooling's input gradient over NHWC ``x`` written out: every
+    window hands its cotangent to its first maximum in row-major order;
+    a window that holds nothing but padding hands it to nobody."""
+    n, h, w, c = x.shape
+
+    def starts(size):
+        span = size + 2 * pad - kernel
+        count = (-(-span // stride) if full else span // stride) + 1
+        return [i * stride - pad for i in range(count)]
+
+    dx = np.zeros(x.shape, np.float64)
+    tied = []
+    for oi, i0 in enumerate(starts(h)):
+        for oj, j0 in enumerate(starts(w)):
+            cells = [(i, j) for i in range(max(i0, 0), min(i0 + kernel, h))
+                     for j in range(max(j0, 0), min(j0 + kernel, w))]
+            if not cells:
+                continue
+            vals = np.stack([x[:, i, j, :] for i, j in cells])
+            first = np.argmax(vals, axis=0)        # first of equal maxima
+            tied.append((vals == vals.max(axis=0)).sum(axis=0) > 1)
+            for k, (i, j) in enumerate(cells):
+                dx[:, i, j, :] += np.where(first == k, g[:, oi, oj, :], 0)
+    return dx, np.mean(tied)
+
+
+@pytest.mark.parametrize("policy", ["bytediet", "legacy"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("convention", ["valid", "full"])
+@pytest.mark.parametrize("layout", ["NHWC", "NCHW"])
+@pytest.mark.parametrize("kernel,stride,pad", [(3, 2, 1), (3, 1, 1),
+                                               (2, 2, 1)])
+def test_max_pool_backward_ties(kernel, stride, pad, layout, convention,
+                                dtype, policy):
+    """Training-mode max ``Pooling`` on a post-ReLU input rounded until
+    most windows tie: the input gradient is, exactly, that of the plain
+    ``reduce_window`` and the first maximum's.  2x2/2 pad 1 over 3x3
+    under ``full`` ends on a window of padding alone."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from mxnet_tpu.op.registry import OpContext, get
+
+    rng = np.random.RandomState(kernel * 10 + stride)
+    size = (3, 3) if kernel == 2 else (7, 10)
+    x = np.maximum(np.round(rng.randn(2, *size, 3) * 0.5), 0)
+    # numpy sees NHWC; the op sees its layout
+    to_op, to_nhwc = ((0, 1, 2, 3),) * 2 if layout == "NHWC" \
+        else ((0, 3, 1, 2), (0, 2, 3, 1))
+    op = get("Pooling")
+    params = op.parse_params(dict(
+        kernel=(kernel, kernel), stride=(stride, stride), pad=(pad, pad),
+        pool_type="max", pooling_convention=convention, layout=layout))
+    ctx = OpContext(is_train=True, dtype_policy=policy)
+    data = jnp.asarray(x.transpose(to_op), dtype)
+    out, vjp = jax.vjp(lambda d: op.apply(params, ctx, d)[0][0], data)
+    out_size = np.transpose(out, to_nhwc).shape[1:3]
+    # small whole numbers: sums of up to nine are exact in bfloat16 too
+    g = rng.randint(1, 5, size=(2,) + out_size + (3,)).astype("f")
+    cot = jnp.asarray(g.transpose(to_op), dtype)
+    dx, = vjp(cot)
+    assert dx.dtype == data.dtype
+
+    window, strides, padding = [1] * 4, [1] * 4, [(0, 0)] * 4
+    for ax, n_in, n_out in zip((1, 2) if layout == "NHWC" else (2, 3),
+                               size, out_size):
+        hi = (n_out - 1) * stride + kernel - pad - n_in
+        window[ax], strides[ax], padding[ax] = kernel, stride, (pad, hi)
+    plain_out, plain_vjp = jax.vjp(
+        lambda d: lax.reduce_window(d, np.array(-np.inf, d.dtype), lax.max,
+                                    window, strides, padding), data)
+    np.testing.assert_array_equal(np.asarray(out, "f"),
+                                  np.asarray(plain_out, "f"))
+    np.testing.assert_array_equal(np.asarray(dx, "f"),
+                                  np.asarray(plain_vjp(cot)[0], "f"))
+
+    want, tied = _first_max_pool_grad(x, g, kernel, stride, pad,
+                                      convention == "full")
+    assert tied > 0.5
+    got = np.asarray(dx, "f").transpose(to_nhwc)
+    np.testing.assert_array_equal(got, want)
+    empty = ~np.isfinite(np.asarray(out, "f").transpose(to_nhwc))
+    assert empty.any() == (kernel == 2 and convention == "full")
+    assert got.sum() == g[~empty].sum()
+
+
 def test_softmax_output():
     x = mx.sym.Variable("x")
     l = mx.sym.Variable("l")

@@ -578,7 +578,11 @@ _ATTACK_HISTORY = {
             "reduce_window pass (first index wins ties — "
             "select_and_scatter's own tie rule), backward is a "
             "scatter-add of the cotangent at the saved int32 indices; "
-            "no full-size activation re-read in backward.",
+            "no full-size activation re-read in backward.  TAKEN OUT "
+            "AGAIN (PR 28): never run on a chip until PR 21, where XLA "
+            "sorted the 51.4M indices and scattered them serially, 573 "
+            "of the step's 698 ms; the plain reduce_window's "
+            "select_and_scatter is back.",
     },
 }
 
