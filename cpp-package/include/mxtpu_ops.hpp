@@ -214,6 +214,30 @@ inline std::vector<NDArray> MakeLoss(
   return Invoke("MakeLoss", inputs, kw);
 }
 
+inline std::vector<NDArray> MoEExperts(
+    const std::vector<NDArray> &inputs,
+    int num_experts,
+    int experts_held,
+    int num_hidden,
+    const KWArgs &extra = {}) {
+  KWArgs kw(extra);
+  kw["num_experts"] = std::to_string(num_experts);
+  kw["experts_held"] = std::to_string(experts_held);
+  kw["num_hidden"] = std::to_string(num_hidden);
+  return Invoke("MoEExperts", inputs, kw);
+}
+
+inline std::vector<NDArray> MoERouter(
+    const std::vector<NDArray> &inputs,
+    int num_experts,
+    int top_k,
+    const KWArgs &extra = {}) {
+  KWArgs kw(extra);
+  kw["num_experts"] = std::to_string(num_experts);
+  kw["top_k"] = std::to_string(top_k);
+  return Invoke("MoERouter", inputs, kw);
+}
+
 inline std::vector<NDArray> MultiBoxDetection(
     const std::vector<NDArray> &inputs,
     const KWArgs &extra = {}) {
@@ -260,6 +284,13 @@ inline std::vector<NDArray> Proposal(
   return Invoke("Proposal", inputs, kw);
 }
 
+inline std::vector<NDArray> RMSNorm(
+    const std::vector<NDArray> &inputs,
+    const KWArgs &extra = {}) {
+  KWArgs kw(extra);
+  return Invoke("RMSNorm", inputs, kw);
+}
+
 inline std::vector<NDArray> RNN(
     const std::vector<NDArray> &inputs,
     int state_size,
@@ -289,6 +320,13 @@ inline std::vector<NDArray> Reshape(
     const KWArgs &extra = {}) {
   KWArgs kw(extra);
   return Invoke("Reshape", inputs, kw);
+}
+
+inline std::vector<NDArray> RotaryEmbedding(
+    const std::vector<NDArray> &inputs,
+    const KWArgs &extra = {}) {
+  KWArgs kw(extra);
+  return Invoke("RotaryEmbedding", inputs, kw);
 }
 
 inline std::vector<NDArray> SVMOutput(

@@ -71,6 +71,7 @@ class Initializer(object):
         ("moving_inv_var", "_init_zero"),
         ("moving_var", "_init_one"),
         ("moving_avg", "_init_zero"),
+        ("_count", "_init_zero"),       # MoEExperts' entries an expert
     )
 
     def _legacy_init(self, name, arr):

@@ -3,7 +3,8 @@
 Mirrors the capability of ``example/image-classification/symbols/`` in the
 reference (mlp, lenet, alexnet, vgg, resnet, resnext, googlenet,
 inception-bn, inception-v3, inception-resnet-v2) plus the bucketing LSTM
-language model (``example/rnn/lstm_bucketing.py``) and a transformer.
+language model (``example/rnn/lstm_bucketing.py``), a transformer and a
+latent-attention mixture-of-experts language model (``glm-moe``).
 Architectures are standard published networks, written fresh in
 mxnet_tpu Symbol idiom; the graphs compile to single XLA computations.
 
@@ -23,10 +24,11 @@ from . import googlenet
 from . import lstm_lm
 from . import resnext
 from . import transformer
+from . import glm_moe
 
 __all__ = ["get_symbol", "mlp", "lenet", "alexnet", "vgg", "resnet",
            "resnext", "googlenet", "inception_bn", "inception_v3",
-           "inception_resnet_v2", "lstm_lm", "transformer"]
+           "inception_resnet_v2", "lstm_lm", "transformer", "glm_moe"]
 
 _BUILDERS = {
     "mlp": mlp.get_symbol,
@@ -38,6 +40,7 @@ _BUILDERS = {
     "inception-resnet-v2": inception_resnet_v2.get_symbol,
     "transformer": transformer.get_symbol,
     "gpt": transformer.get_symbol,
+    "glm-moe": glm_moe.get_symbol,
 }
 
 

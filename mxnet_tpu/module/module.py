@@ -618,9 +618,24 @@ class Module(BaseModule):
                 # fit loop's update-then-metric order avoids this cost)
                 self.get_outputs()
             if self._fused_outputs is not None:
-                eval_metric.update(labels, self._fused_outputs)
+                eval_metric.update(labels, self._labelled(
+                    self._fused_outputs, len(labels)))
             return
         self._exec_group.update_metric(eval_metric, labels)
+
+    def _labelled(self, outputs, n_labels):
+        """The outputs a metric is shown.  A graph may have more loss
+        heads than labels (a second head fed from the same label, as a
+        multi-token-prediction module is): the metric then sees, label
+        by label, the output named for it (``<name>_output`` for
+        ``<name>_label``), else the first ``n_labels`` outputs."""
+        if n_labels >= len(outputs) or n_labels != len(self._label_names):
+            return outputs
+        by_name = dict(zip(self._output_names, outputs))
+        wanted = [n[:-len("label")] + "output" for n in self._label_names]
+        if all(n in by_name for n in wanted):
+            return [by_name[n] for n in wanted]
+        return outputs[:n_labels]
 
     # ------------------------------------------------------------------
     def _sync_params_from_devices(self):

@@ -35,6 +35,7 @@ obs_report.py`` uses when a run produced one log per process.
 """
 from __future__ import annotations
 
+import weakref
 from bisect import bisect_left
 from collections.abc import MutableMapping
 from typing import Dict, Optional, Sequence, Tuple
@@ -190,6 +191,7 @@ class Registry:
         self._mu = _tsan.lock("obs.Registry._mu")
         self._metrics: Dict[str, object] = {}
         self._scopes: Dict[str, int] = {}
+        self._pulls: list = []          # weak refresh methods, see pull
 
     # ------------------------------------------------------------- get
     def _get(self, name: str, cls, **kw):
@@ -235,9 +237,25 @@ class Registry:
             return "%s%d" % (prefix, n)
 
     # -------------------------------------------------------- snapshot
+    def pull(self, refresh) -> None:
+        """Have every :meth:`snapshot` call ``refresh`` (a bound method,
+        held weakly) before it reads: for gauges whose source lives on
+        the device and costs a transfer to read, so that nothing on the
+        hot path keeps them current.  An owner that has gone away drops
+        out; what it last set stays."""
+        with self._mu:
+            self._pulls.append(weakref.WeakMethod(refresh))
+
     def snapshot(self) -> Dict:
         """One machine-readable dict of everything:
         ``{"counters": {...}, "gauges": {...}, "histograms": {...}}``."""
+        with self._mu:
+            pulls = [r() for r in self._pulls]
+            self._pulls = [r for r, f in zip(self._pulls, pulls)
+                           if f is not None]
+        for refresh in pulls:
+            if refresh is not None:
+                refresh()
         with self._mu:
             metrics = list(self._metrics.values())
         out = {"counters": {}, "gauges": {}, "histograms": {}}

@@ -13,3 +13,4 @@ from . import contrib  # noqa: F401
 from . import rnn_op  # noqa: F401
 from . import attention  # noqa: F401
 from . import ctc  # noqa: F401
+from . import moe  # noqa: F401

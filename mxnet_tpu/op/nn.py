@@ -336,7 +336,7 @@ def _pool_infer_shape(p, in_shapes):
 @register("Activation",
           params_spec=(Param("act_type", str, required=True,
                              enum=("relu", "sigmoid", "tanh", "softrelu",
-                                   "gelu")),),
+                                   "gelu", "silu")),),
           hint="activation")
 def _activation(p, c, a):
     if p["act_type"] == "relu" and _bd.enabled(c):
@@ -345,7 +345,7 @@ def _activation(p, c, a):
         return _bd.relu_save_output(a)
     return {"relu": jax.nn.relu, "sigmoid": jax.nn.sigmoid,
             "tanh": jnp.tanh, "softrelu": jax.nn.softplus,
-            "gelu": jax.nn.gelu}[p["act_type"]](a)
+            "gelu": jax.nn.gelu, "silu": jax.nn.silu}[p["act_type"]](a)
 
 
 @register("LayerNorm",
@@ -364,6 +364,51 @@ def _layer_norm(p, c, data, gamma, beta):
     shape = [1] * data.ndim
     shape[ax] = data.shape[ax]
     return normed * gamma.reshape(shape) + beta.reshape(shape)
+
+
+@register("RMSNorm",
+          params_spec=(Param("axis", int, -1),
+                       Param("eps", float, 1e-5)),
+          input_names=("data", "gamma"),
+          hint="rmsnorm")
+def _rms_norm(p, c, data, gamma):
+    """x / sqrt(mean(x^2) + eps) * gamma over one axis; the mean of
+    squares in float32 whatever the input's type."""
+    ax = p["axis"]
+    x = data.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=ax, keepdims=True)
+    shape = [1] * data.ndim
+    shape[ax] = data.shape[ax]
+    out = x * jax.lax.rsqrt(ms + p["eps"]) \
+        * gamma.astype(jnp.float32).reshape(shape)
+    return out.astype(data.dtype)
+
+
+@register("RotaryEmbedding",
+          params_spec=(Param("base", float, 10000.0),
+                       Param("offset", int, 0),
+                       Param("dim", int, 0)),
+          hint="rotaryembedding")
+def _rotary_embedding(p, c, data):
+    """Rotary position embedding on a slice of the head dimension.
+
+    ``data`` (batch, time, heads, head_dim); position t is the index
+    along axis 1.  Dims ``offset .. offset + dim`` of the last axis
+    (``dim`` 0: to the end) are rotated in the rotate-half pairing, dim
+    i with dim i + dim/2, by the angle t * base**(-2i/dim); the other
+    dims pass through.  Angles and the rotation in float32."""
+    lo = p["offset"]
+    n = p["dim"] or data.shape[-1] - lo
+    half = n // 2
+    inv_freq = p["base"] ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / n)
+    ang = jnp.arange(data.shape[1], dtype=jnp.float32)[:, None] * inv_freq
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x = data[..., lo:lo + n].astype(jnp.float32)
+    x1, x2 = x[..., :half], x[..., half:]
+    rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                          axis=-1).astype(data.dtype)
+    return jnp.concatenate([data[..., :lo], rot, data[..., lo + n:]],
+                           axis=-1)
 
 
 @register("LeakyReLU",
@@ -611,7 +656,7 @@ def _softmax_output_bwd(pspec, res, g):
             valid = (label != p.get("ignore_label", -1.0)).astype(out.dtype)
             grad = grad * jnp.expand_dims(valid, 1)
     elif label.ndim == out.ndim:
-        grad = out - label  # dense label
+        grad = out - label.astype(out.dtype)  # dense label
         valid = jnp.ones(label.shape[:1], out.dtype)
     else:
         oh = jax.nn.one_hot(label.astype(jnp.int32), out.shape[-1],
@@ -901,8 +946,16 @@ def _ln_infer_shape(p, in_shapes):
     return [tuple(dshape), (n,), (n,)], [tuple(dshape)], []
 
 
+def _rms_infer_shape(p, in_shapes):
+    dshape = in_shapes[0]
+    if dshape is None or 0 in dshape:
+        return None
+    return [tuple(dshape), (dshape[p["axis"]],)], [tuple(dshape)], []
+
+
 # registry fixups: attach custom bidirectional shape inference
 _reg_mod.get("LayerNorm").infer_shape = _ln_infer_shape
+_reg_mod.get("RMSNorm").infer_shape = _rms_infer_shape
 _reg_mod.get("FullyConnected").infer_shape = _fc_infer_shape
 _reg_mod.get("Convolution").infer_shape = _conv_infer_shape
 alias("Convolution_v1", "Convolution")

@@ -29,9 +29,20 @@ for XLA).  Callers that care about routing health should surface the
 fraction via :func:`record_dropped_frac`, which backs the
 ``parallel.moe.dropped_frac`` obs counter; the trainer-side silent
 discard of ``keep`` is exactly what that counter exists to catch.
+
+**The no-drop path** — :func:`sigmoid_topk_route` and
+:func:`moe_apply_held` are the mathematics of the Symbol ops
+``MoERouter`` / ``MoEExperts`` (``op/moe.py``): sigmoid scores, a
+chosen set by score plus a correction bias, and a chip that holds
+``G`` of the ``E`` experts and computes its own experts' part for
+every entry routed to them, whatever the imbalance.  There is no
+capacity and no ``keep``: the entries are sorted by expert into a
+buffer sized for the worst case (all of them here) and the three
+products are grouped matrix products over the rows that are live.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -43,7 +54,8 @@ from .. import obs as _obs
 
 __all__ = ["moe_init", "moe_apply", "moe_apply_dense", "moe_apply_sparse",
            "moe_shardings", "moe_load_balance_loss", "moe_capacity",
-           "moe_dispatch_bytes", "record_dropped_frac"]
+           "moe_dispatch_bytes", "record_dropped_frac",
+           "sigmoid_topk_route", "moe_apply_held"]
 
 # last observed dropped-token fraction (registry-backed; scraped by
 # obs.snapshot() / tools/obs_report.py).  A fraction, set per call —
@@ -247,3 +259,106 @@ def moe_load_balance_loss(params, x, gates=None):
         jax.nn.one_hot(jnp.argmax(gates, -1), E, dtype=gates.dtype), axis=0)
     frac_gates = jnp.mean(gates, axis=0)
     return E * jnp.sum(frac_tokens * frac_gates)
+
+
+# ----------------------------------------------------------------------
+# the no-drop path: sigmoid routing, a chip's share of the experts
+def sigmoid_topk_route(logits, bias, top_k, scale=1.0):
+    """Sigmoid scores and the ``top_k`` experts a token by score plus
+    ``bias`` (the bias only chooses; equal sums go to the lower index).
+
+    ``logits`` (T, E) -> ``(expert (T, k) int32, weight (T, k), score
+    (T, E))`` in float32; a token's weights are its chosen scores over
+    their sum, times ``scale``.
+    """
+    score = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, expert = jax.lax.top_k(score + bias.astype(jnp.float32), top_k)
+    # the chosen scores by comparison, not by gather: a gather's
+    # reverse mode is a scatter, which the chip runs serially
+    chosen = expert[:, :, None] == jnp.arange(score.shape[1])
+    weight = jnp.sum(jnp.where(chosen, score[:, None, :], 0.0), axis=-1)
+    weight = scale * weight / jnp.sum(weight, axis=-1, keepdims=True)
+    return expert.astype(jnp.int32), weight, score
+
+
+# Entries move between their own order (entry n is token n // k) and the
+# order sorted by expert through two gathers that are each other's
+# reverse mode: the scatter autodiff would write runs serially on the chip.
+def _collect_rows(ys, inv, k):
+    """(N, d) sorted rows -> (N / k, d): a token's k entries summed."""
+    return ys[inv].reshape(-1, k, ys.shape[1]).sum(axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _spread(x, order, inv, k):
+    """(T, d) -> (T k, d): sorted slot s gets the row of the token its
+    entry ``order[s]`` belongs to (entry n is token n // k).  The reverse
+    mode is :func:`_collect_rows`, a gather too."""
+    return x[order // k]
+
+
+_spread.defvjp(lambda x, order, inv, k: (x[order // k], inv),
+               lambda k, inv, g: (_collect_rows(g, inv, k), None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _collect(ys, order, inv, k):
+    """:func:`_collect_rows`, with :func:`_spread` as its reverse mode."""
+    return _collect_rows(ys, inv, k)
+
+
+_collect.defvjp(lambda ys, order, inv, k: (_collect_rows(ys, inv, k), order),
+                lambda k, order, g: (g[order // k], None, None))
+
+
+def _grouped_matmul(lhs, rhs, sizes, live):
+    """Row r of ``lhs`` (N, k) times ``rhs[g]`` (G, n, k) transposed,
+    g the group whose run of ``sizes[g]`` sorted rows holds r; rows past
+    the last group (``live`` (N, 1) false) are 0.  ``lax.ragged_dot``
+    walks the row tiles that are live and costs no product for the rest
+    (PERF.md, PR 30: the TPU compiler's own kernels, level with the
+    Pallas megablox kernel on the v5e).  Those kernels leave the rows
+    they skip UNWRITTEN, in reverse mode too: the select makes them 0
+    here and keeps what comes back for them out of the transposes, so
+    that no stale NaN meets a product (0 x NaN is NaN)."""
+    out = jax.lax.ragged_dot(lhs, rhs.swapaxes(1, 2), sizes)
+    return jnp.where(live, out, jnp.zeros((), out.dtype))
+
+
+def moe_apply_held(x, expert, weight, w_gate, w_up, w_down, first_expert,
+                   num_experts):
+    """The routed part of an expert layer that a chip holding experts
+    ``first_expert .. first_expert + G`` of ``num_experts`` computes:
+
+        y[t] = sum over j with e = expert[t, j] held of weight[t, j] * F_e(x[t])
+        F_e(v) = (silu(v W_gate[e]^T) * v W_up[e]^T) W_down[e]^T
+
+    ``x`` (T, d); ``expert``/``weight`` (T, k) from
+    :func:`sigmoid_topk_route`; ``w_gate``/``w_up`` (G, h, d), ``w_down``
+    (G, d, h).  Returns ``(y (T, d), count (num_experts,) float32)``,
+    the count of entries routed to each of all the experts.  Nothing is
+    dropped: the T*k entries are sorted by expert, those of absent
+    experts last, into a buffer of T*k rows, and the grouped products
+    run over the held experts' rows alone.
+    """
+    k = expert.shape[1]
+    N, G = x.shape[0] * k, w_gate.shape[0]
+    ef = expert.reshape(N)
+    local = ef - first_expert
+    key = jnp.where((local >= 0) & (local < G), local, G)
+    order = jnp.argsort(key, stable=True)            # sorted slot -> entry
+    inv = jnp.argsort(order)                         # entry -> sorted slot
+    sizes = jnp.sum(key[:, None] == jnp.arange(G), axis=0, dtype=jnp.int32)
+    live = (jnp.arange(N) < jnp.sum(sizes))[:, None]
+
+    # the select on xs is for the way back: what the transposes return
+    # for rows past the groups must not reach the tokens' gradient
+    xs = jnp.where(live, _spread(x, order, inv, k), jnp.zeros((), x.dtype))
+    h = jax.nn.silu(_grouped_matmul(xs, w_gate, sizes, live)) \
+        * _grouped_matmul(xs, w_up, sizes, live)
+    ys = _grouped_matmul(h.astype(x.dtype), w_down, sizes, live)
+    ws = _spread(weight.reshape(N, 1), order, inv, 1).astype(ys.dtype)
+    y = _collect(ys * ws, order, inv, k)
+    count = jnp.sum(ef[:, None] == jnp.arange(num_experts), axis=0,
+                    dtype=jnp.float32)
+    return y.astype(x.dtype), count

@@ -82,6 +82,44 @@ def _seeds_reach_grads(symbol) -> bool:
     return True
 
 
+# where a graph reads an input as indices, and the ops that hand values on
+# unchanged (shape, slicing, and arithmetic with a scalar)
+_INDEX_SLOTS = {"Embedding": ("data",), "SoftmaxOutput": ("label",)}
+_VALUE_KEEPING = frozenset((
+    "Reshape", "Flatten", "expand_dims", "transpose", "slice_axis", "slice",
+    "Concat", "SliceChannel", "BlockGrad", "_mul_scalar", "_plus_scalar",
+    "_minus_scalar"))
+
+
+def _index_inputs(nodes) -> frozenset:
+    """Names of the variables a graph (its ``nodes``) consumes as
+    indices: those that reach ``Embedding``'s data or ``SoftmaxOutput``'s
+    label directly or through value-keeping ops.  A class or token id
+    does not survive the cast to bfloat16 (exact to 256), so
+    ``_forward`` leaves these as they were fed, float32 by MXNet's
+    convention included."""
+    found = set()
+    stack = []
+    for n in nodes:
+        if n.is_variable:
+            continue
+        slots = _INDEX_SLOTS.get(n.op.name, ())
+        for slot, (src, _) in zip(n.op.list_inputs(n.params), n.inputs):
+            if slot in slots:
+                stack.append(src)
+    seen = set()
+    while stack:
+        n = stack.pop()
+        if id(n) in seen:
+            continue
+        seen.add(id(n))
+        if n.is_variable:
+            found.add(n.name)
+        elif n.op.name in _VALUE_KEEPING:
+            stack.extend(src for src, _ in n.inputs)
+    return frozenset(found)
+
+
 def remat_policy(name):
     """Resolve a rematerialization policy for the fused step.
 
@@ -455,6 +493,7 @@ class Trainer:
                         "paths" % (oname, tuple(oshape or ()), bsz))
         self._arg_shapes = dict(zip(self.prog.arg_names, arg_shapes))
         self._aux_shapes = dict(zip(self.aux_names, aux_shapes))
+        self._bind_moe_gauges(shapes)
         self._input_shapes = {n: self._arg_shapes[n]
                               for n in self.data_names + self.label_names}
         if self.grad_accum > 1:
@@ -472,6 +511,60 @@ class Trainer:
                            ndata))
         self._build()
         return self
+
+    # ------------------------------------------------ expert-layer load
+    def _bind_moe_gauges(self, shapes):
+        """The ``moe.*`` obs gauges of a graph with ``MoEExperts`` nodes:
+        what the chip holds and is sent, set here, and how evenly, read
+        from the nodes' count state whenever a snapshot is taken (a
+        pull: the step carries the counts as it carries BatchNorm's
+        statistics and nothing reads them on its path)."""
+        layers = [n for n in self.prog.nodes
+                  if not n.is_variable and n.op.name == "MoEExperts"]
+        self._moe_layers = [
+            (n.name + "_count", n.params["first_expert"],
+             n.params["first_expert"] + n.params["experts_held"])
+            for n in layers]
+        if not layers:
+            return
+        inner = self.symbol.get_internals()
+        shape_of = dict(zip(inner.list_outputs(),
+                            inner.infer_shape(**shapes)[1]))
+        entries = 0
+        for n in layers:
+            src, i = n.inputs[1]              # the router's chosen experts
+            entries += int(np.prod(shape_of[
+                "%s_%s" % (src.name, src.op.list_outputs(src.params)[i])]))
+        _obs.gauge("moe.experts_held").set(
+            max(hi - lo for _, lo, hi in self._moe_layers))
+        _obs.gauge("moe.entries_per_step").set(entries)
+        _obs.REGISTRY.pull(self._pull_moe_gauges)
+
+    def _pull_moe_gauges(self):
+        """``moe.held_entries_share``: of the last step's routing
+        entries, the share sent to experts this chip holds, over all
+        expert layers.  ``moe.load_max_over_mean``: the fullest held
+        expert's entries over the mean held expert's."""
+        if self.aux is None:
+            return
+        counts = jax.device_get([self.aux[name]
+                                 for name, _, _ in self._moe_layers])
+        held = np.concatenate([np.asarray(c)[lo:hi] for c, (_, lo, hi)
+                               in zip(counts, self._moe_layers)])
+        total = float(sum(np.sum(c) for c in counts))
+        if total and held.sum():
+            _obs.gauge("moe.held_entries_share").set(
+                float(held.sum()) / total)
+            _obs.gauge("moe.load_max_over_mean").set(
+                float(held.max() / held.mean()))
+
+    def __del__(self):
+        # a trainer that goes away leaves its last reading in the gauges
+        try:
+            if getattr(self, "_moe_layers", None):
+                self._pull_moe_gauges()
+        except Exception:          # noqa: BLE001 — never raise from a finalizer
+            pass
 
     def _param_sharding(self, name):
         if self.mesh is None:
@@ -782,6 +875,7 @@ class Trainer:
         arg_names = prog.arg_names
         aux_names = self.aux_names
         compute_dtype = self.compute_dtype
+        index_inputs = _index_inputs(prog.nodes)
         init_fn, update_fn = make_update_fn(self.optimizer, self.param_names)
         self._update_fn = update_fn
 
@@ -798,7 +892,8 @@ class Trainer:
                               if jnp.issubdtype(v.dtype, jnp.floating) else v)
                           for n, v in params.items()}
                 batch = {n: (v.astype(compute_dtype)
-                             if jnp.issubdtype(v.dtype, jnp.floating) else v)
+                             if jnp.issubdtype(v.dtype, jnp.floating)
+                             and n not in index_inputs else v)
                          for n, v in batch.items()}
                 aux_vals = [(v.astype(compute_dtype)
                              if jnp.issubdtype(v.dtype, jnp.floating) else v)

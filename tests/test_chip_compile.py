@@ -46,6 +46,7 @@ SHAPES = [
     pytest.param((2, 8192, 8, 64), jnp.bfloat16, True, id="long-context"),
     pytest.param((4, 2048, 8, 128), jnp.bfloat16, True, id="head-dim-128"),
     pytest.param((4, 1000, 8, 64), jnp.float32, False, id="ragged-f32"),
+    pytest.param((1, 4096, 20, 256), jnp.bfloat16, True, id="glm-4.7-flash"),
 ]
 
 
@@ -70,3 +71,26 @@ def test_flash_gradient_compiles_for_v5e(v5e, shape, dtype, causal):
 
     grad = jax.grad(loss, argnums=(0, 1, 2))
     assert "tpu_custom_call" in _compiled_text(grad, shape, dtype, v5e)
+
+
+def test_expert_layer_compiles_to_the_chips_grouped_kernels(v5e):
+    """``MoEExperts``' mathematics at glm-4.7-flash's widths (4,096 rows
+    x 4 entries, 8 of 64 experts held): forward and gradient compile for
+    the v5e, and the three grouped products are the compiler's own
+    ragged-dot kernels, which walk the live row tiles, not a dense
+    product an expert over the whole worst-case buffer."""
+    from mxnet_tpu.parallel import moe
+
+    def loss(x, idx, w, wg, wu, wd):
+        y, count = moe.moe_apply_held(x, idx, w, wg, wu, wd, 0, 64)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(count)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 2, 3, 4, 5))).lower(
+        spec((4096, 2048)), spec((4096, 4), jnp.int32),
+        spec((4096, 4), jnp.float32), spec((8, 1536, 2048)),
+        spec((8, 1536, 2048)), spec((8, 2048, 1536))).compile().as_text()
+    assert text.count("ragged-dot-metadata") >= 3
+    assert "tpu_custom_call" in text

@@ -330,7 +330,7 @@ R.set_state(_R_STATE)
 G("Pooling", {"data": randn(1, 2, 4, 4)},
   {"kernel": (2, 2), "stride": (2, 2), "pool_type": "avg"},
   id_suffix="avg")
-for act in ["relu", "sigmoid", "tanh", "softrelu"]:
+for act in ["relu", "sigmoid", "tanh", "softrelu", "gelu", "silu"]:
     G("Activation", {"data": nz(2, 3)}, {"act_type": act}, id_suffix=act)
 G("LeakyReLU", {"data": nz(2, 3)}, {"act_type": "leaky", "slope": 0.1})
 G("Dropout", {"data": randn(2, 3)}, {"p": 0.0})
@@ -355,6 +355,27 @@ G("InstanceNorm",
   rtol=8e-2, atol=2e-2)
 G("LayerNorm",
   {"data": randn(2, 6), "gamma": pos(6), "beta": randn(6)},
+  rtol=8e-2, atol=2e-2)
+G("RMSNorm", {"data": randn(2, 6), "gamma": pos(6)}, rtol=8e-2, atol=2e-2)
+G("RotaryEmbedding", {"data": randn(1, 3, 2, 8)},
+  {"base": 100.0, "offset": 4, "dim": 4})
+# expert layer: the router's choice is a step function of its inputs, so
+# its contract is checked forward; the experts' part is smooth in the
+# data, the router's weights and the three leaves
+F("MoERouter", {"data": distinct(3, 4) - 1.0, "weight": distinct(6, 4) - 1.0},
+  {"num_experts": 6, "top_k": 2, "scale": 1.8},
+  aux={"bias": np.zeros(6, "f")}, out=1,
+  fwd=lambda data, weight: (lambda s: 1.8 * np.sort(s, 1)[:, :-3:-1]
+                            / np.sort(s, 1)[:, -2:].sum(1, keepdims=True))(
+      1 / (1 + np.exp(-data @ weight.T))))
+G("MoEExperts",
+  {"data": randn(5, 4), "expert": np.array(
+      [[0, 3], [1, 2], [5, 1], [2, 4], [1, 0]], "f"),
+   "weight": pos(5, 2), "gate_weight": randn(2, 3, 4) * 0.5,
+   "up_weight": randn(2, 3, 4) * 0.5, "down_weight": randn(2, 4, 3) * 0.5},
+  {"num_experts": 6, "experts_held": 2, "first_expert": 1, "num_hidden": 3},
+  aux={"count": np.zeros(6, "f")},
+  grad_nodes=["data", "weight", "gate_weight", "up_weight", "down_weight"],
   rtol=8e-2, atol=2e-2)
 G("L2Normalization", {"data": nz(2, 6)})
 G("LRN", {"data": pos(1, 3, 3, 3)}, {"nsize": 3}, rtol=8e-2, atol=2e-2)
