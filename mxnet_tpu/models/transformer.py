@@ -2,9 +2,10 @@
 
 The reference predates attention models (SURVEY §5: no attention op in
 the tree), but long-context is first-class here: the attention core is
-the Pallas flash-attention kernel (``op/pallas/flash_attention.py``,
-streamed K/V tiles, O(T) memory) through the ``DotProductAttention``
-op, and the same symbol trains with sequence parallelism via
+the Pallas flash-attention kernels (``op/pallas/flash_attention.py``:
+forward and backward each one kernel, score tiles in VMEM, O(T) memory,
+dead causal tiles skipped) through the ``DotProductAttention`` op, and
+the same symbol trains with sequence parallelism via
 ``parallel.ring_attention_sharded`` (see ``examples/long-context``).
 
 Pre-norm blocks: x + Attn(LN(x)), x + MLP(LN(x)); learned positional

@@ -7,12 +7,27 @@ registers/VMEM, so HBM traffic is O(T) per q-block instead of the O(T^2)
 score matrix.  The MXU sees two big matmuls per tile (QK^T and PV) in
 float32 accumulation.
 
-Backward is the standard recomputation form (no score matrix saved — only
-the per-row logsumexp): a ``lax.scan`` over K blocks recomputes P from
-(Q, K, lse) and accumulates dQ/dK/dV, keeping memory O(T * block_k).  XLA
-fuses each scan body into a handful of MXU calls, so a hand-written Pallas
-backward buys little on TPU; the forward kernel is where manual blocking
-wins.
+Backward is a Pallas kernel too, the standard recomputation form (no score
+matrix saved, only the per-row logsumexp): one grid step per (group of
+heads, k-block, q-block) recomputes the tile's P from (Q, K, lse) and feeds
+five MXU products (S, dP, dV, dK, dQ); S, P, dP and dS live in VMEM and
+never reach HBM, and tiles wholly above the causal diagonal are skipped.
+The tile is held transposed, ``[block_k, block_q]``, so the per-row ``lse``
+and ``delta = sum(dO * O)`` are lane-dense rows ``[1, block_q]`` that
+broadcast over sublanes: no lane-replicated ``[t, 128]`` copy of either
+exists, in the residuals or in the backward.  dK/dV accumulate in float32
+scratch across the q-blocks of one k-block, dQ in a float32 scratch that
+stays resident for the whole group (``4 * t_q * 128`` bytes at 64-wide
+heads) across the k-blocks.  Heads narrower than the 128 lanes go through
+the kernel side by side, ``[b*h/g, t, g*d]``: its arrays fill their HBM
+tiles, and the residuals live between the passes as the graph has them,
+``[b, t, h, d]``, not as ``[b*h, t, 64]`` padded to 128 lanes (1.3 GB less
+at GPT-2 medium's 24 layers, for the same kernel time).  On a v5e the
+``lax.scan`` of einsums this replaces took 4.37 ms a layer at
+(8, 1024, 16, 64) and 10.97 ms at (1, 4096, 20, 256), layout included;
+this takes 1.07 and 2.99 ms.  One fused kernel beat a dK/dV kernel plus a
+dQ kernel (seven products and every elementwise pass twice), 1.33 against
+1.82 ms and 3.13 against 4.46 ms (PERF.md §6, PR 31).
 
 The 2017-era reference has no attention op at all (SURVEY.md §5
 long-context); this is greenfield capability required for parity with
@@ -162,79 +177,207 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     return o[:, :t_q], lse.reshape(bh, t_qp)
 
 
-def _bwd_impl(q, k, v, o, lse, do, causal, scale, block_k):
-    """Blockwise recompute backward; all arrays [bh, t, d], lse [bh, t_qp].
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
-    The five einsums feed the MXU **in the input dtype** (bf16 for the
-    training path) with ``preferred_element_type=f32`` accumulation —
-    an f32 upcast first would run the MXU at a fraction of its bf16
-    rate and double the scan's HBM traffic.  The softmax recompute
-    (``exp``) and the ``ds`` combination stay in f32: they carry the
-    numerics; the matmul inputs don't (same contract as the forward
-    kernel's bf16-in/f32-accum design)."""
-    bh, t_q, d = q.shape
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                block_q, block_k, causal, masked, scale, t_kv_real,
+                heads, d):
+    # Grid is (groups of heads, n_kb, n_qb), queries innermost: one K/V
+    # tile stays in VMEM while the Q/dO tiles stream past it, dk/dv
+    # accumulate in scratch over the q-blocks, dq in a scratch row per
+    # q-block that lives across the k-blocks of this group.
+    kb = pl.program_id(1)
+    j = pl.program_id(2)
+    n_kb = pl.num_programs(1)
+    n_qb = pl.num_programs(2)
+
+    @pl.when(j == 0)
+    def _init_dkv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(kb == 0)
+    def _init_dq():
+        dq_acc[j] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+    first_q = j * block_q
+    first_k = kb * block_k
+    live = (first_k <= first_q + block_q - 1) if causal else True
+
+    @pl.when(live)
+    def _update():
+        # same contract as the forward: MXU operands in the storage
+        # dtype, float32 accumulation, the scale, the exponent, delta
+        # and the ds combination in float32.  The tile is [bk, bq].
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        if masked:
+            k_pos = first_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            mask = k_pos < t_kv_real
+            if causal:
+                q_pos = first_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                mask = jnp.logical_and(mask, q_pos >= k_pos)
+
+        def only(hd, x):
+            # `heads` heads lie side by side along the lanes.  One
+            # head's products come from operands with the other heads'
+            # lanes zeroed: a contraction over them adds nothing, a
+            # product with them lands in this head's lanes of the
+            # accumulator, and the MXU does the work of one d-wide
+            # product either way (its depth and width are 128).
+            if heads == 1:
+                return x
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, heads * d), 1)
+            return jnp.where(lane // d == hd, x, jnp.zeros_like(x))
+
+        for hd in range(heads):
+            q_h, k_h, do_h = only(hd, q), only(hd, k), only(hd, do)
+            s_t = jax.lax.dot_general(
+                k, q_h, _NT, preferred_element_type=jnp.float32) * scale
+            p_t = jnp.exp(s_t - lse_ref[0, hd:hd + 1, :])
+            if masked:
+                p_t = jnp.where(mask, p_t, 0.0)
+            dv_acc[...] += jax.lax.dot_general(
+                p_t.astype(do.dtype), do_h, _NN,
+                preferred_element_type=jnp.float32)
+            dp_t = jax.lax.dot_general(
+                v, do_h, _NT, preferred_element_type=jnp.float32)
+            ds_t = (p_t * (dp_t - delta_ref[0, hd:hd + 1, :])).astype(q.dtype)
+            dk_acc[...] += jax.lax.dot_general(
+                ds_t, q_h, _NN, preferred_element_type=jnp.float32)
+            dq_acc[j] += jax.lax.dot_general(
+                ds_t, k_h, _TN, preferred_element_type=jnp.float32)
+
+    @pl.when(j == n_qb - 1)
+    def _finalize_dkv():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(kb == n_kb - 1)
+    def _finalize_dq():
+        dq_ref[0] = (dq_acc[j] * scale).astype(dq_ref.dtype)
+
+
+def _pack(x, g):
+    """[b, t, h, d] -> [b*h/g, t, g*d]: ``g`` neighbouring heads side by
+    side along the lanes, so that heads narrower than the 128 lanes do
+    not leave the rest of every HBM tile and vector register empty."""
+    b, t, h, d = x.shape
+    return x.reshape(b, t, h // g, g * d).transpose(0, 2, 1, 3).reshape(
+        b * h // g, t, g * d)
+
+
+def _unpack(x, b, g):
+    n, t, gd = x.shape
+    return x.reshape(b, n // b, t, gd).transpose(0, 2, 1, 3).reshape(
+        b, t, n // b * g, gd // g)
+
+
+def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+              interpret):
+    """q/k/v/o/do: [b, t, h, d], lse: [b*h, t_q_pad] -> (dq, dk, dv)."""
+    b, t_q, h, d = q.shape
     t_kv = k.shape[1]
-    f32 = jnp.float32
-    mxu = q.dtype if q.dtype in (jnp.bfloat16, jnp.float16) else f32
-    qs = (q.astype(f32) * scale).astype(mxu)   # scale applied in f32
-    do_m = do.astype(mxu)
-    delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)  # [bh, t_q]
-    lse = lse[:, :t_q]
-
-    kp = _pad_time(k.astype(mxu), block_k)
-    vp = _pad_time(v.astype(mxu), block_k)
+    # as many neighbouring heads a grid step as fit the lanes
+    g = max(n for n in range(1, h + 1)
+            if h % n == 0 and (n == 1 or n * d <= _LANES))
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    qp = _pad_time(_pack(q, g), block_q)
+    dop = _pad_time(_pack(do, g), block_q)   # zero rows: no gradient
+    kp = _pad_time(_pack(k, g), block_k)
+    vp = _pad_time(_pack(v, g), block_k)
+    n, t_qp, gd = qp.shape
     t_kvp = kp.shape[1]
+    n_qb = t_qp // block_q
     n_kb = t_kvp // block_k
-    kb_arr = kp.reshape(bh, n_kb, block_k, d).transpose(1, 0, 2, 3)
-    vb_arr = vp.reshape(bh, n_kb, block_k, d).transpose(1, 0, 2, 3)
+    # per-row state as lane-dense rows, a head a row; lse comes padded
+    # from the forward
+    lse = lse.reshape(n, g, t_qp)
+    delta = jnp.pad(delta.transpose(0, 2, 1).reshape(n, g, t_q),
+                    ((0, 0), (0, 0), (0, t_qp - t_q)))
 
-    q_pos = jnp.arange(t_q)
-
-    def body(dq, xs):
-        kb_idx, kblk, vblk = xs
-        s = jnp.einsum("btd,bkd->btk", qs, kblk,
-                       preferred_element_type=f32)
-        k_pos = kb_idx * block_k + jnp.arange(block_k)
-        mask = k_pos[None, :] < t_kv
+    def q_blk(kb, j):
+        # a dead tile (queries before this k-block) asks for the block the
+        # first live one will, so nothing is fetched for it
         if causal:
-            mask = jnp.logical_and(mask, q_pos[:, None] >= k_pos[None, :])
-        s = jnp.where(mask[None], s, _NEG_INF)
-        # exp(-inf - lse) -> 0 even when lse == -inf thanks to the where
-        p = jnp.where(mask[None], jnp.exp(s - lse[..., None]), 0.0)
-        p_m = p.astype(mxu)
-        dv_blk = jnp.einsum("btk,btd->bkd", p_m, do_m,
-                            preferred_element_type=f32)
-        dp = jnp.einsum("btd,bkd->btk", do_m, vblk,
-                        preferred_element_type=f32)
-        ds = (p * (dp - delta[..., None])).astype(mxu)
-        dq = dq + jnp.einsum("btk,bkd->btd", ds, kblk,
-                             preferred_element_type=f32) * scale
-        dk_blk = jnp.einsum("btk,btd->bkd", ds, qs,
-                            preferred_element_type=f32)
-        return dq, (dk_blk, dv_blk)
+            j = jnp.minimum(jnp.maximum(j, kb * block_k // block_q),
+                            n_qb - 1)
+        return j
 
-    dq0 = jnp.zeros((bh, t_q, d), f32)
-    dq, (dk_b, dv_b) = jax.lax.scan(
-        body, dq0, (jnp.arange(n_kb), kb_arr, vb_arr))
-    dk = dk_b.transpose(1, 0, 2, 3).reshape(bh, t_kvp, d)[:, :t_kv]
-    dv = dv_b.transpose(1, 0, 2, 3).reshape(bh, t_kvp, d)[:, :t_kv]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    q_spec = pl.BlockSpec((1, block_q, gd),
+                          lambda i, kb, j: (i, q_blk(kb, j), 0))
+    row_spec = pl.BlockSpec((1, g, block_q),
+                            lambda i, kb, j: (i, 0, q_blk(kb, j)))
+    kv_spec = pl.BlockSpec((1, block_k, gd), lambda i, kb, j: (i, kb, 0))
+    # dq's block stays put until the last k-block, so each block goes to
+    # HBM once, after its last contribution
+    dq_spec = pl.BlockSpec(
+        (1, block_q, gd),
+        lambda i, kb, j: (i, jnp.where(kb == n_kb - 1, j, 0), 0))
+    kernel = functools.partial(
+        _bwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
+        masked=causal or t_kvp != t_kv, scale=scale, t_kv_real=t_kv,
+        heads=g, d=d)
+    from jax.experimental.pallas import tpu as pltpu
+    kwargs = {}
+    if not interpret:
+        lanes = -(-gd // _LANES) * _LANES
+        vmem = (4 * t_qp * lanes                          # dq, resident
+                + 2 * 4 * block_k * lanes                 # dk, dv
+                + 12 * (block_q + block_k) * lanes * q.dtype.itemsize
+                + 6 * 4 * block_q * block_k)              # the tile's values
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(max(2 * vmem, 32 << 20), 100 << 20))
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid=(n, n_kb, n_qb),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[dq_spec, kv_spec, kv_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(qp.shape, q.dtype),
+            jax.ShapeDtypeStruct(kp.shape, k.dtype),
+            jax.ShapeDtypeStruct(vp.shape, v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((n_qb, block_q, gd), jnp.float32),  # dq
+            pltpu.VMEM((block_k, gd), jnp.float32),        # dk
+            pltpu.VMEM((block_k, gd), jnp.float32),        # dv
+        ],
+        interpret=interpret,
+        name="flash_attention_bwd",
+        **kwargs,
+    )(qp, kp, vp, dop, lse, delta)
+    return (_unpack(dq[:, :t_q], b, g), _unpack(dk[:, :t_kv], b, g),
+            _unpack(dv[:, :t_kv], b, g))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, _ = _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret)
-    return o
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k,
+                      interpret)[0]
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, lse = _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret)
+    # the forward kernel takes [b*h, t, d]
+    o, lse = _fwd_impl(_pack(q, 1), _pack(k, 1), _pack(v, 1), causal, scale,
+                       block_q, block_k, interpret)
+    o = _unpack(o, q.shape[0], 1)
+    # what lives between the passes is q, k, v and o as the graph has
+    # them, [b, t, h, d], and lse [b*h, t] float32: each pass folds them
+    # its own way
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
     q, k, v, o, lse = res
-    return _bwd_impl(q, k, v, o, lse, do, causal, scale, block_k)
+    return _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+                     interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -252,8 +395,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    b, t_q, h, d = q.shape
-    t_kv = k.shape[1]
+    t_q, t_kv, d = q.shape[1], k.shape[1], q.shape[3]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
@@ -267,12 +409,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     block_q = clamp(block_q, t_q)
     block_k = clamp(block_k, t_kv)
 
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
-
-    o = _flash(fold(q), fold(k), fold(v), causal, float(scale),
-               block_q, block_k, interpret)
-    return o.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)
+    return _flash(q, k, v, causal, float(scale), block_q, block_k, interpret)
 
 
 def flash_attention_reference(q, k, v, causal=False, scale=None):

@@ -31,24 +31,77 @@ def test_flash_forward_matches_reference(tq, tkv, causal):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_gradients_match_reference(causal):
+# (t_q, t_kv, causal, heads, head, block_q, block_k, dtype): the
+# forward test's shapes, the two 48 x 48 cases this test began with, blocks
+# that differ, a head of 128, bfloat16 inputs, lengths where the causal
+# mask or the padding leaves whole key columns (32 x 128) or key blocks
+# (96 x 40: t_q > t_kv) without a single live score, and head counts and
+# widths at which the backward takes two, four or one head a grid step
+_GRAD_CASES = [
+    pytest.param(48, 48, False, 2, 8, 16, 16, jnp.float32,
+                 id="48x48"),
+    pytest.param(48, 48, True, 2, 8, 16, 16, jnp.float32,
+                 id="48x48-causal"),
+    pytest.param(37, 53, False, 2, 16, 32, 32, jnp.float32,
+                 id="ragged-37x53"),
+    pytest.param(100, 100, True, 2, 16, 32, 32, jnp.float32,
+                 id="ragged-causal-100x100"),
+    pytest.param(32, 128, True, 2, 16, 32, 32, jnp.float32,
+                 id="cross-32x128-causal"),
+    pytest.param(96, 40, True, 2, 16, 32, 32, jnp.float32,
+                 id="masked-96x40-causal"),
+    pytest.param(100, 100, True, 2, 16, 32, 16, jnp.float32,
+                 id="blocks-32x16-causal"),
+    pytest.param(70, 90, False, 2, 16, 16, 64, jnp.float32,
+                 id="blocks-16x64"),
+    pytest.param(64, 64, True, 2, 128, 32, 32, jnp.float32,
+                 id="head-128"),
+    pytest.param(64, 64, True, 4, 32, 32, 32, jnp.float32,
+                 id="four-heads-of-32-a-step"),
+    pytest.param(40, 72, False, 3, 48, 32, 32, jnp.float32,
+                 id="three-heads-of-48-one-a-step"),
+    pytest.param(64, 64, True, 2, 16, 32, 32, jnp.bfloat16,
+                 id="bf16"),
+    pytest.param(100, 100, True, 2, 64, 32, 32, jnp.bfloat16,
+                 id="bf16-ragged-causal-head-64"),
+]
+
+
+@pytest.mark.parametrize("tq,tkv,causal,h,d,block_q,block_k,dtype",
+                         _GRAD_CASES)
+def test_flash_gradients_match_reference(tq, tkv, causal, h, d, block_q,
+                                         block_k, dtype):
+    """dq, dk and dv of the Pallas backward (interpreted here) against
+    ``jax.grad`` of the O(T^2) oracle in float32."""
     rng = np.random.RandomState(1)
-    q, k, v = _qkv(rng, 2, 48, 48, 2, 8)
+    q, k, v = _qkv(rng, 2, tq, tkv, h, d)
 
     def loss_flash(q, k, v):
         return jnp.sum(jnp.sin(flash_attention(
-            q, k, v, causal=causal, block_q=16, block_k=16)))
+            q, k, v, causal=causal, block_q=block_q,
+            block_k=block_k).astype(jnp.float32)))
 
     def loss_ref(q, k, v):
         return jnp.sum(jnp.sin(flash_attention_reference(
             q, k, v, causal=causal)))
 
-    g = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-4)
+    low = (x.astype(dtype) for x in (q, k, v))
+    g = jax.grad(loss_flash, argnums=(0, 1, 2))(*low)
+    # the oracle sees what the kernel saw: the inputs as rounded to dtype
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(
+        *(x.astype(dtype).astype(jnp.float32) for x in (q, k, v)))
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    for name, a, b in zip(("dq", "dk", "dv"), g, gr):
+        assert a.dtype == dtype and a.shape == b.shape, name
+        a = np.asarray(a, np.float32)
+        b = np.asarray(b)
+        assert np.isfinite(a).all(), name
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
+        # a key no query may see (past t_q under the causal mask) gets no
+        # gradient at all, not a small one
+        if name != "dq":
+            dead = np.abs(b).max(axis=(0, 2, 3)) == 0
+            assert (a[:, dead] == 0).all(), name
 
 
 def test_flash_bf16_io():

@@ -65,12 +65,21 @@ def test_flash_forward_compiles_for_v5e(v5e, shape, dtype, causal):
 
 @pytest.mark.parametrize("shape,dtype,causal", SHAPES)
 def test_flash_gradient_compiles_for_v5e(v5e, shape, dtype, causal):
+    """The reverse mode is a kernel of its own, not a loop of einsums:
+    the forward's custom call alone does not pass, and a tile the v5e's
+    compiler refuses (VMEM at head 256 over 4,096 positions) fails
+    here."""
     def loss(q, k, v):
         o = flash_attention(q, k, v, causal=causal, interpret=False)
         return jnp.sum(o.astype(jnp.float32))
 
     grad = jax.grad(loss, argnums=(0, 1, 2))
-    assert "tpu_custom_call" in _compiled_text(grad, shape, dtype, v5e)
+    text = _compiled_text(grad, shape, dtype, v5e)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2, len(calls)       # the forward and the backward
+    assert sum("flash_attention_bwd" in line for line in calls) == 1
+    assert " while(" not in text
 
 
 def test_expert_layer_compiles_to_the_chips_grouped_kernels(v5e):
