@@ -118,10 +118,11 @@ def cross_entropy(probs, labels):
 # train
 def build_module(mx, sym, data_shape, label_shape, context, seed,
                  arg_params=None, aux_params=None):
-    """bench.py's recipe: Module -> fused Trainer via dist_sync_tpu.
-    Gradients are summed over the labels, so they are rescaled by their
-    count.  The rate is a fifth of bench.py's: a few steps on one fixed
-    batch have to fall, and two runs of them have to stay comparable."""
+    """The benchmark's recipe (``benchmark/lib/trainjob.py``): Module ->
+    fused Trainer via dist_sync_tpu.  Gradients are summed over the
+    labels, so they are rescaled by their count.  The rate is a fifth of
+    the benchmark's 0.02: a few steps on one fixed batch have to fall,
+    and two runs of them have to stay comparable."""
     mod = mx.mod.Module(context=context, symbol=sym,
                         compute_dtype="bfloat16")
     mod.bind(data_shapes=[("data", data_shape)],

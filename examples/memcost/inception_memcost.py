@@ -84,7 +84,7 @@ def main(argv=None):
               "at %.2fx the flops" % (100.0 * none / max(full, 1e-9),
                                       flop_ratio))
         # the planning contract: saving fewer residuals must not RAISE
-        # the temp allocation (chip-measured numbers: REMAT_SWEEP.json)
+        # the temp allocation
         assert none <= full * 1.05, (none, full)
     else:
         print("backend reports no temp-allocation stats (CPU); flop "

@@ -1,6 +1,6 @@
 """Lint baseline: the ratchet that gates CI on NEW error findings.
 
-Mirrors the ``STEP_BYTE_BUDGET.json`` pattern (``tools/step_breakdown.py``):
+The pattern ``COMM_BASELINE.json`` and ``MEM_BASELINE.json`` share:
 a checked-in ``LINT_BASELINE.json`` records, per linted model, the
 finding counts at the last intentional ratchet.  ``--check`` fails when
 any rule produces MORE error-severity findings than the baseline allows
@@ -82,7 +82,7 @@ def write_baseline(reports: Dict[str, LintReport], path=None,
     """Record ``reports`` into the baseline file.  ``extras`` merges
     additional per-model fields into each entry (the comm linter
     records ``comm_gb_per_step`` beside the finding counts, the
-    STEP_BYTE_BUDGET pattern)."""
+    figure its ``comm-budget`` rule ratchets)."""
     path = path or BASELINE_PATH
     baseline = load_baseline(path) or {}
     for model, report in reports.items():

@@ -21,7 +21,7 @@ element count, predicted wire bytes
   paid to shard state and then paid again to unshard it.
 * ``comm-budget`` (error) — total predicted wire GB/step regressed past
   the checked-in ``COMM_BASELINE.json`` figure (the
-  ``STEP_BYTE_BUDGET.json`` ratchet semantics — tolerance_pct, ratchet
+  ``analysis.baseline.run_gate`` semantics — tolerance_pct, ratchet
   with ``--write-baseline``).
 * ``rank-divergent-collective`` (error, source level) — Python control
   flow conditioned on ``rank``/``process_index`` guarding a
@@ -361,7 +361,7 @@ class ReshardingThrashPass(GraphPass):
 class CommBudgetPass(GraphPass):
     """Total predicted wire GB/step vs the checked-in baseline figure.
 
-    The ``STEP_BYTE_BUDGET.json`` ratchet semantics: regression past
+    The ``analysis.baseline.run_gate`` semantics: regression past
     ``tolerance_pct`` is an ERROR (the CI gate fails on it as a new
     error finding); an improvement past the same tolerance is reported
     INFO so the baseline gets ratcheted down with

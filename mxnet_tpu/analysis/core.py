@@ -52,8 +52,8 @@ class Finding:
     ``node`` is the symbol node the finding anchors to (``<graph>`` for
     whole-graph findings); ``layer`` is the source layer a jaxpr-level
     finding was attributed to via the executor's per-node
-    ``jax.named_scope`` (the same correlation ``tools/step_breakdown.py``
-    uses for HBM byte attribution).  ``detail`` carries structured
+    ``jax.named_scope`` (the same correlation ``benchmark/lib/tracered.py``
+    uses for a traced op's scope).  ``detail`` carries structured
     provenance: op params, symbol attrs, shapes, dims.
     """
 

@@ -5,9 +5,9 @@ or over the Trainer's fused step) exposes what autodiff and the op
 bodies actually emit: dtype widenings, host callbacks, buffer-donation
 gaps, unfused gather/scatter.  Findings are attributed back to symbol
 layers through each equation's name stack — the same per-node
-``jax.named_scope`` the executor stamps for
-``tools/step_breakdown.py``'s HBM byte attribution, so lint provenance
-and byte attribution agree.
+``jax.named_scope`` the executor stamps, which the benchmark's trace
+reduction (``benchmark/lib/tracered.py``) reads too, so lint provenance
+and a traced op's attribution agree.
 """
 from __future__ import annotations
 
@@ -35,8 +35,8 @@ def layer_of_eqn(eqn, prefix: str = "") -> Tuple[Optional[str], bool]:
     The executor's per-node ``jax.named_scope`` leaves the symbol node
     name as a stack component — plain (``conv0``), or autodiff-wrapped:
     ``jvp(conv0)`` forward, ``transpose(jvp(conv0))`` backward.  Deepest
-    symbol scope wins (mirrors ``step_breakdown.layer_from_op_name``,
-    which parses the same stack out of XLA instruction metadata).
+    symbol scope wins (``benchmark/lib/tracered.py: scope_of`` parses
+    the same stack out of a traced op's XLA metadata).
 
     ``prefix`` is the accumulated name stack of the ENCLOSING call
     equations (:func:`iter_eqns_scoped`): an equation inside a
@@ -397,8 +397,8 @@ class GatherScatterPass(GraphPass):
     warn.  Which scopes are ``Pooling`` nodes is read from ``ctx.view``;
     without one nothing can be told apart and nothing warns.  Every
     other gather/scatter is legitimate (embeddings) and is reported as
-    info counts per layer so the byte attribution in
-    ``tools/step_breakdown.py`` has a trace-time cross-check.
+    info counts per layer so a traced step's per-scope breakdown
+    (``benchmark/lib/tracered.py``) has a trace-time cross-check.
     """
 
     name = "gather-scatter"

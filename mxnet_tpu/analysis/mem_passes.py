@@ -38,7 +38,7 @@ argmax program point, and a per-layer breakdown of what is live at
 the peak.  Rules on top (pass level ``"mem"``):
 
 * ``mem-budget`` (error) — predicted peak regressed past the
-  checked-in ``MEM_BASELINE.json`` figure (the ``STEP_BYTE_BUDGET``
+  checked-in ``MEM_BASELINE.json`` figure (``COMM_BASELINE.json``'s
   ratchet semantics, via the shared ``analysis.baseline.run_gate``).
 * ``mem-capacity`` (error) — predicted peak exceeds ``MXTPU_HBM_BYTES``
   or the detected device memory: the OOM-before-you-run gate.
@@ -53,9 +53,9 @@ the peak.  Rules on top (pass level ``"mem"``):
 CLI: ``tools/mem_lint.py`` (``--check`` gates CI against
 ``MEM_BASELINE.json``).  Consumers: ``tools/autotune.py`` (memory
 feasibility pruning), ``ModelServer.add_model``
-(``MXTPU_SERVE_MEM_BUDGET`` admission), ``bench.py``
-(``mem_model_peak_gb`` + measured-peak drift gate),
-``tools/step_breakdown.py --live``.  Docs:
+(``MXTPU_SERVE_MEM_BUDGET`` admission), ``tests/test_mem_lint.py``
+(the prediction against the compiler's ``memory_analysis()``),
+``tools/mem_lint.py --live``.  Docs:
 ``docs/how_to/static_analysis.md`` "Memory analysis".
 """
 from __future__ import annotations
@@ -457,7 +457,7 @@ def detect_capacity(default: Optional[int] = None) -> Optional[int]:
 @register_pass
 class MemBudgetPass(GraphPass):
     """Predicted peak GB/chip vs the checked-in baseline figure — the
-    ``STEP_BYTE_BUDGET.json`` ratchet semantics (regression past
+    ``COMM_BASELINE.json`` ratchet semantics (regression past
     ``tolerance_pct`` errors; an improvement past it is INFO so the
     baseline gets ratcheted down with ``--write-baseline``)."""
 

@@ -25,7 +25,7 @@ don't.
 ``calibrate_model(sym, arg_params, aux_params, calib_iter)`` — static
 post-training quantization: runs the float forward over a calibration
 set capturing per-activation ranges (billed to the producing symbol
-layer, i.e. the same ``named_scope`` names step_breakdown and
+layer, i.e. the same ``named_scope`` names a traced step and
 graph_lint report), then emits a symbol whose conv/FC data inputs are
 statically quantized to int8 with precomputed per-tensor scales.
 Numerically sensitive ops (softmax, BatchNorm, norms, the output head)

@@ -117,10 +117,6 @@ KNOBS: Dict[str, _Knob] = dict((
        "device staging buffers ahead of the step"),
     _k("MXTPU_UPLOAD_CHUNKS", "int", 1, "io",
        "chunked async device_puts per host batch"),
-    _k("MXTPU_STREAM_DEPTH", "int", 2, "bench",
-       "bench stream-pipeline staging depth"),
-    _k("MXTPU_STREAM_CHUNKS", "int", 4, "bench",
-       "bench stream-pipeline upload chunks"),
     _k("MXTPU_DECODE_START_METHOD", "str", None, "io",
        "multiprocessing start method for decode workers"),
     # --- serving -------------------------------------------------------
@@ -249,38 +245,10 @@ KNOBS: Dict[str, _Knob] = dict((
        "mem-budget gate / bench drift tolerance"),
     _k("MXTPU_HBM_BYTES", "str", None, "analysis",
        "per-chip HBM capacity override for the mem-capacity gate"),
-    # --- bench / CI ----------------------------------------------------
-    _k("MXTPU_BENCH_PIPELINE_STEPS", "int", 24, "bench",
-       "timed pipeline window length"),
-    _k("MXTPU_BENCH_SENTINEL", "bool", True, "bench",
-       "run the sentinel-overhead probe"),
-    _k("MXTPU_BENCH_ZERO_AB", "bool", True, "bench",
-       "run the ZeRO/grad-dtype A/B"),
-    _k("MXTPU_BENCH_SERVING", "bool", True, "bench",
-       "run the serving probe"),
-    _k("MXTPU_BENCH_OBS", "bool", True, "bench",
-       "run the obs-overhead probe"),
-    _k("MXTPU_BENCH_ELASTIC", "bool", True, "bench",
-       "run the elastic recovery drill"),
-    _k("MXTPU_BENCH_PROGRAM", "bool", True, "bench",
-       "run the program-cache probe"),
-    _k("MXTPU_BENCH_INTEGRITY", "bool", True, "bench",
-       "run the integrity probes"),
-    _k("MXTPU_BENCH_STREAM_PROBE", "bool", True, "bench",
-       "run the streaming-pipeline window"),
-    _k("MXTPU_BENCH_TUNE", "bool", True, "bench",
-       "run the tune-plan A/B probe"),
-    _k("MXTPU_BENCH_FLEET", "bool", True, "bench",
-       "run the fleet scaling/churn/rollout probe"),
-    _k("MXTPU_BENCH_PARALLEL", "bool", True, "bench",
-       "run the parallel-workloads probe (MoE/pipeline/ring A/Bs + "
-       "composed transformer windows)"),
-    _k("MXTPU_BENCH_PARALLEL_STEPS", "int", 3, "bench",
-       "dispatches per timed window in the parallel-workloads probe"),
+    # --- tools / CI ----------------------------------------------------
     _k("MXTPU_TUNE_CORPUS", "str", None, "tuneplan",
        "TUNE_CORPUS.jsonl path override (default: repo root)"),
     _k("MXTPU_CI_FULL", "bool", False, "ci", "nightly CI tier"),
-    _k("MXTPU_ARTIFACT_DIR", "str", None, "ci", "CI artifact drop dir"),
     _k("MXTPU_TOY_BACKEND", "str", "cpu", "examples",
        "toy example backend pin"),
 ))

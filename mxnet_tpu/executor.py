@@ -99,8 +99,8 @@ class _GraphProgram:
                         dtype_policy=self.dtype_policy)
         # the named scope stamps the symbol name into the XLA metadata
         # (op_name="jit(..)/<node>/..") of every primitive this node
-        # traces — tools/step_breakdown.py joins per-fusion HBM bytes
-        # back to symbol-level layers through it
+        # traces — benchmark/lib/tracered.py joins a traced device op
+        # back to its symbol-level layer through it
         with jax.named_scope(n.name):
             outs, aux_updates = n.op.apply(n.params, ctx,
                                            *(in_vals + node_aux))

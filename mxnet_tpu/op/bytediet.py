@@ -1,7 +1,7 @@
 """Byte-diet backward formulations for the fused train step.
 
 The fused ResNet-50 step is HBM-bandwidth-bound, not MXU-bound
-(ROOFLINE.json / STEP_BREAKDOWN.json: ~112 of 124 roofline-ms on the
+(an HLO walk of the round-5 step: ~112 of 124 roofline-ms on the
 byte side), and the round-5 recapture named the residue: three zero-FLOP
 1.2-1.6 GB fusions, a 0.92 GB zero-FLOP ``select_and_scatter`` (MaxPool
 backward) and a family of 0.82 GB zero-FLOP fusions — all *backward-pass

@@ -510,7 +510,7 @@ def _batch_norm(p, c, data, gamma, beta, moving_mean, moving_var):
         # activation (jnp.var's (x-mean)^2 formulation needs a second
         # full pass — on a byte-bound step the extra read of the
         # widened activation is the cost; the f32 convert_reduce
-        # fusions that topped STEP_BREAKDOWN.json through round 4).
+        # fusions that topped the step's HLO walk through round 4).
         # Centering on the RUNNING mean c (an aux input — free) keeps
         # the E[.]-mean^2 subtraction benign at steady state, and
         # bytediet.bn_batch_stats guards the catastrophic regime (batch

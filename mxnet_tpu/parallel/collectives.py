@@ -135,7 +135,7 @@ def lowp_allreduce(x, axis_name, n, comm_dtype, keep_shard=False):
 
 def lowp_comm_bytes(shape, n, comm_itemsize=2, keep_shard=False):
     """Per-replica wire bytes :func:`lowp_allreduce` moves for one leaf
-    (the analytic model bench.py reports as ``grad_comm_gb_per_step``)."""
+    (the analytic model ``Trainer.grad_comm_bytes_per_step`` sums)."""
     size = int(np.prod(shape or (1,)))
     d0 = shape[0] if shape else 0
     if d0 >= n and d0 % n == 0:

@@ -230,8 +230,8 @@ def moe_dispatch_bytes(n_tokens, d_model, n_experts,
     dense: the (N, E, C) dispatch tensor is written once and read by
     both einsums, which also stream x/ex_in/ex_out/out.
     sparse: index arrays (int32) plus two gathers — no (N, E, C)
-    tensor ever exists.  bench.py gates sparse <= dense/2 on the
-    transformer-large shape.
+    tensor ever exists.  ``tests/test_parallel_workloads.py`` holds
+    sparse <= dense/2 at the transformer-large shape.
     """
     T, d, E = int(n_tokens), int(d_model), int(n_experts)
     C = moe_capacity(T, E, capacity_factor, top_k)

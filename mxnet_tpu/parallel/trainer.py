@@ -124,7 +124,7 @@ def remat_policy(name):
     """Resolve a rematerialization policy for the fused step.
 
     The step is usually HBM-bandwidth-bound, not MXU-bound (see
-    ROOFLINE.json / docs/how_to/perf.md): rematerialization trades the
+    PERF.md and docs/how_to/perf.md): rematerialization trades the
     idle MXU's free flops for scarce HBM bytes by storing fewer
     residuals and recomputing the rest inside backward.  Policies:
 
@@ -794,7 +794,7 @@ class Trainer:
     def opt_state_bytes_per_chip(self) -> int:
         """Optimizer-state bytes resident on ONE chip.  Replicated state
         counts at full size (every chip holds a copy); zero-sharded
-        state at ~1/n — the number bench.py reports as
+        state at ~1/n — what ``tools/stepcost.cost_model`` reports as
         ``opt_state_bytes_per_chip``."""
         if self.opt_state is None:
             return 0
@@ -1838,8 +1838,8 @@ class Trainer:
           iteration).
 
         The plan total therefore agrees with
-        ``grad_comm_bytes_per_step`` (bench.py asserts <= 5% —
-        ``comm_model_gb_per_step``), and its digest
+        ``grad_comm_bytes_per_step`` (``tests/test_comm_lint.py`` holds
+        the two equal on every corner), and its digest
         (``analysis.plan_digest``) is the cross-rank parity token the
         elastic guard checks before the first step."""
         from ..analysis import comm_passes

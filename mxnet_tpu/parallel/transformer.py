@@ -14,11 +14,11 @@ more than ResNet-50):
   attention over a ``seq`` mesh axis (causal block skip + fused K/V
   permute), for the long-context tokens/sec headline.
 
-The configs here are sized for the virtual 8-device CPU mesh the bench
+The configs here are sized for the virtual 8-device CPU mesh the tests
 and CI run on; the shapes (not the sizes) are what the real chips see.
-``tools/parallel_bench.py`` wraps the step functions in
-:class:`~mxnet_tpu.program.CompiledProgram` for retrace accounting and
-warm-start persistence; tests assert value/grad/resume parity.
+``tools/mem_lint.py`` traces both at these sizes for its peak-HBM
+ratchet; ``tests/test_parallel_workloads.py`` asserts value, gradient
+and resume parity.
 """
 from __future__ import annotations
 

@@ -64,7 +64,7 @@ PROGRAM_CACHE_VERSION = 2
 
 # hit/miss/stale accounting in the process-wide metrics registry —
 # always on (the registry is), scraped via obs.snapshot() and reported
-# by bench.py / tools/obs_report.py
+# by tools/obs_report.py
 _HITS = _obs.counter("program.cache_hit")
 _MISSES = _obs.counter("program.cache_miss")
 _STALE = _obs.counter("program.cache_stale")

@@ -44,7 +44,7 @@ replicated serving tier:
   re-checked before each replica re-admits traffic.
 
 Bench: ``tools/serve_bench.py fleet_probe`` (INFER_BENCH.json
-``fleet`` section, gated in bench.py).  Docs:
+``fleet`` section, a CPU run).  Docs:
 ``docs/how_to/serving.md`` "Fleet serving".
 """
 from __future__ import annotations

@@ -6,6 +6,7 @@ local-launcher trick for testing multi-node on one box); the same code
 runs unmodified on a real TPU mesh.
 """
 import contextlib
+import functools
 import os
 import signal
 import sys
@@ -25,6 +26,24 @@ jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+
+@functools.lru_cache(maxsize=None)
+def repo_files():
+    """Every file of the checkout, as paths from its root, less what
+    ``.gitignore`` keeps out by directory (copies of other commits, chip
+    outputs, caches): what the tests of the tree's own consistency read."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, ".gitignore")) as f:
+        skip = {ln.strip().rstrip("/") for ln in f
+                if ln.strip().endswith("/")}
+    skip.add(".git")
+    found = []
+    for d, subdirs, files in os.walk(root):
+        subdirs[:] = [s for s in subdirs if s not in skip]
+        found += [os.path.relpath(os.path.join(d, name), root)
+                  for name in files]
+    return tuple(found)
 
 
 def pytest_configure(config):

@@ -7,8 +7,7 @@ process-wide compile/load accounting plus numeric fingerprints of every
 path's outputs.
 
 Run it twice against one cache dir (the ci/run_tests.sh warm-cache
-stage, bench.py's ``cold_start_compile_s``/``warm_restart_s`` probe,
-and tests/test_program.py's subprocess acceptance all do):
+stage and tests/test_program.py's subprocess acceptance both do):
 
 * first run (``--expect cold``): compiles > 0, persists > 0 — the cache
   is being filled;

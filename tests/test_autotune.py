@@ -4,6 +4,7 @@ the importable cost model, and the micro-tune acceptance drill
 (docs/how_to/autotune.md)."""
 import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -226,6 +227,21 @@ class TestEnvKnobs:
         monkeypatch.delenv("MXTPU_SERVE_CAP")
         assert envknobs.get_int("MXTPU_SERVE_CAP", 3) == 3
 
+    def test_every_registered_knob_has_a_reader(self):
+        """A name the registry holds occurs in some ``*.py`` or ``*.sh``
+        of the tree besides the registry and the tests: a setting
+        whose reader was deleted leaves with it."""
+        import conftest
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        read = set()
+        for path in conftest.repo_files():
+            if path.endswith((".py", ".sh")) \
+                    and not path.startswith("tests" + os.sep) \
+                    and path != os.path.join("mxnet_tpu", "envknobs.py"):
+                with open(os.path.join(root, path), errors="replace") as f:
+                    read.update(re.findall(r"MXTPU_[A-Z0-9_]+", f.read()))
+        assert sorted(set(envknobs.KNOBS) - read) == []
+
 
 # ----------------------------------------------------------------------
 class TestArrivalSchedule:
@@ -250,14 +266,14 @@ class TestArrivalSchedule:
 # ----------------------------------------------------------------------
 class TestCostModel:
     def test_importable_surrogate(self):
-        from tools.step_breakdown import cost_model
+        from tools.stepcost import cost_model
         out = cost_model({"model": "mlp", "batch": 8})
         assert out["gb_per_step"] > 0
         assert out["bytes"] > 0
         assert out["config"]["model"] == "mlp"
 
     def test_unknown_config_key_is_loud(self):
-        from tools.step_breakdown import cost_model
+        from tools.stepcost import cost_model
         with pytest.raises(ValueError, match="grad_accum"):
             cost_model({"model": "mlp", "grad_acum": 2})
 

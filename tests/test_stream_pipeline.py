@@ -495,22 +495,3 @@ def test_fit_upload_chunks_env(monkeypatch):
     assert isinstance(seen["iter"], io.DeviceUploadIter)
     assert seen["iter"]._chunks == 3
     assert seen["iter"]._depth == 4
-
-
-# ------------------------------------------------------------- attribution
-def test_overlap_attribution_model():
-    from tools.step_breakdown import overlap_attribution
-    att = overlap_attribution(0.25, 0.70, 0.10, measured_s=0.75)
-    assert att["binding_stage"] == "h2d"
-    assert att["bound_s_per_batch"] == 0.70
-    assert att["serial_s_per_batch"] == 1.05
-    assert att["overlap_efficiency"] == pytest.approx(0.70 / 0.75,
-                                                      abs=1e-3)
-    assert att["exposed_s_per_batch"] == pytest.approx(0.05, abs=1e-3)
-    assert att["hidden_s_per_batch"] == pytest.approx(0.30, abs=1e-3)
-    # fully serialized pipeline reads bound/sum
-    ser = overlap_attribution(0.25, 0.70, 0.10, measured_s=1.05)
-    assert ser["overlap_efficiency"] == pytest.approx(0.667, abs=1e-3)
-    # no measurement: model-only fields, no efficiency
-    bare = overlap_attribution(0.25, 0.70, 0.10)
-    assert "overlap_efficiency" not in bare

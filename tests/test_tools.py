@@ -1,6 +1,9 @@
 """Tooling tests: im2rec list/pack round trip, parse_log, launcher env
-contract, op-doc generation (reference ``tools/``)."""
+contract, op-doc generation (reference ``tools/``); the documents
+name only files that exist."""
+import glob
 import os
+import re
 import subprocess
 import sys
 
@@ -89,79 +92,43 @@ def test_gen_op_docs(tmp_path):
     assert "**required**" in text
 
 
-def test_step_breakdown_budget_and_layers(tmp_path):
-    """tools/step_breakdown.py round-6 surface, sans the ResNet compile:
-    symbol-layer attribution parses named-scope ``op_name`` metadata out
-    of real compiled HLO, and the byte-budget emit → parse → gate cycle
-    round-trips (the machinery behind the nightly ``--check`` gate and
-    bench.py's ``byte_budget_*`` fields)."""
-    import json
-    import jax
-    import jax.numpy as jnp
-    from tools import step_breakdown as sb
-
-    # op_name grammar: jvp-wrapped forward, transpose(jvp()) backward,
-    # scope-less wrapper-only paths
-    assert sb.layer_from_op_name("jit(step)/jvp(conv0)/max") == \
-        ("conv0", False)
-    assert sb.layer_from_op_name(
-        "jit(step)/transpose(jvp(stage1_relu))/mul") == ("stage1_relu", True)
-    assert sb.layer_from_op_name("jit(f)/add")[0] is None
-
-    # attribution over REAL compiled HLO (executor.py stamps the same
-    # per-symbol-node scopes the fused step carries)
-    def f(x):
-        with jax.named_scope("conv0"):
-            y = jnp.maximum(x, 0.0)
-        with jax.named_scope("fc1"):
-            return (y * 2.0).sum()
-
-    comp = jax.jit(jax.grad(f)).lower(jnp.ones((256, 256))).compile()
-    rows = sb.analyze(comp.as_text(), hbm_gbps=600.0, mxu_tflops=180.0)
-    layers = sb.layer_table(rows)
-    assert any(k.split(" ")[0] in ("conv0", "fc1") for k in layers), layers
-    assert sum(e["n_instructions"] for e in layers.values()) == len(rows)
-
-    # budget: emit -> parse -> gate (ok inside tolerance, fail outside)
-    entry = sb.byte_budget_entry(
-        {"model": "toy", "cost_model_gb_per_step": 10.0})
-    path = str(tmp_path / "budget.json")
-    json.dump({"tolerance_pct": 3.0, "cpu": entry}, open(path, "w"))
-    budget = sb.load_budget(path)
-    ok, delta = sb.check_byte_budget(10.1, budget["cpu"],
-                                     budget["tolerance_pct"])
-    assert ok and abs(delta - 1.0) < 0.2
-    ok, delta = sb.check_byte_budget(10.4, budget["cpu"],
-                                     budget["tolerance_pct"])
-    assert not ok and delta > 3.0
-
-    # the checked-in budget file parses and carries the gate's fields
-    budget = sb.load_budget()
-    assert budget and "tolerance_pct" in budget
-    for plat in ("tpu", "cpu"):
-        assert "cost_model_gb_per_step" in budget[plat]
-        # run_check refuses to gate against a wrong-shape entry (a
-        # full-shape capture recorded into the small-shape CPU slot
-        # would leave the gate ~95% slack): every entry must carry the
-        # model string the guard compares
-        assert "model" in budget[plat]
+# ---------------------------------------------------- documents and files
+_CODE_SPAN = re.compile(r"```.*?```|`[^`\n]+`", re.S)
+# a path of this repository as a document writes it: under one of four
+# directories, a `<name>.py`, or one of the root's upper-case records
+_REPO_PATH = re.compile(
+    r"(?<![\w/.*\-])((?:tools|mxnet_tpu|ci|benchmark)/[\w./*\-]+"
+    r"|[\w\-]+\.py|[A-Z][A-Z0-9_]*\.jsonl?)(?![\w/\-])")
+# not this repository's: the reader's own scripts, two files of the
+# reference, and what `tools/quantize.py` writes beside a checkpoint
+_NOT_OURS = {"train.py", "serve.py", "kill-mxnet.py",
+             "executor_manager.py", "QUANT_GATE.json"}
 
 
-def test_attn_bench_smoke(tmp_path):
-    """tools/attn_bench.py runs end-to-end at toy size (flash in
-    interpret mode on CPU) and writes a well-formed artifact."""
-    import json
-    out = str(tmp_path / "attn.json")
-    res = _run([os.path.join(_ROOT, "tools", "attn_bench.py"),
-                "--seqs", "128", "--batch", "1", "--heads", "2",
-                "--dim", "64", "--steps", "2", "--out", out],
-               timeout=280, env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert res.returncode == 0, res.stderr[-2000:]
-    art = json.load(open(out))
-    row = art["rows"][0]
-    assert row["seq"] == 128
-    assert "flash_fwd_ms" in row and "naive_fwd_ms" in row
-    assert "flash_fwdbwd_ms" in row
+def _exists(path):
+    if "/" not in path:
+        # `<name>.py` at the root, or a module named by its file alone
+        import conftest
+        return any(os.path.basename(f) == path
+                   for f in conftest.repo_files())
+    if glob.glob(os.path.join(_ROOT, path)):
+        return True
+    # `tools/stepcost.cost_model`: a module's attribute
+    return os.path.exists(
+        os.path.join(_ROOT, path.rsplit(".", 1)[0] + ".py"))
+
+
+@pytest.mark.parametrize("doc", ["README.md"] + sorted(
+    os.path.relpath(p, _ROOT)
+    for p in glob.glob(os.path.join(_ROOT, "docs", "how_to", "*.md"))))
+def test_document_names_only_files_that_exist(doc):
+    """Every path of the repository a document back-quotes is in the
+    tree: a deleted tool leaves the documents with it."""
+    with open(os.path.join(_ROOT, doc)) as f:
+        text = f.read()
+    named = {m.group(1).rstrip(".") for span in _CODE_SPAN.findall(text)
+             for m in _REPO_PATH.finditer(span)} - _NOT_OURS
+    assert sorted(p for p in named if not _exists(p)) == []
 
 
 def test_time_limit_fails_a_test_that_outlasts_it(monkeypatch):

@@ -1,2 +1,3 @@
-# Makes the analysis helpers importable (tools.stepcost) from bench.py
-# and the perf tools; the CLI scripts in here still run standalone.
+# Makes the helpers importable (tools.stepcost, tools.serve_bench) from
+# chip_smoke.py, the tests and each other; the CLI scripts in here
+# still run standalone.

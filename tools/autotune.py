@@ -10,7 +10,7 @@ this driver makes that search cheap by leaning on two existing layers:
 
 * **cheap surrogates prune the space.**  The training side scores every
   candidate with the XLA byte cost model
-  (:func:`tools.step_breakdown.cost_model` — compile, never execute;
+  (:func:`tools.stepcost.cost_model` — compile, never execute;
   GB/step + gradient wire GB).  The serving side scores candidates with
   the serving latency model: per-bucket execute-latency EWMAs
   (:meth:`CompiledForward.record_latency`) calibrated once, then an
@@ -147,7 +147,7 @@ def train_surrogate(configs, batch=64, model="mlp", capacity=None):
     ``mem_feasible: False`` and sorted LAST — it is never adopted and
     never gets a timed window: a config that OOMs cannot win a
     wall-clock race it cannot finish."""
-    from tools.step_breakdown import cost_model
+    from tools.stepcost import cost_model
     if capacity is None:
         from mxnet_tpu.analysis import detect_capacity
         capacity = detect_capacity()
@@ -485,7 +485,7 @@ def run_tune(network="mlp", micro=False, top_k=2, seed=0, out=None,
             t_best = t_rows[0]
             # a predicted-bytes winner enters the plan ONLY with a
             # timed confirmation (fewer bytes can still be slower
-            # wall-clock — REMAT_SWEEP.json documents exactly that)
+            # wall-clock, as every remat policy did on the v5e)
             # AND only when measured meshless: the plan's one key is
             # the meshless serve identity, so a zero=1/bf16 corner
             # measured on a real mesh stays in measured/corpus (the
@@ -711,58 +711,6 @@ def _ratchet_infer_bench(path, plan, summary):
     with open(path, "w") as f:
         json.dump(artifact, f, indent=1)
         f.write("\n")
-
-
-# ----------------------------------------------------------------------
-def plan_ab(plan_path, quick=True, seed=0, corpus=None):
-    """The bench.py probe: A/B the persisted plan's serving config
-    against the built-in defaults on one identical seeded arrival
-    sequence.  Returns the ``tune`` section of the bench line."""
-    from mxnet_tpu import tuneplan
-    from tools.serve_bench import (_mixed_payloads, arrival_schedule,
-                                   build_model, single_request_baseline)
-
-    plan = tuneplan.load(plan_path)
-    network = plan.get("meta", {}).get("network", "mlp")
-    deadline_ms = int(plan.get("key", {}).get("slo", {})
-                      .get("deadline_ms", 250))
-    serve_cfg = dict(SERVE_DEFAULTS, **plan.get("serve", {}))
-    with _pinned_env():
-        # scrubbed: with MXTPU_TUNE_PLAN exported (the setup being
-        # A/B'd!) the "default" server would silently load the plan
-        sym, wargs, waux, example = build_model(network, seed)
-        n_req = 120 if quick else 300
-        base = single_request_baseline(sym, wargs, waux, example,
-                                       n=(80 if quick else 200),
-                                       seed=seed + 1)
-        rate = max(1.0, base["rps"])
-        payloads = _mixed_payloads(example, (1, 2, 4), n_req, seed + 2)
-        arrivals = arrival_schedule(n_req, rate, seed + 3)
-        default = timed_serve_trial(sym, wargs, waux, example,
-                                    SERVE_DEFAULTS, payloads, arrivals,
-                                    rate, deadline_ms, corpus=corpus,
-                                    label="bench:default")
-        tuned = timed_serve_trial(sym, wargs, waux, example, serve_cfg,
-                                  payloads, arrivals, rate, deadline_ms,
-                                  corpus=corpus, label="bench:plan")
-    out = {"plan": plan_path, "network": network,
-           "offered_rps": round(rate, 1),
-           "default": default, "tuned": tuned,
-           "headline": "serve_p99_ms"}
-    if default.get("p99_ms") and tuned.get("p99_ms"):
-        out["p99_improvement_pct"] = round(
-            (1.0 - tuned["p99_ms"] / default["p99_ms"]) * 100.0, 2)
-    if default.get("p50_ms") and tuned.get("p50_ms"):
-        out["p50_improvement_pct"] = round(
-            (1.0 - tuned["p50_ms"] / default["p50_ms"]) * 100.0, 2)
-        # p50-judged with the tuner gate's noise-sized tolerances (p99
-        # of identical configs varies >10% window-to-window; p50
-        # min-of-windows still spreads ~1.2x run-to-run)
-        out["plan_no_worse"] = (
-            tuned["p50_ms"] <= default["p50_ms"] * 1.30
-            and tuned.get("goodput_rps", 0)
-            >= 0.85 * default.get("goodput_rps", 0))
-    return out
 
 
 # ----------------------------------------------------------------------

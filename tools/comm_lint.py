@@ -26,7 +26,7 @@ through, and runs the comm rules over each plan
 Everything is pure trace time (no device execution), so the gate runs
 in the fast CI tier.  ``--check`` fails on NEW error findings OR a
 predicted-GB regression past tolerance vs the checked-in
-``COMM_BASELINE.json`` (the ``STEP_BYTE_BUDGET.json`` ratchet pattern);
+``COMM_BASELINE.json`` (the ``LINT_BASELINE.json`` ratchet pattern);
 ``--write-baseline`` re-records both after an intentional change.
 Docs: ``docs/how_to/static_analysis.md`` "Communication analysis".
 """

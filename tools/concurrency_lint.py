@@ -17,10 +17,9 @@ Two finding sources, one report format (the graph linter's
   replays their combined log here).
 
 ``--check`` fails on NEW error findings vs the checked-in
-``RACE_BASELINE.json`` (the ``LINT_BASELINE.json`` /
-``STEP_BYTE_BUDGET.json`` ratchet pattern); ``--write-baseline``
-re-records after an intentional change.  Rule catalogue + fix recipes:
-``docs/how_to/static_analysis.md``.
+``RACE_BASELINE.json`` (the ``LINT_BASELINE.json`` ratchet pattern);
+``--write-baseline`` re-records after an intentional change.  Rule
+catalogue + fix recipes: ``docs/how_to/static_analysis.md``.
 """
 import argparse
 import os

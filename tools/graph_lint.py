@@ -14,9 +14,9 @@ callbacks, non-donated buffers, unfused gather/scatter) — on:
 Everything is pure trace time (no device execution), so the gate runs
 in the fast CI tier.  ``--check`` diffs error-severity findings against
 the checked-in ``LINT_BASELINE.json`` and exits non-zero on NEW errors
-(the ``STEP_BYTE_BUDGET.json`` ratchet pattern — see
-``tools/step_breakdown.py``); ``--write-baseline`` re-records after an
-intentional change.  Rule catalog: ``docs/how_to/graph_lint.md``.
+(the ratchet of ``mxnet_tpu/analysis/baseline.py``);
+``--write-baseline`` re-records after an intentional change.  Rule
+catalog: ``docs/how_to/graph_lint.md``.
 """
 import argparse
 import os

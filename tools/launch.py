@@ -187,8 +187,7 @@ def launch_local_elastic(args):
     relaunched job re-initializes ``jax.distributed`` over the shrunk
     world and auto-resumes from the newest intact checkpoint.  At
     success it prints ``ELASTIC_RECOVERY_S=<detect -> resumed-first-step
-    seconds>`` (the number bench.py reports as ``elastic_recovery_s``)
-    when both timestamps were recorded."""
+    seconds>`` when both timestamps were recorded."""
     import json
     import shutil
     import tempfile

@@ -17,9 +17,9 @@ each program:
   * ``pipeline`` — the SPMD pipeline on the interleaved v=2 schedule
     (stage-hop scan: body temporaries counted once, stacked outputs at
     call level).
-  * ``transformer-large`` — the composed bench workload's full train
+  * ``transformer-large`` — the composed workload's full train
     step (pipeline x MoE x grad-accum x ZeRO momentum) at the exact
-    ``transformer_large()`` config bench.py times.
+    ``transformer_large()`` config.
   * ``ringattn-long-context`` — the long-context causal ring-attention
     LM forward at the exact ``ringattn_long_context()`` config.
 
@@ -31,7 +31,7 @@ Rules: ``mem-budget`` (predicted-GB ratchet vs ``MEM_BASELINE.json``),
 Everything is pure trace time (no device execution), so the gate runs
 in the fast CI tier.  ``--check`` fails on NEW error findings OR a
 predicted-GB regression past tolerance vs the checked-in
-``MEM_BASELINE.json`` (the ``STEP_BYTE_BUDGET.json`` ratchet pattern);
+``MEM_BASELINE.json`` (the ``LINT_BASELINE.json`` ratchet pattern);
 ``--write-baseline`` re-records both after an intentional change.
 Docs: ``docs/how_to/static_analysis.md`` "Memory analysis".
 """
@@ -162,8 +162,8 @@ def _abstract(tree):
 
 def transformer_large_target():
     """The composed transformer-large train step, traced abstractly at
-    the SAME config bench.py's parallel probe times — the peak-HBM
-    ratchet for the headline workload (needs the 8-device mesh)."""
+    ``transformer_large()`` config — the peak-HBM ratchet for the
+    composed workload (needs the 8-device mesh)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

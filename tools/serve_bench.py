@@ -41,7 +41,7 @@ The degradation invariant — goodput at the highest overload >= 0.9x
 goodput at 1x — is what "graceful" means quantitatively: past
 saturation the server sheds the excess deliberately and keeps serving
 at capacity instead of letting queues and p99 grow without bound.
-``bench.py`` asserts it on every run.
+``tests/test_serving_overload.py`` holds it at test scale.
 
 **Fleet sweep** (:func:`fleet_probe`, ``--fleet``): the replicated tier
 (``FleetRouter``) under three windows — scaling (same offered load and
@@ -53,8 +53,8 @@ requests and zero spin-up compiles across the rollout.
 
 ``--out INFER_BENCH.json`` merges ``serving`` and ``overload`` (and
 ``quant`` / ``fleet`` when requested) sections into the artifact (field
-definitions: docs/how_to/perf.md "Serving"); ``bench.py`` embeds the
-quick sweeps via :func:`serving_probe` / :func:`overload_probe` /
+definitions: docs/how_to/perf.md "Serving"); ``--quick`` runs the
+bounded sweeps of :func:`serving_probe` / :func:`overload_probe` /
 :func:`fleet_probe`.
 """
 from __future__ import annotations
@@ -325,7 +325,7 @@ def overload_probe(network="mlp", quick=True, buckets=None,
     the ``reject`` shedding policy, a bounded queue, and a per-request
     deadline.  Returns the INFER_BENCH ``overload`` section, including
     the degradation verdict (goodput at max load >= 0.9x goodput at
-    1x) that ``bench.py`` asserts."""
+    1x) that ``tests/test_serving_overload.py`` asserts."""
     from mxnet_tpu import serving
 
     sym, args, aux, example = build_model(network, seed)
@@ -653,89 +653,6 @@ def quant_probe(quick=True, seed=0, vocab=400_000, dim=512, slots=256,
     }
 
 
-def obs_overhead_probe(network="mlp-wide", pairs=3, n=200, buckets=None,
-                       seed=0):
-    """Measure the cost of ``MXTPU_OBS=1`` span recording + JSONL
-    export on the serving path (``docs/how_to/observability.md``).
-
-    The GATED number (``obs_overhead_pct``, bench.py asserts < 5%)
-    compares alternating OFF/ON **open-loop Poisson sweeps at half the
-    measured saturation throughput** over one warmed server — the
-    serving sweep's own arrival model at a load the server holds, where
-    telemetry must fit inside the batching slack without stretching the
-    completion wall.  A secondary, informational number
-    (``obs_overhead_saturated_pct``) compares closed-loop saturation
-    blasts — the worst case, where every telemetry microsecond competes
-    with the scheduler's own Python on a fully-loaded host; it is
-    reported, not gated, because on a 1-2 core CI box its baseline
-    varies more run-to-run than the effect being measured.  Alternating
-    pairs, min-of-2 windows per phase, and the median ratio are the
-    anti-noise measures the integrity probe established."""
-    import tempfile
-
-    from mxnet_tpu import obs, serving
-
-    sym, args, aux, example = build_model(network, seed)
-    rng = np.random.RandomState(seed + 1)
-    # 4-row requests: the serving sweep's upper row-mix — per-request
-    # compute at the batched design point, not the 1-row degenerate
-    payloads = [rng.randn(4, *example).astype("f") for _ in range(n)]
-
-    server = serving.ModelServer(buckets=buckets, max_wait_us=200)
-    server.add_model("m", sym, args, aux, input_shapes={"data": example})
-
-    def blast():
-        t0 = time.perf_counter()
-        futs = [server.submit(data=p) for p in payloads]
-        for f in futs:
-            f.result(timeout=60)
-        return time.perf_counter() - t0
-
-    def sweep(rate_rps, seed_):
-        t0 = time.perf_counter()
-        futs, _, _, _, _ = _open_loop_submit(server, payloads, rate_rps,
-                                             seed=seed_)
-        for f in futs:
-            f.result(timeout=60)
-        return time.perf_counter() - t0
-
-    sat_ratios, sweep_ratios, samples = [], [], []
-    with server, tempfile.TemporaryDirectory() as d:
-        blast()                                    # warm the off path
-        with obs.scoped(log_path=os.path.join(d, "warm.jsonl"),
-                        flush_s=0.2):
-            blast()                                # warm the on path
-        cap_rps = n / min(blast(), blast())        # saturation estimate
-        rate = cap_rps / 2.0
-        for i in range(pairs):
-            # min-of-2 per phase: the min filters the scheduler noise a
-            # shared CI host injects into any single window
-            sw_off = min(sweep(rate, seed + i), sweep(rate, seed + i))
-            bl_off = min(blast(), blast())
-            log = os.path.join(d, "obs_%d.jsonl" % i)
-            # flush_s matches the production arrangement: the exporter
-            # thread serializes off the hot path, concurrently
-            with obs.scoped(log_path=log, flush_s=0.2):
-                sw_on = min(sweep(rate, seed + i), sweep(rate, seed + i))
-                bl_on = min(blast(), blast())
-            sweep_ratios.append(sw_on / sw_off)
-            sat_ratios.append(bl_on / bl_off)
-            samples.append({"sweep_off_s": round(sw_off, 4),
-                            "sweep_on_s": round(sw_on, 4),
-                            "blast_off_s": round(bl_off, 4),
-                            "blast_on_s": round(bl_on, 4)})
-    med = float(np.median(sweep_ratios))
-    sat = float(np.median(sat_ratios))
-    return {
-        "network": network,
-        "requests_per_window": n,
-        "sweep_rate_rps": round(rate, 1),
-        "pairs": samples,
-        "obs_overhead_pct": round((med - 1.0) * 100.0, 2),
-        "obs_overhead_saturated_pct": round((sat - 1.0) * 100.0, 2),
-    }
-
-
 # ----------------------------------------------------------------------
 def _fleet_window(fleet, payloads, rate_rps, seed, deadline_s,
                   trigger_i=None, trigger=None):
@@ -949,7 +866,7 @@ def fleet_probe(network="mlp", quick=True, replicas=3, pace_rps=120.0,
         "rollout": rollout,
         "spinup_compiles": spinup_compiles,
         "retraces": int(retraces_scaling),
-        # the bench.py gates in one place
+        # the gates ``main`` exits non-zero on, in one place
         "scaling_ok": bool(scaling_x and scaling_x >= 2.2),
         "recovery_ok": bool(churn["recovery_ratio"]
                             and churn["recovery_ratio"] >= 0.9),
@@ -964,7 +881,7 @@ def main(argv=None):
     ap.add_argument("--network", default="mlp",
                     help="mlp (CPU-fast) or resnet-50")
     ap.add_argument("--quick", action="store_true",
-                    help="bounded sweep (the bench.py probe)")
+                    help="bounded sweep")
     ap.add_argument("--buckets", default=None,
                     help="comma batch buckets (default MXTPU_SERVE_BUCKETS"
                          " or 1,4,8,16,32)")
