@@ -22,13 +22,16 @@ def _attention(x, seq_len, num_hidden, num_heads, prefix, causal=True):
     head_dim = num_hidden // num_heads
     qkv = sym.FullyConnected(x, num_hidden=3 * num_hidden,
                              name=prefix + "qkv")
-    qkv = sym.Reshape(qkv, shape=(-1, seq_len, 3, num_heads, head_dim))
-    q = sym.Reshape(sym.slice_axis(qkv, axis=2, begin=0, end=1),
+    # q, k and v are column blocks of the one product, and are sliced as
+    # such: sliced along the "3" of a [b, t, 3, h, d] view, XLA lays the
+    # product out with that axis major, so that the slices are free, and
+    # then transposes q, k, v, dq, dk and dv between that layout and the
+    # [b, t, h*d] the kernels read in place (10 of gpt2m_train's 161 ms)
+    q, k, v = (
+        sym.Reshape(sym.slice_axis(qkv, axis=1, begin=i * num_hidden,
+                                   end=(i + 1) * num_hidden),
                     shape=(-1, seq_len, num_heads, head_dim))
-    k = sym.Reshape(sym.slice_axis(qkv, axis=2, begin=1, end=2),
-                    shape=(-1, seq_len, num_heads, head_dim))
-    v = sym.Reshape(sym.slice_axis(qkv, axis=2, begin=2, end=3),
-                    shape=(-1, seq_len, num_heads, head_dim))
+        for i in range(3))
     # [b, t, h, d] -> flash attention (Pallas on TPU)
     out = sym._contrib_DotProductAttention(q, k, v, causal=causal,
                                   name=prefix + "attn")

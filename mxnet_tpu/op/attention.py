@@ -15,6 +15,16 @@ import jax
 from .registry import Param, register
 
 
+def _attention_infer_shape(p, in_shapes):
+    # the query's shape with the value's last dimension: no walk of a
+    # Symbol's shapes has to trace the kernel to learn it
+    if any(s is None or 0 in s for s in in_shapes):
+        return None
+    q, _, v = in_shapes
+    return ([tuple(s) for s in in_shapes],
+            [tuple(q[:-1]) + (v[-1],)], [])
+
+
 @register("_contrib_DotProductAttention",
           input_names=("query", "key", "value"),
           params_spec=(Param("causal", bool, False),
@@ -24,7 +34,8 @@ from .registry import Param, register
                        # (512x512, measured 2-3x over 128x128 at 8k+)
                        Param("block_q", int, 0),
                        Param("block_k", int, 0)),
-          hint="dotproductattention")
+          hint="dotproductattention",
+          infer_shape=_attention_infer_shape)
 def _dot_product_attention(p, c, q, k, v):
     scale = None if p["scale"] <= 0 else p["scale"]
     if p["flash"]:

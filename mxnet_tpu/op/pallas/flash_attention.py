@@ -1,33 +1,62 @@
-"""Flash attention as a Pallas TPU kernel.
+"""Flash attention as two Pallas TPU kernels, one form for both passes.
 
-Forward is a Pallas kernel: one grid step per (batch*head, q-block); K/V
-live in VMEM and the kernel walks K in ``block_k`` tiles keeping the online
-softmax state (running max ``m``, denominator ``l``, accumulator ``o``) in
-registers/VMEM, so HBM traffic is O(T) per q-block instead of the O(T^2)
-score matrix.  The MXU sees two big matmuls per tile (QK^T and PV) in
-float32 accumulation.
+Both kernels hold the score tile transposed, ``[block_k, block_q]``, in
+VMEM, so what a query row carries (the forward's running maximum and
+denominator, the backward's ``lse`` and ``delta = sum(dO * O)``) is a
+lane-dense row ``[1, block_q]`` a head that broadcasts over sublanes: no
+lane-replicated ``[t, 128]`` copy of any of it exists, in a kernel or
+between the passes.  Heads narrower than the 128 lanes go through side
+by side, ``g`` to a grid step (two at 64 wide): one head's products take
+operands with the other heads' lanes zeroed, which costs the MXU nothing
+at depth and width 128.  MXU operands stay in the storage dtype (bf16)
+with float32 accumulation; the scale, the maximum, the exponent and the
+denominator are float32; tiles wholly above the causal diagonal are
+neither computed nor fetched.
 
-Backward is a Pallas kernel too, the standard recomputation form (no score
-matrix saved, only the per-row logsumexp): one grid step per (group of
-heads, k-block, q-block) recomputes the tile's P from (Q, K, lse) and feeds
-five MXU products (S, dP, dV, dK, dQ); S, P, dP and dS live in VMEM and
-never reach HBM, and tiles wholly above the causal diagonal are skipped.
-The tile is held transposed, ``[block_k, block_q]``, so the per-row ``lse``
-and ``delta = sum(dO * O)`` are lane-dense rows ``[1, block_q]`` that
-broadcast over sublanes: no lane-replicated ``[t, 128]`` copy of either
-exists, in the residuals or in the backward.  dK/dV accumulate in float32
-scratch across the q-blocks of one k-block, dQ in a float32 scratch that
-stays resident for the whole group (``4 * t_q * 128`` bytes at 64-wide
-heads) across the k-blocks.  Heads narrower than the 128 lanes go through
-the kernel side by side, ``[b*h/g, t, g*d]``: its arrays fill their HBM
-tiles, and the residuals live between the passes as the graph has them,
-``[b, t, h, d]``, not as ``[b*h, t, 64]`` padded to 128 lanes (1.3 GB less
-at GPT-2 medium's 24 layers, for the same kernel time).  On a v5e the
-``lax.scan`` of einsums this replaces took 4.37 ms a layer at
-(8, 1024, 16, 64) and 10.97 ms at (1, 4096, 20, 256), layout included;
-this takes 1.07 and 2.99 ms.  One fused kernel beat a dK/dV kernel plus a
-dQ kernel (seven products and every elementwise pass twice), 1.33 against
-1.82 ms and 3.13 against 4.46 ms (PERF.md §6, PR 31).
+Forward: grid ``(batch, head groups, q-blocks, k-blocks)``, keys
+innermost.  K/V stream through VMEM a ``[block_k, g*d]`` tile a step
+while the online softmax's state carries in scratch: two rows a head and
+an accumulator ``[g*d, block_q]``, transposed like the tile, so that the
+correction by the running maximum is a row broadcast too.  It writes O
+and ``lse`` as ``[b*h/g, g, t]`` rows, the layout the backward reads.
+
+Backward: the standard recomputation form (no score matrix saved), grid
+``(batch, head groups, k-blocks, q-blocks)``, queries innermost.  One
+K/V tile stays while the Q/dO tiles stream past it; five MXU products a
+tile (S, dP, dV, dK, dQ); dK/dV accumulate in float32 scratch over the
+q-blocks of one k-block, dQ in a float32 scratch that stays resident for
+the whole group across the k-blocks; ``delta`` is taken in the group's
+first pass over the q-blocks, where O is read, once.
+
+Both read the graph's arrays where they lie: q, k, v, o and dO are
+``[b, t, h, d]``, which viewed as ``[b, t, h*d]`` (no copy) has a group
+of heads as a ``g*d``-wide column block that a ``BlockSpec`` addresses.
+Where such a block is not whole lane tiles (``g*d`` not a multiple of
+128) or a time length is not whole blocks, the same kernels take a fold
+``[b*h/g, t, g*d]`` padded in time.  The shapes decide, and
+``attention.flash.in_place`` / ``attention.flash.folded`` in ``mx.obs``
+count which a traced node took.
+
+On a v5e, bf16 causal, blocks 512 x 512, the node with whatever layout
+passes it needs (my chip run, PR 34; the parent is PR 31's pair of
+kernels, the forward on ``[b*h, t, d]`` with lane-replicated state):
+
+====================  ================  ================  ==================
+shape (b, t, h, d)    forward           forward+backward  the same, folded
+====================  ================  ================  ==================
+(8, 1024, 16, 64)     1.553 -> 0.630    2.840 -> 1.538    0.737 / 1.771
+(1, 4096, 20, 256)    3.142 -> 1.929    7.229 -> 5.275    2.531 / 6.416
+(2, 8192, 8, 64)      5.828 -> 3.052    11.34 -> 7.533    3.114 / 7.678
+(4, 2048, 8, 128)     1.169 -> 0.611    2.135 -> 1.477    0.777 / 1.761
+====================  ================  ================  ==================
+
+(ms; "folded" is this file's kernels made to take the fold at a shape
+they read in place: what the layout passes cost.)  Outputs and
+gradients stand as far from the float32 oracle as the parent's did
+(relative gradient error 0.0016962 against 0.0016962 at the first
+shape).  One fused backward kernel beat a dK/dV kernel plus a dQ kernel (seven
+products and every elementwise pass twice), 1.33 against 1.82 ms and
+3.13 against 4.46 ms (PERF.md §6, PR 31).
 
 The 2017-era reference has no attention op at all (SURVEY.md §5
 long-context); this is greenfield capability required for parity with
@@ -41,24 +70,67 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ... import obs as _obs
 
 __all__ = ["flash_attention", "flash_attention_reference"]
 
 _NEG_INF = float("-inf")
 
+_LANES = 128  # VPU lane width: a block's last dimension is a multiple
 
-_LANES = 128  # VPU lane width; per-row softmax state is lane-replicated
+# nodes traced, by what the kernels read: the graph's arrays where they
+# lie, or a fold and a pad of them
+_IN_PLACE = _obs.counter("attention.flash.in_place")
+_FOLDED = _obs.counter("attention.flash.folded")
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *,
-                block_k, causal, scale, t_kv_real, block_q):
-    # Grid is (bh, n_qb, n_kb) with the K dimension innermost: K/V stream
-    # through VMEM one [block_k, d] tile per step (never the full sequence),
-    # while the online-softmax state (acc/m/l) carries in VMEM scratch.
-    q_blk_idx = pl.program_id(1)
-    kb = pl.program_id(2)
-    n_kb = pl.num_programs(2)
+def _only(hd, x, heads, d):
+    # `heads` heads lie side by side along the lanes.  One head's
+    # products come from operands with the other heads' lanes zeroed: a
+    # contraction over them adds nothing, a product with them lands in
+    # this head's lanes of the accumulator, and the MXU does the work of
+    # one d-wide product either way (its depth and width are 128).
+    if heads == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, heads * d), 1)
+    return jnp.where(lane // d == hd, x, jnp.zeros_like(x))
+
+
+def _live(j, kb, block_q, block_k, causal):
+    """Whether the causal mask leaves the tile of q-block ``j`` and
+    k-block ``kb`` a single score."""
+    return (kb * block_k <= j * block_q + block_q - 1) if causal else True
+
+
+def _tile_mask(j, kb, block_q, block_k, causal, t_kv_real):
+    """[block_k, block_q]: the scores of the tile that live."""
+    k_pos = kb * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 0)
+    mask = k_pos < t_kv_real
+    if causal:
+        q_pos = j * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 1)
+        mask = jnp.logical_and(mask, q_pos >= k_pos)
+    return mask
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, block_q, block_k, causal, masked, scale, t_kv_real,
+                heads, d):
+    # Grid is (batch, groups of heads, n_qb, n_kb), keys innermost: K/V
+    # stream through VMEM a [block_k, g*d] tile a step while the online
+    # softmax's state carries in scratch.  The tile is [bk, bq], as in
+    # the backward: the running maximum and denominator are lane-dense
+    # rows [1, bq], a head a row, that broadcast over sublanes, and the
+    # reductions over keys run down the sublanes.
+    j, kb = pl.program_id(2), pl.program_id(3)
+    n_kb = pl.num_programs(3)
 
     @pl.when(kb == 0)
     def _init():
@@ -66,134 +138,61 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # with causal masking, tiles entirely above the diagonal contribute
-    # nothing — skip their matmuls (the scheduler still runs init/finalize)
-    first_q = q_blk_idx * block_q
-    live = (kb * block_k <= first_q + block_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(j, kb, block_q, block_k, causal))
     def _update():
-        # matmul INPUTS stay in the storage dtype (bf16): casting them
-        # to f32 first would force multi-pass f32 MXU kernels at a
-        # fraction of bf16 rate; preferred_element_type keeps the
-        # ACCUMULATION in f32, and the softmax scale is applied to the
-        # f32 scores so no precision is lost to bf16 pre-scaling
-        qb = q_ref[0]
-        kblk = k_ref[0]
-        s = jax.lax.dot_general(
-            qb, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        q_pos = first_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < t_kv_real
-        if causal:
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        s = jnp.where(mask, s, _NEG_INF)
-        m = m_ref[:, 0:1]  # [block_q, 1], lane-replicated
-        l = l_ref[:, 0:1]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_blk)
-        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(m - m_safe))
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # PV at bf16 MXU rate too: P is in [0,1] post-softmax, so the
-        # bf16 cast costs ~2^-9 relative — inside the bf16 pipeline's
-        # own noise (the f32 path would be 4x+ slower on the MXU)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        # MXU operands in the storage dtype, float32 accumulation; the
+        # scale is applied to the float32 scores; maximum, exponent and
+        # denominator in float32; P rounded to the storage dtype for
+        # the P.V product only (it is in [0, 1]: 2^-9 relative in bf16).
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        if masked:
+            mask = _tile_mask(j, kb, block_q, block_k, causal, t_kv_real)
+        for hd in range(heads):
+            row = slice(hd, hd + 1)
+            band = slice(hd * d, (hd + 1) * d)
+            s_t = jax.lax.dot_general(
+                k, _only(hd, q, heads, d), _NT,
+                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s_t = jnp.where(mask, s_t, _NEG_INF)
+            m = m_ref[row, :]
+            m_new = jnp.maximum(m, jnp.max(s_t, axis=0, keepdims=True))
+            # a row with no live key so far keeps -inf; its exponents
+            # are taken against 0 and come out 0
+            m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+            p_t = jnp.exp(s_t - m_safe)
+            corr = jnp.exp(m - m_safe)
+            l_ref[row, :] = l_ref[row, :] * corr + jnp.sum(
+                p_t, axis=0, keepdims=True)
+            m_ref[row, :] = m_new
+            # V.T @ P.T for all g*d rows; this head's are its band
+            pv_t = jax.lax.dot_general(
+                v, p_t.astype(v.dtype), _TN,
+                preferred_element_type=jnp.float32)
+            acc_ref[band, :] = acc_ref[band, :] * corr + pv_t[band, :]
 
     @pl.when(kb == n_kb - 1)
     def _finalize():
-        m = m_ref[:, 0]
-        l = l_ref[:, 0]
+        m, l = m_ref[...], l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse = jnp.where(jnp.isneginf(m), _NEG_INF, m + jnp.log(l_safe))
-        # lse block is the full [n_qb, block_q] plane for this bh (TPU
-        # tiling needs trailing block dims to match the array); each
-        # (j, last-k) step fills its own row.
-        lse_ref[0, q_blk_idx, :] = lse
+        for hd in range(heads):
+            band = slice(hd * d, (hd + 1) * d)
+            acc_ref[band, :] = acc_ref[band, :] / l_safe[hd:hd + 1, :]
+        o_ref[0] = acc_ref[...].T.astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(jnp.isneginf(m), _NEG_INF,
+                               m + jnp.log(l_safe))
 
 
-def _pad_time(x, block):
-    t = x.shape[1]
-    pad = (-t) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-    return x
-
-
-def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
-    """q/k/v: [bh, t, d] -> (o [bh, t, d], lse [bh, t_q_pad])."""
-    bh, t_q, d = q.shape
-    t_kv = k.shape[1]
-    qp = _pad_time(q, block_q)
-    kp = _pad_time(k, block_k)
-    vp = _pad_time(v, block_k)
-    t_qp, t_kvp = qp.shape[1], kp.shape[1]
-    n_qb = t_qp // block_q
-    n_kb = t_kvp // block_k
-    grid = (bh, n_qb, n_kb)
-    kernel = functools.partial(
-        _fwd_kernel, block_k=block_k, causal=causal, scale=scale,
-        t_kv_real=t_kv, block_q=block_q)
-    kwargs = {}
-    if not interpret:
-        from jax.experimental.pallas import tpu as pltpu
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    from jax.experimental.pallas import tpu as pltpu
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, n_qb, block_q), lambda i, j, kb: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_qp, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, n_qb, block_q), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),       # acc
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
-        ],
-        interpret=interpret,
-        **kwargs,
-    )(qp, kp, vp)
-    return o[:, :t_q], lse.reshape(bh, t_qp)
-
-
-_NT = (((1,), (1,)), ((), ()))   # a @ b.T
-_NN = (((1,), (0,)), ((), ()))   # a @ b
-_TN = (((0,), (0,)), ((), ()))   # a.T @ b
-
-
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, delta_acc, *,
                 block_q, block_k, causal, masked, scale, t_kv_real,
                 heads, d):
-    # Grid is (groups of heads, n_kb, n_qb), queries innermost: one K/V
-    # tile stays in VMEM while the Q/dO tiles stream past it, dk/dv
-    # accumulate in scratch over the q-blocks, dq in a scratch row per
-    # q-block that lives across the k-blocks of this group.
-    kb = pl.program_id(1)
-    j = pl.program_id(2)
-    n_kb = pl.num_programs(1)
-    n_qb = pl.num_programs(2)
+    # Grid is (batch, groups of heads, n_kb, n_qb), queries innermost:
+    # one K/V tile stays in VMEM while the Q/dO tiles stream past it,
+    # dk/dv accumulate in scratch over the q-blocks, dq in a scratch row
+    # per q-block that lives across the k-blocks of this group.
+    kb, j = pl.program_id(2), pl.program_id(3)
+    n_kb, n_qb = pl.num_programs(2), pl.num_programs(3)
 
     @pl.when(j == 0)
     def _init_dkv():
@@ -201,42 +200,27 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     @pl.when(kb == 0)
-    def _init_dq():
+    def _first_pass():
+        # the group's first pass over the q-blocks (every one is live
+        # against the first keys): dq starts, and delta = sum(dO * O)
+        # over a head's lanes is taken as the row it is used as
         dq_acc[j] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+        prod_t = (do_ref[0].astype(jnp.float32)
+                  * o_ref[0].astype(jnp.float32)).T           # [g*d, bq]
+        for hd in range(heads):
+            delta_acc[j, hd:hd + 1, :] = jnp.sum(
+                prod_t[hd * d:(hd + 1) * d, :], axis=0, keepdims=True)
 
-    first_q = j * block_q
-    first_k = kb * block_k
-    live = (first_k <= first_q + block_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(j, kb, block_q, block_k, causal))
     def _update():
         # same contract as the forward: MXU operands in the storage
         # dtype, float32 accumulation, the scale, the exponent, delta
         # and the ds combination in float32.  The tile is [bk, bq].
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         if masked:
-            k_pos = first_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            mask = k_pos < t_kv_real
-            if causal:
-                q_pos = first_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_k, block_q), 1)
-                mask = jnp.logical_and(mask, q_pos >= k_pos)
-
-        def only(hd, x):
-            # `heads` heads lie side by side along the lanes.  One
-            # head's products come from operands with the other heads'
-            # lanes zeroed: a contraction over them adds nothing, a
-            # product with them lands in this head's lanes of the
-            # accumulator, and the MXU does the work of one d-wide
-            # product either way (its depth and width are 128).
-            if heads == 1:
-                return x
-            lane = jax.lax.broadcasted_iota(jnp.int32, (1, heads * d), 1)
-            return jnp.where(lane // d == hd, x, jnp.zeros_like(x))
-
+            mask = _tile_mask(j, kb, block_q, block_k, causal, t_kv_real)
         for hd in range(heads):
-            q_h, k_h, do_h = only(hd, q), only(hd, k), only(hd, do)
+            q_h, k_h, do_h = (_only(hd, x, heads, d) for x in (q, k, do))
             s_t = jax.lax.dot_general(
                 k, q_h, _NT, preferred_element_type=jnp.float32) * scale
             p_t = jnp.exp(s_t - lse_ref[0, hd:hd + 1, :])
@@ -247,7 +231,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)
             dp_t = jax.lax.dot_general(
                 v, do_h, _NT, preferred_element_type=jnp.float32)
-            ds_t = (p_t * (dp_t - delta_ref[0, hd:hd + 1, :])).astype(q.dtype)
+            ds_t = (p_t * (dp_t - delta_acc[j, hd:hd + 1, :])).astype(q.dtype)
             dk_acc[...] += jax.lax.dot_general(
                 ds_t, q_h, _NN, preferred_element_type=jnp.float32)
             dq_acc[j] += jax.lax.dot_general(
@@ -263,43 +247,109 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = (dq_acc[j] * scale).astype(dq_ref.dtype)
 
 
-def _pack(x, g):
-    """[b, t, h, d] -> [b*h/g, t, g*d]: ``g`` neighbouring heads side by
-    side along the lanes, so that heads narrower than the 128 lanes do
-    not leave the rest of every HBM tile and vector register empty."""
+def _lay(x, g, block, in_place):
+    """What the kernels take of a ``[b, t, h, d]`` array: ``[b, t, h*d]``,
+    the graph's own array (a block is ``g`` heads' lanes of it), or,
+    where such a block is not whole lane tiles or ``t`` not whole
+    blocks, the fold ``[b*h/g, t, g*d]`` (``g`` neighbouring heads side
+    by side along the lanes) padded in time to whole blocks."""
     b, t, h, d = x.shape
-    return x.reshape(b, t, h // g, g * d).transpose(0, 2, 1, 3).reshape(
+    if in_place:
+        return x.reshape(b, t, h * d)
+    x = x.reshape(b, t, h // g, g * d).transpose(0, 2, 1, 3).reshape(
         b * h // g, t, g * d)
+    return jnp.pad(x, ((0, 0), (0, (-t) % block), (0, 0)))
 
 
-def _unpack(x, b, g):
-    n, t, gd = x.shape
-    return x.reshape(b, n // b, t, gd).transpose(0, 2, 1, 3).reshape(
-        b, t, n // b * g, gd // g)
+def _unlay(x, shape, g, in_place):
+    """``_lay``'s inverse: back to the graph's ``shape``, [b, t, h, d]."""
+    b, t, h, d = shape
+    if in_place:
+        return x.reshape(b, t, h, d)
+    return x[:, :t].reshape(b, h // g, t, g * d).transpose(
+        0, 2, 1, 3).reshape(b, t, h, d)
 
 
-def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+def _compiler_params(interpret, semantics, vmem):
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=min(max(2 * vmem, 32 << 20), 100 << 20))}
+
+
+def _fwd_call(q, k, v, causal, scale, block_q, block_k, g, in_place,
               interpret):
-    """q/k/v/o/do: [b, t, h, d], lse: [b*h, t_q_pad] -> (dq, dk, dv)."""
-    b, t_q, h, d = q.shape
+    """q/k/v: [b, t, h, d] -> (o [b, t_q, h, d], lse [b*h/g, g, t_q_pad])."""
+    d = q.shape[3]
     t_kv = k.shape[1]
-    # as many neighbouring heads a grid step as fit the lanes
-    g = max(n for n in range(1, h + 1)
-            if h % n == 0 and (n == 1 or n * d <= _LANES))
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    qp = _pad_time(_pack(q, g), block_q)
-    dop = _pad_time(_pack(do, g), block_q)   # zero rows: no gradient
-    kp = _pad_time(_pack(k, g), block_k)
-    vp = _pad_time(_pack(v, g), block_k)
-    n, t_qp, gd = qp.shape
+    qp = _lay(q, g, block_q, in_place)
+    kp = _lay(k, g, block_k, in_place)
+    vp = _lay(v, g, block_k, in_place)
+    n, t_qp, width = qp.shape
     t_kvp = kp.shape[1]
-    n_qb = t_qp // block_q
-    n_kb = t_kvp // block_k
-    # per-row state as lane-dense rows, a head a row; lse comes padded
-    # from the forward
-    lse = lse.reshape(n, g, t_qp)
-    delta = jnp.pad(delta.transpose(0, 2, 1).reshape(n, g, t_q),
-                    ((0, 0), (0, 0), (0, t_qp - t_q)))
+    gd = g * d
+    n_hg = width // gd          # groups along the lanes: 1 when folded
+    n_qb, n_kb = t_qp // block_q, t_kvp // block_k
+
+    def k_blk(j, kb):
+        # a dead tile (keys after this q-block) asks for the block the
+        # last live one did, so nothing is fetched for it
+        if causal:
+            kb = jnp.minimum(kb, ((j + 1) * block_q - 1) // block_k)
+        return kb
+
+    q_spec = pl.BlockSpec((1, block_q, gd), lambda i, hg, j, kb: (i, j, hg))
+    kv_spec = pl.BlockSpec((1, block_k, gd),
+                           lambda i, hg, j, kb: (i, k_blk(j, kb), hg))
+    row_spec = pl.BlockSpec((1, g, block_q),
+                            lambda i, hg, j, kb: (i * n_hg + hg, 0, j))
+    kernel = functools.partial(
+        _fwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
+        masked=causal or t_kvp != t_kv, scale=scale, t_kv_real=t_kv,
+        heads=g, d=d)
+    lanes = -(-gd // _LANES) * _LANES
+    vmem = (4 * block_q * lanes                                  # acc
+            + 4 * (2 * block_q + 2 * block_k) * lanes * q.dtype.itemsize
+            + 6 * 4 * block_q * block_k)              # the tile's values
+    o, lse = pl.pallas_call(
+        kernel,
+        grid=(n, n_hg, n_qb, n_kb),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(qp.shape, q.dtype),
+            jax.ShapeDtypeStruct((n * n_hg, g, t_qp), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((gd, block_q), jnp.float32),   # acc, transposed
+            pltpu.VMEM((g, block_q), jnp.float32),    # running max
+            pltpu.VMEM((g, block_q), jnp.float32),    # running denom
+        ],
+        interpret=interpret,
+        name="flash_attention_fwd",
+        **_compiler_params(
+            interpret, ("parallel", "parallel", "parallel", "arbitrary"),
+            vmem),
+    )(qp, kp, vp)
+    return _unlay(o, q.shape, g, in_place), lse
+
+
+def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, g,
+              in_place, interpret):
+    """q/k/v/o/do: [b, t, h, d], lse: [b*h/g, g, t_q_pad] -> (dq, dk, dv)."""
+    d = q.shape[3]
+    t_kv = k.shape[1]
+    qp = _lay(q, g, block_q, in_place)
+    op = _lay(o, g, block_q, in_place)
+    dop = _lay(do, g, block_q, in_place)     # zero rows: no gradient
+    kp = _lay(k, g, block_k, in_place)
+    vp = _lay(v, g, block_k, in_place)
+    n, t_qp, width = qp.shape
+    t_kvp = kp.shape[1]
+    gd = g * d
+    n_hg = width // gd
+    n_qb, n_kb = t_qp // block_q, t_kvp // block_k
 
     def q_blk(kb, j):
         # a dead tile (queries before this k-block) asks for the block the
@@ -310,34 +360,33 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         return j
 
     q_spec = pl.BlockSpec((1, block_q, gd),
-                          lambda i, kb, j: (i, q_blk(kb, j), 0))
-    row_spec = pl.BlockSpec((1, g, block_q),
-                            lambda i, kb, j: (i, 0, q_blk(kb, j)))
-    kv_spec = pl.BlockSpec((1, block_k, gd), lambda i, kb, j: (i, kb, 0))
+                          lambda i, hg, kb, j: (i, q_blk(kb, j), hg))
+    # O is read in the first pass alone, where delta is taken
+    o_spec = pl.BlockSpec(
+        (1, block_q, gd),
+        lambda i, hg, kb, j: (i, jnp.where(kb == 0, j, n_qb - 1), hg))
+    row_spec = pl.BlockSpec(
+        (1, g, block_q),
+        lambda i, hg, kb, j: (i * n_hg + hg, 0, q_blk(kb, j)))
+    kv_spec = pl.BlockSpec((1, block_k, gd), lambda i, hg, kb, j: (i, kb, hg))
     # dq's block stays put until the last k-block, so each block goes to
     # HBM once, after its last contribution
     dq_spec = pl.BlockSpec(
         (1, block_q, gd),
-        lambda i, kb, j: (i, jnp.where(kb == n_kb - 1, j, 0), 0))
+        lambda i, hg, kb, j: (i, jnp.where(kb == n_kb - 1, j, 0), hg))
     kernel = functools.partial(
         _bwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
         masked=causal or t_kvp != t_kv, scale=scale, t_kv_real=t_kv,
         heads=g, d=d)
-    from jax.experimental.pallas import tpu as pltpu
-    kwargs = {}
-    if not interpret:
-        lanes = -(-gd // _LANES) * _LANES
-        vmem = (4 * t_qp * lanes                          # dq, resident
-                + 2 * 4 * block_k * lanes                 # dk, dv
-                + 12 * (block_q + block_k) * lanes * q.dtype.itemsize
-                + 6 * 4 * block_q * block_k)              # the tile's values
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=min(max(2 * vmem, 32 << 20), 100 << 20))
+    lanes = -(-gd // _LANES) * _LANES
+    vmem = (4 * t_qp * lanes                          # dq, resident
+            + 2 * 4 * block_k * lanes                 # dk, dv
+            + 12 * (block_q + block_k) * lanes * q.dtype.itemsize
+            + 6 * 4 * block_q * block_k)              # the tile's values
     dq, dk, dv = pl.pallas_call(
         kernel,
-        grid=(n, n_kb, n_qb),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        grid=(n, n_hg, n_kb, n_qb),
+        in_specs=[q_spec, kv_spec, kv_spec, o_spec, q_spec, row_spec],
         out_specs=[dq_spec, kv_spec, kv_spec],
         out_shape=[
             jax.ShapeDtypeStruct(qp.shape, q.dtype),
@@ -348,39 +397,65 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
             pltpu.VMEM((n_qb, block_q, gd), jnp.float32),  # dq
             pltpu.VMEM((block_k, gd), jnp.float32),        # dk
             pltpu.VMEM((block_k, gd), jnp.float32),        # dv
+            pltpu.VMEM((n_qb, g, block_q), jnp.float32),   # delta
         ],
         interpret=interpret,
         name="flash_attention_bwd",
-        **kwargs,
-    )(qp, kp, vp, dop, lse, delta)
-    return (_unpack(dq[:, :t_q], b, g), _unpack(dk[:, :t_kv], b, g),
-            _unpack(dv[:, :t_kv], b, g))
+        **_compiler_params(
+            interpret, ("parallel", "parallel", "arbitrary", "arbitrary"),
+            vmem),
+    )(qp, kp, vp, op, dop, lse)
+    return (_unlay(dq, q.shape, g, in_place),
+            _unlay(dk, k.shape, g, in_place),
+            _unlay(dv, v.shape, g, in_place))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    return _flash_fwd(q, k, v, causal, scale, block_q, block_k,
-                      interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 10)))
+def _flash(q, k, v, causal, scale, block_q, block_k, g, in_place, interpret):
+    return _fwd_call(q, k, v, causal, scale, block_q, block_k, g, in_place,
+                     interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    # the forward kernel takes [b*h, t, d]
-    o, lse = _fwd_impl(_pack(q, 1), _pack(k, 1), _pack(v, 1), causal, scale,
-                       block_q, block_k, interpret)
-    o = _unpack(o, q.shape[0], 1)
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, g, in_place,
+               interpret):
+    o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, g,
+                       in_place, interpret)
     # what lives between the passes is q, k, v and o as the graph has
-    # them, [b, t, h, d], and lse [b*h, t] float32: each pass folds them
-    # its own way
+    # them, [b, t, h, d], and lse [b*h/g, g, t] float32
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
+def _flash_bwd(causal, scale, block_q, block_k, g, in_place, interpret, res,
+               do):
     q, k, v, o, lse = res
     return _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
-                     interpret)
+                     g, in_place, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _plan(t_q, t_kv, h, d, block_q, block_k):
+    """(block_q, block_k, g, in_place) for these shapes: the blocks
+    clamped to the sequences, how many heads go side by side, and
+    whether the kernels can read the graph's arrays where they lie."""
+    def clamp(block, t):
+        # clamp to the sequence but keep the block LANE-ALIGNED: a raw
+        # min(block, t) for 128 < t < block would hand Mosaic a
+        # non-tile-multiple block shape (t=300 -> (300, d) blocks);
+        # rounding t up to a 128 multiple keeps one aligned block and
+        # the pad in time makes the array match
+        return min(block, -(-max(t, 1) // _LANES) * _LANES)
+    block_q = clamp(block_q, t_q)
+    block_k = clamp(block_k, t_kv)
+    # as many neighbouring heads a grid step as fit the lanes
+    g = max(n for n in range(1, h + 1)
+            if h % n == 0 and (n == 1 or n * d <= _LANES))
+    # in place where a block is whole lane tiles and the sequences are
+    # whole blocks; else a fold and a pad feed the same kernels
+    in_place = (g * d % _LANES == 0 and t_q % block_q == 0
+                and t_kv % block_k == 0)
+    return block_q, block_k, g, in_place
 
 
 def flash_attention(q, k, v, causal=False, scale=None,
@@ -395,21 +470,14 @@ def flash_attention(q, k, v, causal=False, scale=None,
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    t_q, t_kv, d = q.shape[1], k.shape[1], q.shape[3]
+    _, t_q, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-
-    def clamp(block, t):
-        # clamp to the sequence but keep the block LANE-ALIGNED: a raw
-        # min(block, t) for 128 < t < block would hand Mosaic a
-        # non-tile-multiple block shape (t=300 -> (300, d) blocks);
-        # rounding t up to a 128 multiple keeps one aligned block and
-        # the _pad_time path pads the array to match
-        return min(block, -(-max(t, 1) // _LANES) * _LANES)
-    block_q = clamp(block_q, t_q)
-    block_k = clamp(block_k, t_kv)
-
-    return _flash(q, k, v, causal, float(scale), block_q, block_k, interpret)
+    block_q, block_k, g, in_place = _plan(t_q, k.shape[1], h, d,
+                                          block_q, block_k)
+    (_IN_PLACE if in_place else _FOLDED).inc()
+    return _flash(q, k, v, causal, float(scale), block_q, block_k, g,
+                  in_place, interpret)
 
 
 def flash_attention_reference(q, k, v, causal=False, scale=None):

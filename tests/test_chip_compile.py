@@ -9,7 +9,9 @@ at the widths the main path uses, so a kernel the chip would refuse
 compile, not a run: ``chip_smoke.py`` is the run.
 """
 import os
+import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -41,45 +43,121 @@ def v5e():
     compilation_cache.reset_cache()
 
 
+# (shape, dtype, causal, in place): the kernels read q, k, v, o and dO
+# where the graph has them unless the time length needs padding
 SHAPES = [
-    pytest.param((8, 1024, 16, 64), jnp.bfloat16, True, id="gpt2-medium"),
-    pytest.param((2, 8192, 8, 64), jnp.bfloat16, True, id="long-context"),
-    pytest.param((4, 2048, 8, 128), jnp.bfloat16, True, id="head-dim-128"),
-    pytest.param((4, 1000, 8, 64), jnp.float32, False, id="ragged-f32"),
-    pytest.param((1, 4096, 20, 256), jnp.bfloat16, True, id="glm-4.7-flash"),
+    pytest.param((8, 1024, 16, 64), jnp.bfloat16, True, True,
+                 id="gpt2-medium"),
+    pytest.param((2, 8192, 8, 64), jnp.bfloat16, True, True,
+                 id="long-context"),
+    pytest.param((4, 2048, 8, 128), jnp.bfloat16, True, True,
+                 id="head-dim-128"),
+    pytest.param((4, 1000, 8, 64), jnp.float32, False, False,
+                 id="ragged-f32"),
+    pytest.param((1, 4096, 20, 256), jnp.bfloat16, True, True,
+                 id="glm-4.7-flash"),
 ]
 
 
+def _node(q, k, v, shape, causal):
+    """The attention node between the products that feed it and the one
+    it feeds, which hold ``[b, t, h*d]``: the reshapes to and from the
+    op's ``[b, t, h, d]`` are bitcasts."""
+    o = flash_attention(*(a.reshape(shape) for a in (q, k, v)),
+                        causal=causal, interpret=False)
+    return o.reshape(q.shape)
+
+
 def _compiled_text(fn, shape, dtype, sharding):
-    x = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    b, t, h, d = shape
+    x = jax.ShapeDtypeStruct((b, t, h * d), dtype, sharding=sharding)
     return jax.jit(fn).lower(x, x, x).compile().as_text()
 
 
-@pytest.mark.parametrize("shape,dtype,causal", SHAPES)
-def test_flash_forward_compiles_for_v5e(v5e, shape, dtype, causal):
-    def forward(q, k, v):
-        return flash_attention(q, k, v, causal=causal, interpret=False)
+def _kernels(text):
+    """The names of the module's Pallas kernels, in order."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return [re.search(r"flash_attention_(?:fwd|bwd)", line).group(0)
+            for line in calls]
 
-    assert "tpu_custom_call" in _compiled_text(forward, shape, dtype, v5e)
+
+def _layout_passes(text, shape, scope=""):
+    """The instructions, fused or not, that move a whole operand of the
+    kernels about: a ``transpose``, a ``pad``, or the ``copy`` a
+    transpose is once the compiler has assigned layouts.  ``scope``
+    keeps those traced under a node of that name."""
+    whole = int(np.prod(shape))
+    found = []
+    for line in text.splitlines():
+        if scope not in line:
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"(transpose|pad|copy)\(", line)
+        if m and np.prod([int(n) for n in m.group(1).split(",") if n]) \
+                >= whole:
+            found.append(line.strip()[:120])
+    return found
 
 
-@pytest.mark.parametrize("shape,dtype,causal", SHAPES)
-def test_flash_gradient_compiles_for_v5e(v5e, shape, dtype, causal):
+@pytest.mark.parametrize("shape,dtype,causal,in_place", SHAPES)
+def test_flash_forward_compiles_for_v5e(v5e, shape, dtype, causal, in_place):
+    text = _compiled_text(lambda q, k, v: _node(q, k, v, shape, causal),
+                          shape, dtype, v5e)
+    assert _kernels(text) == ["flash_attention_fwd"]
+    # folded, the detector has something to find: it is not blind
+    assert bool(_layout_passes(text, shape)) != in_place
+
+
+@pytest.mark.parametrize("shape,dtype,causal,in_place", SHAPES)
+def test_flash_gradient_compiles_for_v5e(v5e, shape, dtype, causal, in_place):
     """The reverse mode is a kernel of its own, not a loop of einsums:
     the forward's custom call alone does not pass, and a tile the v5e's
     compiler refuses (VMEM at head 256 over 4,096 positions) fails
-    here."""
+    here.  Where the kernels read in place, nothing in the module
+    transposes, pads or copies q, k, v, o or dO."""
     def loss(q, k, v):
-        o = flash_attention(q, k, v, causal=causal, interpret=False)
-        return jnp.sum(o.astype(jnp.float32))
+        return jnp.sum(_node(q, k, v, shape, causal).astype(jnp.float32)
+                       ** 2)
 
-    grad = jax.grad(loss, argnums=(0, 1, 2))
-    text = _compiled_text(grad, shape, dtype, v5e)
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 2, len(calls)       # the forward and the backward
-    assert sum("flash_attention_bwd" in line for line in calls) == 1
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), shape, dtype,
+                          v5e)
+    assert _kernels(text) == ["flash_attention_fwd", "flash_attention_bwd"]
     assert " while(" not in text
+    passes = _layout_passes(text, shape)
+    assert bool(passes) != in_place, passes
+
+
+def test_transformer_step_feeds_the_kernels_with_no_layout_pass(v5e):
+    """The fused training step of ``models.get_symbol("transformer")``,
+    two layers at GPT-2 medium's width and batch, compiled for the v5e:
+    q, k and v reach the forward kernel, and dq, dk and dv leave the
+    backward kernel, with no transpose, pad or copy of a whole
+    ``[8, 1024, 1024]`` array.  (Sliced along the "3" of a
+    ``[b, t, 3, h, d]`` view of the qkv product, XLA laid the product out
+    with that axis major and put six such copies a layer around the
+    kernels.)"""
+    import mxnet_tpu as mx
+    from mxnet_tpu import models
+    from mxnet_tpu.parallel.trainer import Trainer
+
+    sym = models.get_symbol("transformer", seq_len=1024, num_hidden=1024,
+                            num_heads=16, num_layers=2, vocab_size=2048)
+    trainer = Trainer(sym, mx.optimizer.SGD(learning_rate=0.02, momentum=0.9),
+                      compute_dtype="bfloat16")      # no mesh: one chip
+    trainer.bind(data_shapes={"data": (8, 1024)},
+                 label_shapes={"softmax_label": (8, 1024)})
+    trainer.init_params(mx.init.Normal(0.02))
+    trainer.prog.platform = "tpu"       # the op takes its compiled path
+    args = trainer.abstract_step_args({"data": np.int32,
+                                       "softmax_label": np.int32})
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.result_type(a),
+                                       sharding=v5e), args)
+    text = trainer._step_fn.lower(*args).compile().as_text()
+    assert _kernels(text) == ["flash_attention_fwd"] * 2 \
+        + ["flash_attention_bwd"] * 2
+    assert _layout_passes(text, (8, 1024, 16, 64), scope="_attn_attn") == []
 
 
 def test_expert_layer_compiles_to_the_chips_grouped_kernels(v5e):

@@ -1,9 +1,13 @@
 """Shape inference tests (reference
 ``tests/python/unittest/test_infer_shape.py``)."""
+import json
+import os
+
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
+from mxnet_tpu import models
 
 
 def test_mlp_infer():
@@ -77,3 +81,76 @@ def test_variable_shape_attr_used():
     out = mx.symbol.tanh(v)
     _, out_shapes, _ = out.infer_shape()
     assert out_shapes == [(5, 5)]
+
+
+# ----------------------------------------------------------------------
+# the attention op's shape rule: no walk of a Symbol's shapes traces a
+# Pallas kernel (set-up walks a Symbol three times, and a trace of the
+# flash kernels a node on every walk was most of those walks' time)
+_BENCH_CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "configs")
+
+
+@pytest.fixture
+def no_kernel(monkeypatch):
+    """``pl.pallas_call`` counts its calls and refuses each."""
+    from jax.experimental import pallas as pl
+    calls = []
+
+    def refuse(*args, **kwargs):
+        calls.append(kwargs.get("name"))
+        raise AssertionError("a walk of shapes traced a Pallas kernel")
+
+    monkeypatch.setattr(pl, "pallas_call", refuse)
+    return calls
+
+
+@pytest.mark.parametrize("config,batch,outputs", [
+    pytest.param("gpt2-medium", (8, 1024), [(8192, 50257)],
+                 id="gpt2m_train"),
+    pytest.param("glm-4.7-flash", (1, 4096), [(4096, 19360), (4096, 19360)],
+                 id="glm47flash_train"),
+])
+def test_walk_of_a_cells_symbol_traces_no_kernel(no_kernel, config, batch,
+                                                 outputs):
+    """The two token cells' Symbols at their published sizes, 24 and 6
+    attention nodes: ``infer_shape`` succeeds with the kernel refused."""
+    with open(os.path.join(_BENCH_CONFIGS, config + ".json")) as f:
+        symbol = json.load(f)["symbol"]
+    sym = models.get_symbol(symbol["network"], **symbol["kwargs"])
+    nodes = json.loads(sym.tojson())["nodes"]
+    assert sum(n["op"] == "_contrib_DotProductAttention" for n in nodes) \
+        == {"gpt2-medium": 24, "glm-4.7-flash": 6}[config]
+    arg_shapes, out_shapes, _ = sym.infer_shape(data=batch,
+                                                softmax_label=batch)
+    assert no_kernel == []
+    assert [tuple(s) for s in out_shapes] == outputs
+    assert all(s is not None and 0 not in s for s in arg_shapes)
+
+
+@pytest.mark.parametrize("q,k,v,flash", [
+    pytest.param((8, 1024, 16, 64), (8, 1024, 16, 64), (8, 1024, 16, 64),
+                 True, id="gpt2m_train"),
+    pytest.param((1, 4096, 20, 256), (1, 4096, 20, 256), (1, 4096, 20, 256),
+                 True, id="glm47flash_train"),
+    pytest.param((2, 128, 4, 64), (2, 384, 4, 64), (2, 384, 4, 64),
+                 True, id="t_q-not-t_kv"),
+    # the kernels take one width for q, k and v; the plain path does not
+    pytest.param((2, 128, 4, 192), (2, 256, 4, 192), (2, 256, 4, 128),
+                 False, id="d_v-not-d_qk"),
+])
+def test_attention_shape_rule_is_what_the_op_body_returns(q, k, v, flash):
+    import jax
+    from mxnet_tpu.op import registry
+    from mxnet_tpu.op.attention import _attention_infer_shape
+    op = registry.get("_contrib_DotProductAttention")
+    params = op.parse_params({"causal": True, "flash": flash})
+    body = jax.eval_shape(
+        lambda *xs: op.fn(params, registry.OpContext(), *xs),
+        *(jax.ShapeDtypeStruct(s, np.float32) for s in (q, k, v)))
+    in_s, out_s, aux_s = op.infer_shape_generic(params, [q, k, v])
+    assert out_s == [tuple(body.shape)] == [q[:3] + v[3:]]
+    assert in_s == [q, k, v] and aux_s == []
+    # an unknown input is the generic path's to refuse
+    assert _attention_infer_shape(params, [q, None, v]) is None
+    assert _attention_infer_shape(params, [q, k, (0,) * 4]) is None
