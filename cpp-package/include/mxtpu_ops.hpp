@@ -431,6 +431,20 @@ inline std::vector<NDArray> _contrib_DotProductAttention(
   return Invoke("_contrib_DotProductAttention", inputs, kw);
 }
 
+inline std::vector<NDArray> _contrib_GatedDeltaRule(
+    const std::vector<NDArray> &inputs,
+    const KWArgs &extra = {}) {
+  KWArgs kw(extra);
+  return Invoke("_contrib_GatedDeltaRule", inputs, kw);
+}
+
+inline std::vector<NDArray> _contrib_ShortConv(
+    const std::vector<NDArray> &inputs,
+    const KWArgs &extra = {}) {
+  KWArgs kw(extra);
+  return Invoke("_contrib_ShortConv", inputs, kw);
+}
+
 inline std::vector<NDArray> _div(
     const std::vector<NDArray> &inputs,
     const KWArgs &extra = {}) {

@@ -3,8 +3,10 @@
 Mirrors the capability of ``example/image-classification/symbols/`` in the
 reference (mlp, lenet, alexnet, vgg, resnet, resnext, googlenet,
 inception-bn, inception-v3, inception-resnet-v2) plus the bucketing LSTM
-language model (``example/rnn/lstm_bucketing.py``), a transformer and a
-latent-attention mixture-of-experts language model (``glm-moe``).
+language model (``example/rnn/lstm_bucketing.py``), a transformer, a
+latent-attention mixture-of-experts language model (``glm-moe``) and a
+hybrid whose blocks mix by a delta rule with a state along the sequence
+or by latent attention (``bailing-hybrid``).
 Architectures are standard published networks, written fresh in
 mxnet_tpu Symbol idiom; the graphs compile to single XLA computations.
 
@@ -25,10 +27,12 @@ from . import lstm_lm
 from . import resnext
 from . import transformer
 from . import glm_moe
+from . import bailing_hybrid
 
 __all__ = ["get_symbol", "mlp", "lenet", "alexnet", "vgg", "resnet",
            "resnext", "googlenet", "inception_bn", "inception_v3",
-           "inception_resnet_v2", "lstm_lm", "transformer", "glm_moe"]
+           "inception_resnet_v2", "lstm_lm", "transformer", "glm_moe",
+           "bailing_hybrid"]
 
 _BUILDERS = {
     "mlp": mlp.get_symbol,
@@ -41,6 +45,7 @@ _BUILDERS = {
     "transformer": transformer.get_symbol,
     "gpt": transformer.get_symbol,
     "glm-moe": glm_moe.get_symbol,
+    "bailing-hybrid": bailing_hybrid.get_symbol,
 }
 
 

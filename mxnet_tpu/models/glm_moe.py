@@ -11,7 +11,11 @@ part and drops no entry) and one shared expert.  Attention is latent
 expanded from a ``kv_lora_rank`` latent, a rotary part of
 ``qk_rope_head_dim`` dims whose key is one vector a position shared by
 all heads; expanded, it is ordinary causal attention at head dimension
-``qk_nope_head_dim + qk_rope_head_dim`` and runs the flash kernel.
+``qk_nope_head_dim + qk_rope_head_dim`` and runs the flash kernel
+(``v_head_dim`` may differ from it: the attention op pads the narrower
+operands with zero columns and cuts the result, see ``op/attention.py``).
+``_linear``, ``_gated_ffn``, ``_expert_layer`` and ``_block`` (which
+takes its mixer) also build ``models/bailing_hybrid.py``.
 
 The multi-token-prediction module (arXiv:2412.19437 sec. 2.2, depth 1)
 joins the trunk's last hidden state at position i with the embedding of
@@ -83,6 +87,8 @@ def _expert_layer(x, cfg):
     """Shared expert plus this chip's share of the routed experts."""
     router = sym.MoERouter(x, num_experts=cfg["n_experts"],
                            top_k=cfg["top_k"], scale=cfg["scaling"],
+                           n_group=cfg.get("n_group", 1),
+                           topk_group=cfg.get("topk_group", 1),
                            name="moe_router")
     routed = sym.MoEExperts(x, router[0], router[1],
                             num_experts=cfg["n_experts"],
@@ -94,11 +100,13 @@ def _expert_layer(x, cfg):
                       "moe_shared_") + routed
 
 
-def _block(x, cfg, prefix, dense):
-    """One pre-norm block; every node's name starts with ``prefix``."""
+def _block(x, cfg, prefix, dense, mixer=None):
+    """One pre-norm block; every node's name starts with ``prefix``.
+    ``mixer(x, cfg)`` is the block's first half, latent attention
+    unless another is given."""
     with _name.Prefix(prefix):
-        x = x + _attention(sym.RMSNorm(x, eps=cfg["eps"], name="norm1"),
-                           cfg)
+        x = x + (mixer or _attention)(
+            sym.RMSNorm(x, eps=cfg["eps"], name="norm1"), cfg)
         h = sym.RMSNorm(x, eps=cfg["eps"], name="norm2")
         if dense:
             return x + _gated_ffn(h, cfg["dense_width"], cfg["hidden"],
@@ -121,11 +129,6 @@ def get_symbol(num_classes=512, vocab_size=None, seq_len=32, hidden_size=64,
     vocab = vocab_size or num_classes
     if mtp_layers not in (0, 1):
         raise ValueError("mtp_layers is 0 or 1, got %r" % (mtp_layers,))
-    if qk_nope_head_dim + qk_rope_head_dim != v_head_dim:
-        raise ValueError(
-            "the attention kernel takes one head dimension: qk_nope + "
-            "qk_rope (%d) has to equal v_head_dim (%d)"
-            % (qk_nope_head_dim + qk_rope_head_dim, v_head_dim))
     cfg = dict(seq_len=seq_len, hidden=hidden_size, num_heads=num_heads,
                q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
                qk_nope=qk_nope_head_dim, qk_rope=qk_rope_head_dim,

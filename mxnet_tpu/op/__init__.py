@@ -14,3 +14,4 @@ from . import rnn_op  # noqa: F401
 from . import attention  # noqa: F401
 from . import ctc  # noqa: F401
 from . import moe  # noqa: F401
+from . import delta_rule  # noqa: F401

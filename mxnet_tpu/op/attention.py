@@ -5,14 +5,22 @@ bucketing + truncated BPTT were its only sequence-scaling tools), so these
 are greenfield capability ops.  ``_contrib_DotProductAttention`` is exact
 multi-head attention over ``[batch, time, heads, dim]`` inputs; on TPU it
 runs the Pallas flash kernel (O(T*block) memory, MXU-blocked); elsewhere a
-jnp oracle with identical semantics.  Sequence parallelism over a mesh is
+jnp oracle with identical semantics.  Value heads may be narrower or wider
+than query/key heads (latent attention at 192 / 128): the kernels take one
+head dimension, so the three operands are padded with zero columns to the
+next whole lane tile and the result is cut to the value width, which is
+exact.  Sequence parallelism over a mesh is
 ``mx.parallel.ring_attention`` — same math, K/V rotated over ICI.
 """
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from .registry import Param, register
+
+
+_LANES = 128
 
 
 def _attention_infer_shape(p, in_shapes):
@@ -47,7 +55,17 @@ def _dot_product_attention(p, c, q, k, v):
             kw["block_q"] = p["block_q"]
         if p["block_k"]:
             kw["block_k"] = p["block_k"]
-        return flash_attention(q, k, v, causal=p["causal"], scale=scale,
-                               interpret=interpret, **kw)
+        d_qk, d_v = q.shape[-1], v.shape[-1]
+        if d_qk != d_v:
+            # the kernels take one head dimension: zero columns up to
+            # whole lane tiles (192 / 128 -> 256) add nothing to a score
+            # and leave zero columns of the result, which are cut off
+            width = -(-max(d_qk, d_v) // _LANES) * _LANES
+            q, k, v = (jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
+                       for x in (q, k, v))
+            scale = scale or d_qk ** -0.5
+        out = flash_attention(q, k, v, causal=p["causal"], scale=scale,
+                              interpret=interpret, **kw)
+        return out if d_qk == d_v else out[..., :d_v]
     from ..parallel.ring_attention import attention_reference
     return attention_reference(q, k, v, causal=p["causal"], scale=scale)

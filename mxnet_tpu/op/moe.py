@@ -19,6 +19,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
+from ..base import MXNetError
 from .registry import Param, register
 from . import registry as _reg
 
@@ -26,7 +27,9 @@ from . import registry as _reg
 @register("MoERouter",
           params_spec=(Param("num_experts", int, required=True),
                        Param("top_k", int, required=True),
-                       Param("scale", float, 1.0)),
+                       Param("scale", float, 1.0),
+                       Param("n_group", int, 1),
+                       Param("topk_group", int, 1)),
           input_names=("data", "weight"), aux_names=("bias",),
           num_outputs=3,
           output_names=lambda p: ["expert", "weight", "score"],
@@ -36,12 +39,19 @@ def _moe_router(p, c, data, weight, bias):
     int32, their weights (T, k) and all scores (T, num_experts), both
     float32: sigmoid scores from a product accumulated in float32, the
     ``top_k`` by score + bias, weights renormalized over the chosen and
-    times ``scale``."""
+    times ``scale``.  ``n_group`` > 1 limits the choice to the experts
+    of the ``topk_group`` groups whose two best score + bias sum
+    highest (``num_experts`` / ``n_group`` neighbours a group)."""
     from ..parallel.moe import sigmoid_topk_route
+    if p["num_experts"] % p["n_group"] or not \
+            1 <= p["topk_group"] <= p["n_group"]:
+        raise MXNetError(
+            "MoERouter: %d experts in %d groups of which %d are kept"
+            % (p["num_experts"], p["n_group"], p["topk_group"]))
     logits = lax.dot_general(data, weight, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    expert, wt, score = sigmoid_topk_route(logits, bias, p["top_k"],
-                                           p["scale"])
+    expert, wt, score = sigmoid_topk_route(
+        logits, bias, p["top_k"], p["scale"], p["n_group"], p["topk_group"])
     return expert, wt, score, bias
 
 

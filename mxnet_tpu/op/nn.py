@@ -387,16 +387,18 @@ def _rms_norm(p, c, data, gamma):
 @register("RotaryEmbedding",
           params_spec=(Param("base", float, 10000.0),
                        Param("offset", int, 0),
-                       Param("dim", int, 0)),
+                       Param("dim", int, 0),
+                       Param("interleaved", bool, False)),
           hint="rotaryembedding")
 def _rotary_embedding(p, c, data):
     """Rotary position embedding on a slice of the head dimension.
 
     ``data`` (batch, time, heads, head_dim); position t is the index
     along axis 1.  Dims ``offset .. offset + dim`` of the last axis
-    (``dim`` 0: to the end) are rotated in the rotate-half pairing, dim
-    i with dim i + dim/2, by the angle t * base**(-2i/dim); the other
-    dims pass through.  Angles and the rotation in float32."""
+    (``dim`` 0: to the end) are rotated by the angle t * base**(-2i/dim)
+    in pairs: dim i with dim i + dim/2 (rotate-half), or with
+    ``interleaved`` dim 2i with dim 2i + 1; the other dims pass through.
+    Angles and the rotation in float32."""
     lo = p["offset"]
     n = p["dim"] or data.shape[-1] - lo
     half = n // 2
@@ -404,9 +406,18 @@ def _rotary_embedding(p, c, data):
     ang = jnp.arange(data.shape[1], dtype=jnp.float32)[:, None] * inv_freq
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x = data[..., lo:lo + n].astype(jnp.float32)
-    x1, x2 = x[..., :half], x[..., half:]
-    rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                          axis=-1).astype(data.dtype)
+    if p["interleaved"]:
+        # a pair's partner by two rolls and a select, which keep the
+        # lanes where they are (a reshape to (.., dim/2, 2) would not)
+        cos, sin = jnp.repeat(cos, 2, axis=-1), jnp.repeat(sin, 2, axis=-1)
+        even = jnp.arange(n) % 2 == 0
+        partner = jnp.where(even, -jnp.roll(x, -1, axis=-1),
+                            jnp.roll(x, 1, axis=-1))
+        rot = (x * cos + partner * sin).astype(data.dtype)
+    else:
+        x1, x2 = x[..., :half], x[..., half:]
+        rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1).astype(data.dtype)
     return jnp.concatenate([data[..., :lo], rot, data[..., lo + n:]],
                            axis=-1)
 
@@ -589,8 +600,10 @@ def _l2_normalization(p, c, a):
         axes = (1,)
     else:
         axes = tuple(range(2, a.ndim))
-    norm = jnp.sqrt(jnp.sum(a * a, axis=axes, keepdims=True) + p["eps"])
-    return a / norm
+    # the sum of squares in float32 whatever the input's type
+    x = a.astype(jnp.float32)
+    norm = jnp.sqrt(jnp.sum(x * x, axis=axes, keepdims=True) + p["eps"])
+    return (x / norm).astype(a.dtype)
 
 
 @register("LRN", params_spec=(Param("alpha", float, 1e-4),

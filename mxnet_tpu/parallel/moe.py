@@ -263,16 +263,29 @@ def moe_load_balance_loss(params, x, gates=None):
 
 # ----------------------------------------------------------------------
 # the no-drop path: sigmoid routing, a chip's share of the experts
-def sigmoid_topk_route(logits, bias, top_k, scale=1.0):
+def sigmoid_topk_route(logits, bias, top_k, scale=1.0, n_group=1,
+                       topk_group=1):
     """Sigmoid scores and the ``top_k`` experts a token by score plus
     ``bias`` (the bias only chooses; equal sums go to the lower index).
+    With ``n_group`` > 1 the choice is group-limited: the experts lie in
+    ``n_group`` equal runs, a group's score is the sum of its two largest
+    score + bias, and only the ``topk_group`` best groups' experts can
+    be chosen.
 
     ``logits`` (T, E) -> ``(expert (T, k) int32, weight (T, k), score
     (T, E))`` in float32; a token's weights are its chosen scores over
     their sum, times ``scale``.
     """
     score = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, expert = jax.lax.top_k(score + bias.astype(jnp.float32), top_k)
+    pick = score + bias.astype(jnp.float32)
+    if n_group > 1:
+        tokens, experts = pick.shape
+        best2, _ = jax.lax.top_k(pick.reshape(tokens, n_group, -1), 2)
+        _, groups = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+        kept = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
+        pick = jnp.where(jnp.repeat(kept, experts // n_group, axis=1),
+                         pick, -jnp.inf)
+    _, expert = jax.lax.top_k(pick, top_k)
     # the chosen scores by comparison, not by gather: a gather's
     # reverse mode is a scatter, which the chip runs serially
     chosen = expert[:, :, None] == jnp.arange(score.shape[1])

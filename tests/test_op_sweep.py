@@ -466,6 +466,14 @@ F("_contrib_DotProductAttention",
   {"query": randn(2, 3, 2, 4), "key": randn(2, 3, 2, 4),
    "value": randn(2, 3, 2, 4)},
   check=lambda o: o.shape == (2, 3, 2, 4))
+# a state along the sequence: two chunks of 16 positions; the gate is a
+# log decay and stays below 0
+G("_contrib_GatedDeltaRule",
+  {"query": unit(1, 32, 2, 4), "key": unit(1, 32, 2, 4),
+   "value": randn(1, 32, 2, 3), "gate": -pos(1, 32, 2, 4) * 0.3,
+   "beta": R.uniform(0.2, 0.8, (1, 32, 2)).astype("f")},
+  {"chunk": 16}, rtol=8e-2, atol=2e-2)
+G("_contrib_ShortConv", {"data": randn(2, 6, 3), "weight": randn(3, 4)})
 
 # differentiable aliases exercise the alias path end-to-end
 _ALIAS_GRADS = {
