@@ -4,7 +4,9 @@
 chooses ``top_k`` of them; ``MoEExperts`` is told which experts it holds
 (``experts_held`` of them from ``first_expert``) and computes their part
 of the layer's result for every entry routed to them.  No capacity, no
-dropped entry.  The mathematics is ``parallel/moe.py``'s
+dropped entry, and no buffer for the worst case either: the sorted
+entries are walked in chunks of rows fixed by the shapes, as many trips
+as the step's routing fills.  The mathematics is ``parallel/moe.py``'s
 (:func:`sigmoid_topk_route`, :func:`moe_apply_held`), beside the
 capacity paths that ``parallel/transformer.py`` runs and that do drop.
 
@@ -12,7 +14,8 @@ Both carry an auxiliary state through the step as BatchNorm carries its
 moving statistics: the router its selection bias, which nothing here
 updates (its rule is a training recipe), and the experts the last
 step's count of entries for each of all the experts, which
-``obs.snapshot()`` turns into the load gauges.
+``obs.snapshot()`` turns into the load gauges and the count of chunks
+walked (``moe.row_chunks_per_layer``).
 """
 from __future__ import annotations
 

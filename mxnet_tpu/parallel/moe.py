@@ -36,9 +36,12 @@ discard of ``keep`` is exactly what that counter exists to catch.
 chosen set by score plus a correction bias, and a chip that holds
 ``G`` of the ``E`` experts and computes its own experts' part for
 every entry routed to them, whatever the imbalance.  There is no
-capacity and no ``keep``: the entries are sorted by expert into a
-buffer sized for the worst case (all of them here) and the three
-products are grouped matrix products over the rows that are live.
+capacity and no ``keep``, and no buffer sized for the worst case: the
+entries are sorted by expert and the held experts' run of them is
+walked in chunks of a fixed number of rows, as many trips as the
+routing fills (``moe.row_chunks_per_layer``; one at the expected
+load), the three products of a trip grouped matrix products over its
+rows.
 """
 from __future__ import annotations
 
@@ -55,7 +58,8 @@ from .. import obs as _obs
 __all__ = ["moe_init", "moe_apply", "moe_apply_dense", "moe_apply_sparse",
            "moe_shardings", "moe_load_balance_loss", "moe_capacity",
            "moe_dispatch_bytes", "record_dropped_frac",
-           "sigmoid_topk_route", "moe_apply_held"]
+           "sigmoid_topk_route", "moe_apply_held", "held_chunk_rows",
+           "row_chunks"]
 
 # last observed dropped-token fraction (registry-backed; scraped by
 # obs.snapshot() / tools/obs_report.py).  A fraction, set per call —
@@ -294,40 +298,49 @@ def sigmoid_topk_route(logits, bias, top_k, scale=1.0, n_group=1,
     return expert.astype(jnp.int32), weight, score
 
 
-# Entries move between their own order (entry n is token n // k) and the
-# order sorted by expert through two gathers that are each other's
-# reverse mode: the scatter autodiff would write runs serially on the chip.
-def _collect_rows(ys, inv, k):
-    """(N, d) sorted rows -> (N / k, d): a token's k entries summed."""
-    return ys[inv].reshape(-1, k, ys.shape[1]).sum(axis=1)
+# A chunk of sorted entries meets the tokens' rows through two moves that
+# are each other's reverse mode: a gather of the chunk's rows one way, a
+# product with the chunk's one-hot (rows x tokens, built by comparison)
+# the other.  The scatter autodiff would write runs serially on the chip.
+def _sum_by_token(rows, tok, live, tokens):
+    """``rows`` (R, c) -> (tokens, c) float32: row t is the sum of the
+    rows s that are live and whose token ``tok[s]`` is t."""
+    onehot = (tok[:, None] == jnp.arange(tokens)) & live
+    exact = jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
+    return jax.lax.dot_general(
+        onehot.astype(rows.dtype), rows, (((0,), (0,)), ((), ())),
+        precision=exact, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _spread(x, order, inv, k):
-    """(T, d) -> (T k, d): sorted slot s gets the row of the token its
-    entry ``order[s]`` belongs to (entry n is token n // k).  The reverse
-    mode is :func:`_collect_rows`, a gather too."""
-    return x[order // k]
+def _take_rows(x, tok, live, tokens):
+    """(tokens, c) -> (R, c): sorted row s gets the row of its token."""
+    return x[tok]
 
 
-_spread.defvjp(lambda x, order, inv, k: (x[order // k], inv),
-               lambda k, inv, g: (_collect_rows(g, inv, k), None, None))
+_take_rows.defvjp(
+    lambda x, tok, live, tokens: (x[tok], (tok, live)),
+    lambda tokens, res, g: (_sum_by_token(g, *res, tokens).astype(g.dtype),
+                            None, None))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _collect(ys, order, inv, k):
-    """:func:`_collect_rows`, with :func:`_spread` as its reverse mode."""
-    return _collect_rows(ys, inv, k)
+def _sum_rows(ys, tok, live, tokens):
+    """(R, c) -> (tokens, c) float32: a token's live rows summed."""
+    return _sum_by_token(ys, tok, live, tokens)
 
 
-_collect.defvjp(lambda ys, order, inv, k: (_collect_rows(ys, inv, k), order),
-                lambda k, order, g: (g[order // k], None, None))
+# (an empty array carries the rows' type to the reverse mode)
+_sum_rows.defvjp(
+    lambda ys, tok, live, tokens: (_sum_by_token(ys, tok, live, tokens),
+                                   (tok, jnp.zeros((0,), ys.dtype))),
+    lambda tokens, res, g: (g.astype(res[1].dtype)[res[0]], None, None))
 
 
 def _grouped_matmul(lhs, rhs, sizes, live):
-    """Row r of ``lhs`` (N, k) times ``rhs[g]`` (G, n, k) transposed,
+    """Row r of ``lhs`` (R, k) times ``rhs[g]`` (G, n, k) transposed,
     g the group whose run of ``sizes[g]`` sorted rows holds r; rows past
-    the last group (``live`` (N, 1) false) are 0.  ``lax.ragged_dot``
+    the last group (``live`` (R, 1) false) are 0.  ``lax.ragged_dot``
     walks the row tiles that are live and costs no product for the rest
     (PERF.md, PR 30: the TPU compiler's own kernels, level with the
     Pallas megablox kernel on the v5e).  Those kernels leave the rows
@@ -338,8 +351,96 @@ def _grouped_matmul(lhs, rhs, sizes, live):
     return jnp.where(live, out, jnp.zeros((), out.dtype))
 
 
+def held_chunk_rows(entries, held, experts):
+    """The rows one trip of :func:`moe_apply_held` walks, from shapes
+    alone: twice the entries expected on the ``held`` of ``experts``
+    experts, up to a multiple of 512, at most all of them.  Twice, so
+    that an ordinary step's surplus finds room in the one trip: at some
+    hundred rows an expert the products are bound by the weights' bytes,
+    and a second trip for a handful of rows reads every weight again."""
+    expected = -(-entries * held // experts)
+    return min(entries, -(-2 * expected // 512) * 512)
+
+
+def row_chunks(live, rows):
+    """The trips :func:`moe_apply_held` makes over ``live`` sorted
+    entries in chunks of ``rows``."""
+    return (live + rows - 1) // rows
+
+
+def _held_chunk(x, weight, w_gate, w_up, w_down, ent, lo, ends):
+    """Sorted rows ``lo .. lo + R``'s part of the result, (T, d)
+    float32; ``ent`` (R,) their entries (entry n is token n // k, choice
+    n % k), ``ends`` (G,) where each held expert's run ends."""
+    rows, (tokens, k) = ent.shape[0], weight.shape
+    # an expert's run may be cut by the chunk's edge
+    sizes = jnp.diff(jnp.clip(ends, lo, lo + rows), prepend=lo)
+    live = (lo + jnp.arange(rows) < ends[-1])[:, None]
+    tok = ent // k
+    # the select on xs is for the way back: what the transposes return
+    # for rows past the groups must not reach the tokens' gradient
+    xs = jnp.where(live, _take_rows(x, tok, live, tokens),
+                   jnp.zeros((), x.dtype))
+    h = jax.nn.silu(_grouped_matmul(xs, w_gate, sizes, live)) \
+        * _grouped_matmul(xs, w_up, sizes, live)
+    ys = _grouped_matmul(h.astype(x.dtype), w_down, sizes, live)
+    chosen = (ent % k)[:, None] == jnp.arange(k)
+    ws = jnp.sum(jnp.where(chosen, _take_rows(weight, tok, live, tokens), 0.0),
+                 axis=1, keepdims=True)
+    return _sum_rows(ys * ws.astype(ys.dtype), tok, live, tokens)
+
+
+def _chunk_of(order, ends, c, rows):
+    """Chunk ``c``'s :func:`_held_chunk` as a function of the five
+    leaves."""
+    ent = jax.lax.dynamic_slice(order, (c * rows,), (rows,))
+    return lambda *leaves: _held_chunk(*leaves, ent, c * rows, ends)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_part(rows, x, weight, w_gate, w_up, w_down, order, ends):
+    """The sum of :func:`_held_chunk` over the chunks that hold a live
+    row.  Chunk 0 is outside the loop, so that the one chunk of a step
+    at the expected load meets no accumulator (with no entry held it
+    computes exact zeros); the trips after it, as many as the routing
+    fills, add their chunks to its result."""
+    leaves = (x, weight, w_gate, w_up, w_down)
+    y = jax.lax.fori_loop(
+        1, row_chunks(ends[-1], rows),
+        lambda c, y: y + _chunk_of(order, ends, c, rows)(*leaves),
+        _chunk_of(order, ends, 0, rows)(*leaves))
+    return y.astype(x.dtype)
+
+
+def _held_part_bwd(rows, saved, g):
+    """A second walk of the same chunks, each computing its forward
+    again (chunk 0's is the forward pass's own, which the compiler
+    keeps: R rows) and adding its five gradients: the sum of a trip in
+    float32, kept in the leaf's type (an accumulator in float32 is a
+    fill, an add and a conversion of every expert weight, 1.3 ms a layer
+    at GLM's shapes: PERF.md, PR 36).  Autodiff cannot reverse a loop
+    whose trip count is traced, and would keep every chunk's rows if it
+    could."""
+    *leaves, order, ends = saved
+    g = g.astype(jnp.float32)
+
+    def grads(c):
+        return jax.vjp(_chunk_of(order, ends, c, rows), *leaves)[1](g)
+
+    def trip(c, sums):
+        return tuple((s.astype(jnp.float32) + d.astype(jnp.float32))
+                     .astype(s.dtype) for s, d in zip(sums, grads(c)))
+
+    return jax.lax.fori_loop(1, row_chunks(ends[-1], rows), trip,
+                             grads(0)) + (None, None)
+
+
+_held_part.defvjp(lambda rows, *args: (_held_part(rows, *args), args),
+                  _held_part_bwd)
+
+
 def moe_apply_held(x, expert, weight, w_gate, w_up, w_down, first_expert,
-                   num_experts):
+                   num_experts, chunk_rows=None):
     """The routed part of an expert layer that a chip holding experts
     ``first_expert .. first_expert + G`` of ``num_experts`` computes:
 
@@ -350,28 +451,26 @@ def moe_apply_held(x, expert, weight, w_gate, w_up, w_down, first_expert,
     :func:`sigmoid_topk_route`; ``w_gate``/``w_up`` (G, h, d), ``w_down``
     (G, d, h).  Returns ``(y (T, d), count (num_experts,) float32)``,
     the count of entries routed to each of all the experts.  Nothing is
-    dropped: the T*k entries are sorted by expert, those of absent
-    experts last, into a buffer of T*k rows, and the grouped products
-    run over the held experts' rows alone.
+    dropped and nothing of T*k rows is built but the int32 order: the
+    T*k entries are sorted by expert, those of absent experts last, and
+    walked in chunks of ``chunk_rows`` (:func:`held_chunk_rows` of the
+    shapes unless given) for as many trips as the held experts' entries
+    fill, T*k / ``chunk_rows`` when every entry is theirs; a trip
+    gathers its rows, runs the three grouped products over them and adds
+    each token's rows into ``y`` in float32.
     """
-    k = expert.shape[1]
-    N, G = x.shape[0] * k, w_gate.shape[0]
+    tokens, k = expert.shape
+    N, G = tokens * k, w_gate.shape[0]
+    rows = chunk_rows or held_chunk_rows(N, G, num_experts)
     ef = expert.reshape(N)
     local = ef - first_expert
     key = jnp.where((local >= 0) & (local < G), local, G)
-    order = jnp.argsort(key, stable=True)            # sorted slot -> entry
-    inv = jnp.argsort(order)                         # entry -> sorted slot
-    sizes = jnp.sum(key[:, None] == jnp.arange(G), axis=0, dtype=jnp.int32)
-    live = (jnp.arange(N) < jnp.sum(sizes))[:, None]
-
-    # the select on xs is for the way back: what the transposes return
-    # for rows past the groups must not reach the tokens' gradient
-    xs = jnp.where(live, _spread(x, order, inv, k), jnp.zeros((), x.dtype))
-    h = jax.nn.silu(_grouped_matmul(xs, w_gate, sizes, live)) \
-        * _grouped_matmul(xs, w_up, sizes, live)
-    ys = _grouped_matmul(h.astype(x.dtype), w_down, sizes, live)
-    ws = _spread(weight.reshape(N, 1), order, inv, 1).astype(ys.dtype)
-    y = _collect(ys * ws, order, inv, k)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    ends = jnp.cumsum(jnp.sum(key[:, None] == jnp.arange(G), axis=0,
+                              dtype=jnp.int32))
+    # whole chunks: a slice that starts past the end would be moved back
+    order = jnp.pad(order, (0, -N % rows))
+    y = _held_part(rows, x, weight, w_gate, w_up, w_down, order, ends)
     count = jnp.sum(ef[:, None] == jnp.arange(num_experts), axis=0,
                     dtype=jnp.float32)
-    return y.astype(x.dtype), count
+    return y, count

@@ -36,6 +36,7 @@ from .. import obs as _obs
 from .. import program as _program
 from .. import tuneplan as _tuneplan
 from .mesh import batch_sharding, replicated
+from .moe import held_chunk_rows, row_chunks
 from .optim import make_update_fn
 
 from .collectives import _process_index
@@ -521,10 +522,7 @@ class Trainer:
         statistics and nothing reads them on its path)."""
         layers = [n for n in self.prog.nodes
                   if not n.is_variable and n.op.name == "MoEExperts"]
-        self._moe_layers = [
-            (n.name + "_count", n.params["first_expert"],
-             n.params["first_expert"] + n.params["experts_held"])
-            for n in layers]
+        self._moe_layers = []
         if not layers:
             return
         inner = self.symbol.get_internals()
@@ -533,10 +531,15 @@ class Trainer:
         entries = 0
         for n in layers:
             src, i = n.inputs[1]              # the router's chosen experts
-            entries += int(np.prod(shape_of[
+            mine = int(np.prod(shape_of[
                 "%s_%s" % (src.name, src.op.list_outputs(src.params)[i])]))
+            entries += mine
+            lo, held = n.params["first_expert"], n.params["experts_held"]
+            self._moe_layers.append(
+                (n.name + "_count", lo, lo + held,
+                 held_chunk_rows(mine, held, n.params["num_experts"])))
         _obs.gauge("moe.experts_held").set(
-            max(hi - lo for _, lo, hi in self._moe_layers))
+            max(hi - lo for _, lo, hi, _ in self._moe_layers))
         _obs.gauge("moe.entries_per_step").set(entries)
         _obs.REGISTRY.pull(self._pull_moe_gauges)
 
@@ -544,15 +547,24 @@ class Trainer:
         """``moe.held_entries_share``: of the last step's routing
         entries, the share sent to experts this chip holds, over all
         expert layers.  ``moe.load_max_over_mean``: the fullest held
-        expert's entries over the mean held expert's."""
+        expert's entries over the mean held expert's.
+        ``moe.row_chunks_per_layer``: the mean over the expert layers of
+        the chunks of sorted rows that held a live entry, which is the
+        trips ``moe_apply_held`` made after its first chunk, plus one: 1
+        at the expected load, entries over the chunk's rows when every
+        entry goes to a held expert."""
         if self.aux is None:
             return
-        counts = jax.device_get([self.aux[name]
-                                 for name, _, _ in self._moe_layers])
-        held = np.concatenate([np.asarray(c)[lo:hi] for c, (_, lo, hi)
-                               in zip(counts, self._moe_layers)])
+        counts = jax.device_get([self.aux[layer[0]]
+                                 for layer in self._moe_layers])
+        held = [np.asarray(c)[lo:hi] for c, (_, lo, hi, _)
+                in zip(counts, self._moe_layers)]
         total = float(sum(np.sum(c) for c in counts))
-        if total and held.sum():
+        if total and sum(h.sum() for h in held):
+            _obs.gauge("moe.row_chunks_per_layer").set(float(np.mean(
+                [row_chunks(int(h.sum()), rows) for h, (_, _, _, rows)
+                 in zip(held, self._moe_layers)])))
+            held = np.concatenate(held)
             _obs.gauge("moe.held_entries_share").set(
                 float(held.sum()) / total)
             _obs.gauge("moe.load_max_over_mean").set(

@@ -160,24 +160,40 @@ def test_transformer_step_feeds_the_kernels_with_no_layout_pass(v5e):
     assert _layout_passes(text, (8, 1024, 16, 64), scope="_attn_attn") == []
 
 
-def test_expert_layer_compiles_to_the_chips_grouped_kernels(v5e):
-    """``MoEExperts``' mathematics at glm-4.7-flash's widths (4,096 rows
-    x 4 entries, 8 of 64 experts held): forward and gradient compile for
-    the v5e, and the three grouped products are the compiler's own
-    ragged-dot kernels, which walk the live row tiles, not a dense
-    product an expert over the whole worst-case buffer."""
+# (tokens, entries a token, experts, held, d, h) of the two expert cells
+EXPERT_SHAPES = [
+    pytest.param(4096, 4, 64, 8, 2048, 1536, id="glm-4.7-flash"),
+    pytest.param(4096, 8, 512, 8, 2560, 768, id="ling-3.0-flash"),
+]
+
+
+@pytest.mark.parametrize("t,k,experts,held,d,h", EXPERT_SHAPES)
+def test_expert_layer_compiles_to_the_chips_grouped_kernels(
+        v5e, t, k, experts, held, d, h):
+    """``MoEExperts``' mathematics at the cells' widths (4,096 rows x 4
+    entries, 8 of 64 experts held; x 8 entries, 8 of 512): value and
+    gradient compile for the v5e, and the three grouped products are the
+    compiler's own ragged-dot kernels, which walk the live row tiles,
+    not a dense product an expert.  The sorted entries are walked in
+    chunks by one loop a pass, and no buffer of all of their rows by d
+    columns exists."""
     from mxnet_tpu.parallel import moe
 
     def loss(x, idx, w, wg, wu, wd):
-        y, count = moe.moe_apply_held(x, idx, w, wg, wu, wd, 0, 64)
-        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(count)
+        y, count = moe.moe_apply_held(x, idx, w, wg, wu, wd, 0, experts)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + jnp.sum(count)
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 2, 3, 4, 5))).lower(
-        spec((4096, 2048)), spec((4096, 4), jnp.int32),
-        spec((4096, 4), jnp.float32), spec((8, 1536, 2048)),
-        spec((8, 1536, 2048)), spec((8, 2048, 1536))).compile().as_text()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 2, 3, 4, 5))).lower(
+        spec((t, d)), spec((t, k), jnp.int32), spec((t, k), jnp.float32),
+        spec((held, h, d)), spec((held, h, d)), spec((held, d, h))
+    ).compile().as_text()
     assert text.count("ragged-dot-metadata") >= 3
     assert "tpu_custom_call" in text
+    assert len(re.findall(r" while\(", text)) == 2
+    assert "[%d,%d]" % (t * k, d) not in text \
+        and "[%d,%d]" % (t * k, h) not in text
+    rows = moe.held_chunk_rows(t * k, held, experts)
+    assert "bf16[%d,%d]" % (rows, d) in text

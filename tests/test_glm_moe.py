@@ -360,6 +360,90 @@ def test_rows_the_grouped_kernels_leave_unwritten_never_reach_a_product(
         close(a, b, jnp.float32)
 
 
+ABSENT = [0, 1, 9, 15]
+# 64 tokens x 4 entries = 256 sorted rows, experts 4..7 of 16 held:
+# (the experts of every token | "random", of how many tokens, chunk rows,
+# trips)
+WALKS = {
+    "run_cut_by_an_edge": ("random", 64, 24, None),
+    "all_on_one_held": ([6, 6, 6, 6], 64, 32, 8),
+    "rows_do_not_divide_entries": ([5, 5, 5, 5], 64, 24, 11),
+    "none_held": (ABSENT, 64, 32, 0),
+    "live_fills_two_chunks": ([4, 5, 6, 7], 16, 32, 2),
+    "live_fills_one_chunk": ([4, 5, 6, 7], 16, 64, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_walk_in_row_chunks_is_the_masked_dense_loop(case, ref):
+    """``moe_apply_held`` with a small ``chunk_rows``: whatever the trips
+    the routing asks for, value and the five gradients are the masked
+    dense loop's; with no entry held, exact zeros."""
+    from mxnet_tpu.parallel import moe
+    t, k = 64, 4
+    experts, first_tokens, rows, trips = WALKS[case]
+    if experts == "random":               # some 64 live rows, 24 a trip
+        idx = jax.random.randint(jax.random.key(37), (t, k), 0, 16,
+                                 jnp.int32)
+    else:
+        idx = jnp.tile(jnp.array(ABSENT, jnp.int32), (t, 1)) \
+            .at[:first_tokens].set(jnp.array(experts, jnp.int32))
+    live = int(jnp.sum((idx >= 4) & (idx < 8)))
+    if trips is None:
+        assert live % rows and live > 2 * rows
+    else:
+        assert moe.row_chunks(live, rows) == trips
+    x, seed = rnd(38, t, 32), rnd(39, t, 32)
+    wg, wu, wd = expert_weights(jnp.float32)
+    wt = 0.1 + jax.random.uniform(jax.random.key(40), (t, k))
+
+    def op(x, wt, wg, wu, wd):
+        return moe.moe_apply_held(x, idx, wt, wg, wu, wd, 4, 16,
+                                  chunk_rows=rows)[0]
+
+    def dense(x, wt, wg, wu, wd):
+        return ref.routed_part(x, idx, wt, wg, wu, wd, 4)
+
+    got, want = (jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a) * seed), argnums=(0, 1, 2, 3, 4)))(
+            x, wt, wg, wu, wd) for fn in (op, dense))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if case == "none_held":
+            assert not np.asarray(a).any() and not np.asarray(b).any()
+        close(a, b, jnp.float32)
+
+
+def test_walk_builds_nothing_of_all_entries_rows_and_scatters_nothing():
+    """Value and gradient of the op, 64 x 4 entries walked 32 a trip:
+    no array of 256 rows by d = 32 or h = 24 columns, no scatter.  The
+    gather that autodiff would reverse has both, so the search sees."""
+    from mxnet_tpu.analysis.jaxpr_passes import iter_eqns
+    from mxnet_tpu.parallel import moe
+    x, wt = rnd(41, 64, 32), rnd(42, 64, 4)
+    idx = jax.random.randint(jax.random.key(43), (64, 4), 0, 16, jnp.int32)
+    wg, wu, wd = expert_weights(jnp.float32)
+
+    def held(x, wt, wg, wu, wd):
+        return jnp.sum(moe.moe_apply_held(x, idx, wt, wg, wu, wd, 4, 16,
+                                          chunk_rows=32)[0] ** 2)
+
+    def spread(x, wt, wg, wu, wd):
+        return jnp.sum(x[jnp.argsort(idx.reshape(-1)) // 4] ** 2)
+
+    def found(fn):
+        eqns = list(iter_eqns(jax.make_jaxpr(jax.value_and_grad(
+            fn, argnums=(0, 1, 2, 3, 4)))(x, wt, wg, wu, wd)))
+        shapes = {tuple(v.aval.shape) for e in eqns for v in e.outvars}
+        names = {e.primitive.name for e in eqns}
+        return ({s for s in shapes if len(s) == 2 and s[0] == 256
+                 and s[1] in (32, 24)},
+                {n for n in names if "scatter" in n},
+                {"while", "ragged_dot_general"} <= names)
+
+    assert found(spread) == ({(256, 32)}, {"scatter-add"}, False)
+    assert found(held) == (set(), set(), True)
+
+
 def test_all_absent_leaves_the_shared_experts_part(ref):
     """The whole layer when the router sends nothing here: the shared
     expert's output alone, in the program and in the reference."""
@@ -619,6 +703,22 @@ def test_obs_gauges_after_two_steps_equal_counts_made_by_hand(tiny):
         held.sum() / (2 * B * T * 4))
     assert gauges["moe.load_max_over_mean"] == pytest.approx(
         held.max() / held.mean())
+    # the chunks of sorted rows that held a live entry, a layer: the
+    # chunk is twice the expected entries up to a multiple of 512, 1,024
+    # rows in ling3flash_train and 4,096 in glm47flash_train, and all
+    # 512 entries here; then with chunks of 16 rows in its place
+    from mxnet_tpu.parallel.moe import held_chunk_rows
+    assert held_chunk_rows(4096 * 8, 8, 512) == 1024
+    assert held_chunk_rows(4096 * 4, 8, 64) == 4096
+    assert held_chunk_rows(B * T * 4, 4, 16) == B * T * 4 == 512
+    layers = mod._trainer._moe_layers
+    assert [rows for _, _, _, rows in layers] == [512, 512]
+    assert gauges["moe.row_chunks_per_layer"] == 1.0
+    mod._trainer._moe_layers = [layer[:3] + (16,) for layer in layers]
+    by_hand = np.mean([np.ceil(c[:4].sum() / 16) for c in counts])
+    assert by_hand > 4
+    assert obs.snapshot()["gauges"]["moe.row_chunks_per_layer"] \
+        == pytest.approx(by_hand)
     # a trainer that goes away leaves its last reading
     del mod
     import gc
