@@ -431,11 +431,25 @@ inline std::vector<NDArray> _contrib_DotProductAttention(
   return Invoke("_contrib_DotProductAttention", inputs, kw);
 }
 
+inline std::vector<NDArray> _contrib_ExitDistribution(
+    const std::vector<NDArray> &inputs,
+    const KWArgs &extra = {}) {
+  KWArgs kw(extra);
+  return Invoke("_contrib_ExitDistribution", inputs, kw);
+}
+
 inline std::vector<NDArray> _contrib_GatedDeltaRule(
     const std::vector<NDArray> &inputs,
     const KWArgs &extra = {}) {
   KWArgs kw(extra);
   return Invoke("_contrib_GatedDeltaRule", inputs, kw);
+}
+
+inline std::vector<NDArray> _contrib_RowCrossEntropy(
+    const std::vector<NDArray> &inputs,
+    const KWArgs &extra = {}) {
+  KWArgs kw(extra);
+  return Invoke("_contrib_RowCrossEntropy", inputs, kw);
 }
 
 inline std::vector<NDArray> _contrib_ShortConv(

@@ -10,6 +10,11 @@ run once), jits it, and:
     kept for backward (no recompute; the linearize/transpose caches make the
     per-step Python overhead bounded).
   * ``backward(out_grads)`` calls the pullback — one more compiled program.
+  * the reference's mirror option (``MXNET_BACKWARD_DO_MIRROR``) is the
+    ``remat_segment`` node attribute: a run of consecutive nodes that
+    carry one value is evaluated as one function whose intermediates the
+    backward pass computes again instead of keeping
+    (``_GraphProgram._eval_segment``).
   * memory planning (``PlanMemory``), in-place detection
     (``DetectInplaceAddTo``) and op fusion (bulk segments) are all XLA's
     job; none of the reference's passes exist here because the compiler
@@ -20,19 +25,50 @@ per node — the functional replacement of ``ResourceRequest::kRandom``.
 """
 from __future__ import annotations
 
+import collections
+import itertools
 from typing import Dict, List, Optional
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .base import MXNetError, _dtype, current_context
 from .ndarray import NDArray, zeros
 from .op.registry import OpContext
 from .symbol import Symbol, _topo
 
-__all__ = ["Executor", "bind", "simple_bind"]
+__all__ = ["Executor", "bind", "simple_bind", "SEGMENT_ATTR"]
+
+# The node attribute that names a recomputation segment
+# (``with mx.AttrScope(remat_segment="u1"): ...``): this framework's form
+# of the reference's ``force_mirroring`` attribute and
+# ``MXNET_BACKWARD_DO_MIRROR`` (``src/executor/graph_executor.cc:210``).
+# There the planner picks single nodes whose outputs it recomputes; here
+# the Symbol's author names a whole block, whose every intermediate is
+# dropped after the forward pass and computed again, once, when the
+# backward pass reaches the block.
+SEGMENT_ATTR = "remat_segment"
+# what goes before a node's scope in the backward pass of its segment:
+# AGAIN where its forward operations run a second time, BACK on their
+# reverse modes, so that a device trace tells the two apart
+AGAIN, BACK = "remat.", "remat_bwd."
+
+
+# A run of consecutive compute nodes that carry one ``remat_segment``
+# value, the entries they read from outside, the entries the rest
+# reads, their auxiliary states' slots, and whether any draws from a key.
+_Segment = collections.namedtuple(
+    "_Segment", "name nodes reads gives aux_slots uses_rng")
+
+
+def _zero_ct(value):
+    """The cotangent of a value that nothing differentiable reads."""
+    if jnp.issubdtype(value.dtype, jnp.inexact):
+        return jnp.zeros_like(value)
+    return np.zeros(np.shape(value), jax.dtypes.float0)
 
 
 def _jax_device_for(ctx):
@@ -79,10 +115,59 @@ class _GraphProgram:
         # with jax.device_put; XLA inserts the cross-device transfers.
         self.placement = {}
         self._jitted = {}
+        self._plan = self._plan_segments()
+
+    # ------------------------------------------------------------------
+    def _plan_segments(self):
+        """None for a Symbol with no marked node (the walk is then the
+        plain one), else ``(steps, rng_index)``: the compute nodes in
+        the walk's own order, every run of consecutive ones that carry
+        one ``remat_segment`` value gathered into a :class:`_Segment`,
+        and for every node the number the plain walk folds into its
+        key."""
+        compute = [n for n in self.nodes if not n.is_variable]
+        if not any(n.attrs.get(SEGMENT_ATTR) for n in compute):
+            return None
+        rng_index, count = {}, 0
+        for n in self.nodes:
+            rng_index[id(n)] = count
+            count += 1 if n.is_variable else n.op.n_outputs(n.params)
+        readers = {}                     # entry -> the nodes that read it
+        for n in compute:
+            for c, i in n.inputs:
+                readers.setdefault((id(c), i), set()).add(id(n))
+        outputs = {(id(nd), i) for nd, i in self.output_entries}
+        steps = []
+        for name, run in itertools.groupby(
+                compute, lambda n: n.attrs.get(SEGMENT_ATTR) or None):
+            ns = list(run)
+            if name is None:
+                steps.extend(ns)
+                continue
+            for n in ns:
+                if n.op.host_callback:
+                    raise MXNetError(
+                        "node %r (op %s) calls back into Python and cannot "
+                        "be in recomputation segment %r: it would be called "
+                        "twice a step" % (n.name, n.op.name, name))
+            inside = {id(n) for n in ns}
+            steps.append(_Segment(
+                name=name, nodes=ns,
+                reads=list(dict.fromkeys(
+                    (id(c), i) for n in ns for c, i in n.inputs
+                    if id(c) not in inside)),
+                gives=[(id(n), i) for n in ns
+                       for i in range(n.op.n_outputs(n.params))
+                       if (id(n), i) in outputs
+                       or readers.get((id(n), i), set()) - inside],
+                aux_slots=[self._aux_index["%s_%s" % (n.name, a)]
+                           for n in ns for a in n.aux_names()],
+                uses_rng=any(n.op.uses_rng for n in ns)))
+        return steps, rng_index
 
     # ------------------------------------------------------------------
     def _eval_node(self, n, env, aux_vals, aux_out, rng_key, is_train,
-                   monitor=None):
+                   monitor=None, rng_index=None, scope=None):
         """Run one compute node against ``env`` (in-place)."""
         in_vals = [env[(id(c), i)] for c, i in n.inputs]
         aux_names = n.aux_names()
@@ -93,7 +178,8 @@ class _GraphProgram:
             node_aux = [jax.lax.stop_gradient(v) for v in node_aux]
         rng = None
         if n.op.uses_rng:
-            rng = jax.random.fold_in(rng_key, len(env))
+            rng = jax.random.fold_in(
+                rng_key, len(env) if rng_index is None else rng_index)
         ctx = OpContext(is_train=is_train, rng=rng,
                         platform=self.platform,
                         dtype_policy=self.dtype_policy)
@@ -101,7 +187,7 @@ class _GraphProgram:
         # (op_name="jit(..)/<node>/..") of every primitive this node
         # traces — benchmark/lib/tracered.py joins a traced device op
         # back to its symbol-level layer through it
-        with jax.named_scope(n.name):
+        with jax.named_scope(scope or n.name):
             outs, aux_updates = n.op.apply(n.params, ctx,
                                            *(in_vals + node_aux))
         dev = self.placement.get(n.name)
@@ -115,7 +201,100 @@ class _GraphProgram:
         for s, v in zip(aux_slots, aux_updates):
             aux_out[s] = v
 
+    def _eval_segment(self, seg, env, aux_vals, aux_out, rng_key,
+                      rng_index):
+        """Run a segment as one function of the entries it reads from
+        outside, which are all the backward pass keeps of it: what lies
+        between is computed again when the cotangents of ``seg.gives``
+        arrive, behind a barrier that keeps the compiler from merging
+        the second computation with the first.  This is what
+        ``jax.checkpoint`` does, written out node by node: a
+        ``checkpoint`` body is lowered under the one name ``checkpoint``,
+        before its nodes' own scopes, and a device trace could no longer
+        tell a segment's nodes apart (tests/test_executor.py holds both
+        forms against each other).  Here a node keeps its scope, with
+        ``remat.`` before it where its forward operations run again and
+        ``remat_bwd.`` on their reverse modes.  Auxiliary states and
+        keys are inputs, so a BatchNorm or Dropout node inside computes
+        the same statistics, the same mask and the same new state as
+        outside, both times."""
+        n_in, n_aux = len(seg.reads), len(seg.aux_slots)
+        impl = jax.random.key_impl(rng_key) if seg.uses_rng else None
+
+        def unpack(flat):
+            key = jax.random.wrap_key_data(flat[-1], impl=impl) \
+                if seg.uses_rng else None
+            return (dict(zip(seg.reads, flat[:n_in])),
+                    dict(zip(seg.aux_slots, flat[n_in:n_in + n_aux])), key)
+
+        def body(*flat):
+            local, aux_in, key = unpack(flat)
+            new_aux = {}
+            for n in seg.nodes:
+                self._eval_node(n, local, aux_in, new_aux, key, True,
+                                rng_index=rng_index[id(n)])
+            return tuple(local[e] for e in seg.gives) \
+                + tuple(new_aux[s] for s in seg.aux_slots)
+
+        def body_bwd(flat, cts):
+            local, aux_in, key = unpack(lax.optimization_barrier(flat))
+            pulls = []
+            for n in seg.nodes:
+                ins = [(id(c), i) for c, i in n.inputs]
+                outs = [(id(n), i) for i in range(n.op.n_outputs(n.params))]
+
+                def node(*vals, n=n, ins=ins, outs=outs):
+                    at = dict(zip(ins, vals))
+                    self._eval_node(n, at, aux_in, {}, key, True,
+                                    rng_index=rng_index[id(n)],
+                                    scope=AGAIN + n.name)
+                    return tuple(at[e] for e in outs)
+
+                vals, pull = jax.vjp(node, *[local[e] for e in ins])
+                local.update(zip(outs, vals))
+                pulls.append((BACK + n.name, ins, outs, pull))
+            ct = dict(zip(seg.gives, cts))
+            for scope, ins, outs, pull in reversed(pulls):
+                with jax.named_scope(scope):
+                    got = pull(tuple(ct.pop(e) if e in ct
+                                     else _zero_ct(local[e]) for e in outs))
+                for e, g in zip(ins, got):
+                    if g.dtype != jax.dtypes.float0:
+                        ct[e] = ct[e] + g if e in ct else g
+            return tuple(ct[e] if e in ct else _zero_ct(v)
+                         for e, v in zip(seg.reads, flat)) \
+                + tuple(_zero_ct(v) for v in flat[n_in:])
+
+        run = jax.custom_vjp(body)
+        run.defvjp(lambda *flat: (body(*flat), flat), body_bwd)
+        flat = [env[e] for e in seg.reads] \
+            + [aux_vals[s] for s in seg.aux_slots]
+        if seg.uses_rng:
+            flat.append(jax.random.key_data(rng_key))
+        out = run(*flat)
+        env.update(zip(seg.gives, out))
+        for s, v in zip(seg.aux_slots, out[len(seg.gives):]):
+            aux_out[s] = v
+
+    def _eval_planned(self, arg_vals, aux_vals, rng_key):
+        """The walk of a Symbol with marked nodes, in training."""
+        steps, rng_index = self._plan
+        env = {(id(n), 0): arg_vals[self._arg_index[n.name]]
+               for n in self.nodes if n.is_variable}
+        aux_out = list(aux_vals)
+        for step in steps:
+            if isinstance(step, _Segment):
+                self._eval_segment(step, env, aux_vals, aux_out, rng_key,
+                                   rng_index)
+            else:
+                self._eval_node(step, env, aux_vals, aux_out, rng_key,
+                                True, rng_index=rng_index[id(step)])
+        outputs = tuple(env[(id(nd), i)] for nd, i in self.output_entries)
+        return outputs, tuple(aux_out)
+
     def _eval(self, arg_vals, aux_vals, rng_key, is_train, monitor=None):
+        if self._plan is not None and is_train and monitor is None:
+            return self._eval_planned(arg_vals, aux_vals, rng_key)
         env = {}
         aux_out = list(aux_vals)
         for n in self.nodes:

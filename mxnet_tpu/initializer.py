@@ -72,6 +72,7 @@ class Initializer(object):
         ("moving_var", "_init_one"),
         ("moving_avg", "_init_zero"),
         ("_count", "_init_zero"),       # MoEExperts' entries an expert
+        ("_pass_share", "_init_zero"),  # ExitDistribution's mean over rows
     )
 
     def _legacy_init(self, name, arr):

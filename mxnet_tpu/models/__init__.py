@@ -6,7 +6,9 @@ inception-bn, inception-v3, inception-resnet-v2) plus the bucketing LSTM
 language model (``example/rnn/lstm_bucketing.py``), a transformer, a
 latent-attention mixture-of-experts language model (``glm-moe``) and a
 hybrid whose blocks mix by a delta rule with a state along the sequence
-or by latent attention (``bailing-hybrid``).
+or by latent attention (``bailing-hybrid``), and a looped language model
+whose one stack of layers runs several times on shared weights, with an
+exit gate after every pass (``loop-lm``).
 Architectures are standard published networks, written fresh in
 mxnet_tpu Symbol idiom; the graphs compile to single XLA computations.
 
@@ -28,11 +30,12 @@ from . import resnext
 from . import transformer
 from . import glm_moe
 from . import bailing_hybrid
+from . import loop_lm
 
 __all__ = ["get_symbol", "mlp", "lenet", "alexnet", "vgg", "resnet",
            "resnext", "googlenet", "inception_bn", "inception_v3",
            "inception_resnet_v2", "lstm_lm", "transformer", "glm_moe",
-           "bailing_hybrid"]
+           "bailing_hybrid", "loop_lm"]
 
 _BUILDERS = {
     "mlp": mlp.get_symbol,
@@ -46,6 +49,7 @@ _BUILDERS = {
     "gpt": transformer.get_symbol,
     "glm-moe": glm_moe.get_symbol,
     "bailing-hybrid": bailing_hybrid.get_symbol,
+    "loop-lm": loop_lm.get_symbol,
 }
 
 

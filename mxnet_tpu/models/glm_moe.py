@@ -15,7 +15,8 @@ all heads; expanded, it is ordinary causal attention at head dimension
 (``v_head_dim`` may differ from it: the attention op pads the narrower
 operands with zero columns and cuts the result, see ``op/attention.py``).
 ``_linear``, ``_gated_ffn``, ``_expert_layer`` and ``_block`` (which
-takes its mixer) also build ``models/bailing_hybrid.py``.
+takes its mixer) also build ``models/bailing_hybrid.py``, the first two
+``models/loop_lm.py``.
 
 The multi-token-prediction module (arXiv:2412.19437 sec. 2.2, depth 1)
 joins the trunk's last hidden state at position i with the embedding of
@@ -38,12 +39,15 @@ def _linear(x, width, name, weight=None):
                               **kw)
 
 
-def _gated_ffn(x, width, hidden, prefix):
-    """(silu(x W_gate) * x W_up) W_down, three plain products."""
-    gate = sym.Activation(_linear(x, width, prefix + "gate"),
+def _gated_ffn(x, width, hidden, prefix, weights=(None, None, None)):
+    """(silu(x W_gate) * x W_up) W_down, three plain products;
+    ``weights`` hands it W_gate, W_up and W_down where another node
+    uses them too."""
+    w_gate, w_up, w_down = weights
+    gate = sym.Activation(_linear(x, width, prefix + "gate", w_gate),
                           act_type="silu", name=prefix + "act")
-    return _linear(gate * _linear(x, width, prefix + "up"), hidden,
-                   prefix + "down")
+    return _linear(gate * _linear(x, width, prefix + "up", w_up), hidden,
+                   prefix + "down", w_down)
 
 
 def _attention(x, cfg):

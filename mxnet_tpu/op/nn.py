@@ -845,6 +845,106 @@ def _softmax_cross_entropy(p, c, data, label):
     return -jnp.sum(picked).reshape((1,))
 
 
+@jax.custom_vjp
+def _row_cross_entropy(data, label):
+    return _row_cross_entropy_fwd(data, label)[0]
+
+
+def _row_cross_entropy_fwd(data, label):
+    idx = label.astype(jnp.int32).reshape((-1, 1))
+    x = data.astype(jnp.float32)
+    top = lax.stop_gradient(jnp.max(x, axis=-1, keepdims=True))
+    lse = top[:, 0] + jnp.log(jnp.sum(jnp.exp(x - top), axis=-1))
+    picked = jnp.take_along_axis(data, idx, axis=-1)[:, 0]
+    # the logits as they came and one float32 a row: no float32 copy of
+    # the logits and no softmax lives from here to the backward pass
+    return lse - picked.astype(jnp.float32), (data, label, lse)
+
+
+def _row_cross_entropy_bwd(res, g):
+    data, label, lse = res
+    idx = label.astype(jnp.int32).reshape((-1, 1))
+    prob = jnp.exp(data.astype(jnp.float32) - lse[:, None])
+    hit = idx == lax.broadcasted_iota(jnp.int32, data.shape, 1)
+    grad = (prob - hit.astype(jnp.float32)) * g.astype(jnp.float32)[:, None]
+    return grad.astype(data.dtype), jnp.zeros_like(label)
+
+
+_row_cross_entropy.defvjp(_row_cross_entropy_fwd, _row_cross_entropy_bwd)
+
+
+def _row_xent_infer_shape(p, in_shapes):
+    dshape = in_shapes[0]
+    if dshape is None or 0 in dshape:
+        return None
+    return [tuple(dshape), (dshape[0],)], [(dshape[0],)], []
+
+
+def _row_xent_infer_dtype(p, in_dtypes):
+    dt = in_dtypes[0] if in_dtypes[0] is not None \
+        else jnp.dtype(jnp.float32)
+    label = in_dtypes[1] if in_dtypes[1] is not None else dt
+    return [dt, label], [jnp.dtype(jnp.float32)], []
+
+
+@register("_contrib_RowCrossEntropy", input_names=("data", "label"),
+          hint="rowcrossentropy", infer_shape=_row_xent_infer_shape,
+          infer_dtype=_row_xent_infer_dtype)
+def _row_cross_entropy_op(p, c, data, label):
+    """data (rows, classes) logits, label (rows,) class ids -> (rows,)
+    float32: -log softmax(data)[label] a row, a differentiable value
+    (``SoftmaxOutput`` injects a gradient at a constant scale and
+    ``softmax_cross_entropy`` sums over the rows; a loss that weighs
+    its rows by something learned needs the rows).  The log-sum-exp is
+    taken in float32 whatever the logits' type.  The reverse mode is
+    its own, in ``op/bytediet.py``'s manner: it keeps the logits as
+    given and the log-sum-exp, and gives back (softmax - onehot) x the
+    row's cotangent in the logits' type, the one-hot as a comparison
+    and not a scatter."""
+    return _row_cross_entropy(data, label)
+
+
+def _exit_infer_shape(p, in_shapes):
+    dshape = in_shapes[0]
+    if dshape is None or 0 in dshape:
+        return None
+    steps = dshape[1] + 1
+    return [tuple(dshape)], [(dshape[0], steps)], [(steps,)]
+
+
+def _exit_infer_dtype(p, in_dtypes):
+    f32 = jnp.dtype(jnp.float32)
+    return [in_dtypes[0] if in_dtypes[0] is not None else f32], [f32], [f32]
+
+
+def _exit_gauges(p, aux):
+    """``loop.expected_steps``: the last step's mean over positions of
+    the pass a position exits after, sum of t x p_t from t = 1: 1 or
+    the number of passes says a gate is stuck."""
+    mean = np.asarray(aux["pass_share"], np.float64)
+    return {"loop.expected_steps":
+            float(np.sum(mean * np.arange(1, mean.size + 1)))}
+
+
+@register("_contrib_ExitDistribution", aux_names=("pass_share",),
+          hint="exitdistribution", infer_shape=_exit_infer_shape,
+          infer_dtype=_exit_infer_dtype, gauges=_exit_gauges)
+def _exit_distribution(p, c, data, pass_share):
+    """data (rows, U - 1): the probability lambda_t of stopping after
+    pass t, given that pass t was reached, for every pass but the last
+    -> (rows, U) float32, the distribution over the pass a row exits
+    after: p_t = lambda_t prod_{j<t} (1 - lambda_j), and what is left,
+    prod_{j<U} (1 - lambda_j), for the last pass (arXiv:2510.25741
+    sec. 3).  Every row sums to 1.  The auxiliary state is the step's
+    mean distribution over the rows, which ``obs.snapshot()`` turns into
+    the gauge ``loop.expected_steps``."""
+    lam = data.astype(jnp.float32)
+    stay = jnp.cumprod(1.0 - lam, axis=1)
+    before = jnp.concatenate([jnp.ones_like(lam[:, :1]), stay[:, :-1]], 1)
+    dist = jnp.concatenate([lam * before, stay[:, -1:]], axis=1)
+    return dist, lax.stop_gradient(jnp.mean(dist, axis=0))
+
+
 @register("IdentityAttachKLSparseReg",
           params_spec=(Param("sparseness_target", float, 0.1),
                        Param("penalty", float, 0.001),

@@ -159,6 +159,11 @@ class Op:
     # (operator.py's CustomOp bridge); the executor awaits such programs
     host_callback: bool = False
     hint: str = ""  # auto-naming hint, defaults to lowercased name
+    # gauges an op's auxiliary state stands for: (params, {aux name:
+    # host array}) -> {gauge name: number}.  Whoever owns the state
+    # (the fused trainer) publishes them on ``obs.snapshot()``, without
+    # knowing the op by name; nodes that name one gauge are averaged
+    gauges: Optional[Callable] = None
     # ops whose outputs must not be differentiated through label-style inputs
     # handle that themselves via jax.custom_vjp / stop_gradient in `fn`.
 

@@ -85,7 +85,8 @@ def _seeds_reach_grads(symbol) -> bool:
 
 # where a graph reads an input as indices, and the ops that hand values on
 # unchanged (shape, slicing, and arithmetic with a scalar)
-_INDEX_SLOTS = {"Embedding": ("data",), "SoftmaxOutput": ("label",)}
+_INDEX_SLOTS = {"Embedding": ("data",), "SoftmaxOutput": ("label",),
+                "_contrib_RowCrossEntropy": ("label",)}
 _VALUE_KEEPING = frozenset((
     "Reshape", "Flatten", "expand_dims", "transpose", "slice_axis", "slice",
     "Concat", "SliceChannel", "BlockGrad", "_mul_scalar", "_plus_scalar",
@@ -495,6 +496,7 @@ class Trainer:
         self._arg_shapes = dict(zip(self.prog.arg_names, arg_shapes))
         self._aux_shapes = dict(zip(self.aux_names, aux_shapes))
         self._bind_moe_gauges(shapes)
+        self._bind_op_gauges()
         self._input_shapes = {n: self._arg_shapes[n]
                               for n in self.data_names + self.label_names}
         if self.grad_accum > 1:
@@ -570,9 +572,34 @@ class Trainer:
             _obs.gauge("moe.load_max_over_mean").set(
                 float(held.max() / held.mean()))
 
+    def _bind_op_gauges(self):
+        """The gauges that ops declare for their auxiliary state
+        (``Op.gauges``), read from the state whenever a snapshot is
+        taken, as the ``moe.*`` ones are: a pull."""
+        self._gauge_nodes = [n for n in self.prog.nodes
+                             if not n.is_variable and n.op.gauges is not None]
+        if self._gauge_nodes:
+            _obs.REGISTRY.pull(self._pull_op_gauges)
+
+    def _pull_op_gauges(self):
+        if self.aux is None:
+            return
+        read = {}
+        for n in self._gauge_nodes:
+            names = n.aux_names()
+            state = jax.device_get([self.aux["%s_%s" % (n.name, a)]
+                                    for a in names])
+            for gauge, value in n.op.gauges(
+                    n.params, dict(zip(names, state))).items():
+                read.setdefault(gauge, []).append(value)
+        for gauge, values in read.items():
+            _obs.gauge(gauge).set(float(np.mean(values)))
+
     def __del__(self):
         # a trainer that goes away leaves its last reading in the gauges
         try:
+            if getattr(self, "_gauge_nodes", None):
+                self._pull_op_gauges()
             if getattr(self, "_moe_layers", None):
                 self._pull_moe_gauges()
         except Exception:          # noqa: BLE001 — never raise from a finalizer

@@ -56,6 +56,8 @@ SHAPES = [
                  id="ragged-f32"),
     pytest.param((1, 4096, 20, 256), jnp.bfloat16, True, True,
                  id="glm-4.7-flash"),
+    pytest.param((1, 4096, 16, 128), jnp.bfloat16, True, True,
+                 id="ouro-2.6b"),
 ]
 
 
@@ -158,6 +160,44 @@ def test_transformer_step_feeds_the_kernels_with_no_layout_pass(v5e):
     assert _kernels(text) == ["flash_attention_fwd"] * 2 \
         + ["flash_attention_bwd"] * 2
     assert _layout_passes(text, (8, 1024, 16, 64), scope="_attn_attn") == []
+
+
+@pytest.mark.parametrize("segments,again", [(True, 4), (False, 0)])
+def test_a_segments_forward_runs_again_on_the_v5e(v5e, segments, again):
+    """The fused step of the looped model (two layers run twice at
+    128-wide heads, 1,024 positions) compiled for the v5e: with a pass
+    a recomputation segment every attention node's forward kernel is
+    in the program twice, once for the forward pass and once behind the
+    barrier of its segment's backward pass (the compiler has not merged
+    the two), under the node's scope and under ``remat.<node>``; with
+    no segment it is there once.  The backward kernel is there once
+    either way."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import models
+    from mxnet_tpu.parallel.trainer import Trainer
+
+    sym = models.get_symbol("loop-lm", vocab_size=2048, seq_len=1024,
+                            hidden_size=512, num_layers=2, num_heads=4,
+                            head_dim=128, intermediate_size=1408,
+                            loop_steps=2, segments=segments)
+    trainer = Trainer(sym, mx.optimizer.SGD(learning_rate=0.02, momentum=0.9),
+                      compute_dtype="bfloat16")
+    trainer.bind(data_shapes={"data": (1, 1024)},
+                 label_shapes={"softmax_label": (1, 1024)})
+    trainer.init_params(mx.init.Normal(0.02))
+    trainer.prog.platform = "tpu"
+    args = trainer.abstract_step_args({"data": np.int32,
+                                       "softmax_label": np.int32})
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.result_type(a),
+                                       sharding=v5e), args)
+    text = trainer._step_fn.lower(*args).compile().as_text()
+    kernels = _kernels(text)
+    assert kernels.count("flash_attention_fwd") == 4 + again
+    assert kernels.count("flash_attention_bwd") == 4
+    marked = set(re.findall(r"remat\.(u\d_l\d_attn_attn)", text))
+    assert len(marked) == again
+    assert _layout_passes(text, (1, 1024, 4, 128), scope="_attn_attn") == []
 
 
 # (tokens, entries a token, experts, held, d, h) of the two expert cells

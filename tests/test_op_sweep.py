@@ -474,6 +474,13 @@ G("_contrib_GatedDeltaRule",
    "beta": R.uniform(0.2, 0.8, (1, 32, 2)).astype("f")},
   {"chunk": 16}, rtol=8e-2, atol=2e-2)
 G("_contrib_ShortConv", {"data": randn(2, 6, 3), "weight": randn(3, 4)})
+# a loss a row, its reverse mode its own; and the exit distribution of
+# three gates, whose rows sum to 1
+G("_contrib_RowCrossEntropy", {"data": randn(3, 5), "label": ints(5, 3)},
+  grad_nodes=["data"])
+G("_contrib_ExitDistribution",
+  {"data": R.uniform(0.2, 0.8, (3, 3)).astype("f")},
+  aux={"pass_share": np.zeros(4, "f")})
 
 # differentiable aliases exercise the alias path end-to-end
 _ALIAS_GRADS = {
