@@ -6,9 +6,11 @@ Pre-norm blocks x + Mixer(RMSNorm(x)), x + FFN(RMSNorm(x)).  A ``kda``
 block's mixer is Kimi delta attention (arXiv:2510.26692): q, k and v
 through a depthwise causal convolution of ``conv_kernel`` taps and SiLU,
 q and k L2-normed a head, a log decay a channel ``lower_bound *
-sigmoid(exp(A_log) * (x W_f + dt_bias))``, a write strength
-``sigmoid(x W_beta)`` a head, the gated delta rule with its state along
-the sequence (``_contrib_GatedDeltaRule``, node ``l<i>_kda_core``), and
+sigmoid(exp(A_log) * (x W_f + dt_bias))`` (the source's
+``kda_safe_gate``: the only gate written here, so every rule node
+declares ``lower_bound``), a write strength ``sigmoid(x W_beta)`` a
+head, the gated delta rule with its state along the sequence
+(``_contrib_GatedDeltaRule``, node ``l<i>_kda_core``), and
 ``(RMSNorm(o) * sigmoid(x W_g)) W_o``.  An ``mla`` block's mixer is
 latent attention with no query bottleneck: keys and values expanded from
 a ``kv_lora_rank`` latent, a rotary part whose key is one vector a
@@ -77,8 +79,12 @@ def _kda(x, cfg):
     beta = sym.Activation(sym.Reshape(_linear(x, h, "kda_beta"),
                                       shape=(-1, t, h), name="kda_beta_seq"),
                           act_type="sigmoid", name="kda_beta_act")
+    # the gate is bounded by construction (``kda_safe_gate``), and the
+    # node says so: the rule then takes its product form
     o = sym._contrib_GatedDeltaRule(q, k, v, g, beta, scale=dk ** -0.5,
-                                    chunk=cfg["chunk"], name="kda_core")
+                                    chunk=cfg["chunk"],
+                                    lower_bound=cfg["kda_lower_bound"],
+                                    name="kda_core")
     o = sym.RMSNorm(o, eps=cfg["eps"], name="kda_o_norm")
     gate = sym.Activation(sym.Reshape(_linear(x, h * dk, "kda_g"),
                                       shape=(-1, t, h, dk),

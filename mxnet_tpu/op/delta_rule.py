@@ -21,12 +21,23 @@ Diag(exp G_C) S0 + (K exp(G_C - G))^T U.  Everything that does not hold
 S0 is computed for all chunks at once; what is left for the scan along
 the sequence is two small products a chunk.
 
-An exponent is never positive: g may fall to -5 a step
-(``kda_lower_bound``), 320 over a chunk, and float32 ends at e^88.
-A and Aq are therefore built from 16-position blocks: a block on the
-diagonal from the differences themselves, a block below it as a product
-of two factors taken against the running sum at the rows' block start,
-exp(G_s - G_b) and exp(G_b - G_r), both at most 1.
+g may fall to -5 a step (``kda_lower_bound``), 320 over a chunk, and
+float32 ends at e^88, so A and Aq are built from 16-position blocks, in
+one of two forms.  With no gate bound declared, a block on the diagonal
+is computed from the differences themselves, elementwise, a [16, 16, K]
+tile a block whose exponents are never positive, and a block below it
+as a product of two factors taken against the running sum at the rows'
+block start, exp(G_s - G_b) and exp(G_b - G_r), both at most 1.  Where
+the node declares ``lower_bound``, every g at least that, and the bound
+is -9 or above, both factors are taken against the running sum at the
+middle of the rows' block, exp(G_s - G_m) and exp(G_m - G_r): inside
+the block each lies within e^(8 x 9) = e^72 of 1, columns before it
+get a factor of at most 1, so one product computes the diagonal blocks
+with those below and a lower triangular select cuts the rest away.  At
+e^72 the low bfloat16 part that a ``HIGHEST`` product splits off a
+factor, 2^-16 of it, is still a normal float32 (from e^-87.3); below -9
+it would be flushed, and at the block's start, against which a factor
+could fall to e^-16|g|, already at -5.
 
 The arithmetic is float32 at the highest product precision whatever the
 operands' type (the products are a few GFLOP a layer; the time is in the
@@ -49,9 +60,13 @@ from .registry import Param, register
 
 _BLOCK = 16          # positions whose decays may be divided by one another
 _HI = lax.Precision.HIGHEST
+# the lowest gate bound under which the diagonal blocks are the product
+_PRODUCT_BOUND = -9.0
 
-# nodes traced, and the chunk steps their scans take (batch x t / chunk)
+# nodes traced, those of them on the product form, and the chunk steps
+# their scans take (batch x t / chunk)
 _NODES = _obs.counter("attention.kda.nodes")
+_BOUNDED = _obs.counter("attention.kda.bounded_nodes")
 _CHUNKS = _obs.counter("attention.kda.chunks")
 
 
@@ -59,14 +74,31 @@ def _mm(spec, a, b):
     return jnp.einsum(spec, a, b, precision=_HI)
 
 
-def _decayed_products(rows, k, G):
+def _bounded(lower_bound):
+    """Whether a declared gate bound lets the diagonal blocks be the
+    product (the module's text)."""
+    return lower_bound is not None and lower_bound >= _PRODUCT_BOUND
+
+
+def _decayed_products(rows, k, G, lower_bound=None):
     """[..., C, C]: sum_c rows_s[c] k_r[c] exp(G_s[c] - G_r[c]) for
     r <= s and 0 above the diagonal; ``rows`` is q or k, all [..., C, K].
-    No exponent taken is positive."""
+    With no bound, or one under -9, no exponent taken is positive."""
     lead, (C, K) = G.shape[:-2], G.shape[-2:]
     nb = C // _BLOCK
     blocks = lambda x: x.reshape(lead + (nb, _BLOCK, K))    # noqa: E731
     Gb, rb, kb = blocks(G), blocks(rows), blocks(k)
+    if _bounded(lower_bound):
+        # both factors against the running sum at the block's middle;
+        # the columns up to the end of the rows' own block
+        mid = Gb[..., _BLOCK // 2 - 1, :]                     # [.., nb, K]
+        rfac = rb * jnp.exp(Gb - mid[..., :, None, :])
+        upto = (jnp.arange(C)[None, :]
+                < _BLOCK * (jnp.arange(nb)[:, None] + 1))[:, :, None]
+        cfac = k[..., None, :, :] * jnp.exp(jnp.where(
+            upto, mid[..., :, None, :] - G[..., None, :, :], -jnp.inf))
+        full = _mm("...isc,...irc->...isr", rfac, cfac).reshape(lead + (C, C))
+        return jnp.where(jnp.tril(jnp.ones((C, C), bool)), full, 0.0)
     # a block on the diagonal, from the differences themselves
     low = jnp.tril(jnp.ones((_BLOCK, _BLOCK), bool))[:, :, None]
     diff = jnp.where(low, Gb[..., :, None, :] - Gb[..., None, :, :],
@@ -98,9 +130,10 @@ def _chunks(x, n, C):
     return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
 
 
-def gated_delta_rule(q, k, v, g, beta, scale, chunk):
+def gated_delta_rule(q, k, v, g, beta, scale, chunk, lower_bound=None):
     """q, k, g [b, t, h, d_k], v [b, t, h, d_v], beta [b, t, h] ->
-    o [b, t, h, d_v] in v's type; see the module's text."""
+    o [b, t, h, d_v] in v's type; ``lower_bound``, where given, is at
+    most every entry of g.  See the module's text."""
     b, t, h, dk = q.shape
     n, C = t // chunk, chunk
     f32 = lambda x: x.astype(jnp.float32)                   # noqa: E731
@@ -109,8 +142,8 @@ def gated_delta_rule(q, k, v, g, beta, scale, chunk):
     B = _chunks(f32(beta), n, C)[..., None]                  # [n,b,h,C,1]
     G = jnp.cumsum(G, axis=-2)
     decay = jnp.exp(G)
-    A = _decayed_products(K, K, G)
-    Aq = _decayed_products(Q, K, G)
+    A = _decayed_products(K, K, G, lower_bound)
+    Aq = _decayed_products(Q, K, G, lower_bound)
     eye = jnp.eye(C, dtype=jnp.float32)
     system = eye + B * (A * (1.0 - eye))
     TV, TK = jnp.split(
@@ -155,21 +188,28 @@ def _check_chunks(t, chunk):
 @register("_contrib_GatedDeltaRule",
           input_names=("query", "key", "value", "gate", "beta"),
           params_spec=(Param("scale", float, -1.0),
-                       Param("chunk", int, 64)),
+                       Param("chunk", int, 64),
+                       Param("lower_bound", float, None)),
           hint="gateddeltarule", infer_shape=_rule_infer_shape)
 def _gated_delta_rule(p, c, q, k, v, g, beta):
     """The gated delta rule with a log decay a key channel, chunked:
     query, key, gate [b, t, h, d_k], value [b, t, h, d_v], beta
     [b, t, h] -> [b, t, h, d_v].  ``gate`` is at most 0; ``scale``
     multiplies the query (default d_k^-1/2); ``t`` is a multiple of
-    ``chunk``, which is a multiple of 16."""
-    t, chunk = q.shape[1], p["chunk"]
+    ``chunk``, which is a multiple of 16.  ``lower_bound``, where given,
+    says that every entry of ``gate`` is at least that (the default:
+    no bound declared); from -9 up the rule computes its diagonal
+    16-position blocks as one product with the blocks below them."""
+    t, chunk, bound = q.shape[1], p["chunk"], p["lower_bound"]
     _check_chunks(t, chunk)
     scale = p["scale"] if p["scale"] > 0 else q.shape[-1] ** -0.5
     _NODES.inc()
+    if _bounded(bound):
+        _BOUNDED.inc()
     _CHUNKS.inc(q.shape[0] * t // chunk)
     rule = jax.checkpoint(
-        lambda *xs: gated_delta_rule(*xs, scale=scale, chunk=chunk))
+        lambda *xs: gated_delta_rule(*xs, scale=scale, chunk=chunk,
+                                     lower_bound=bound))
     return rule(q, k, v, g, beta)
 
 

@@ -7,6 +7,7 @@ of heads and experts against the whole layer, and the tiny model through
 import importlib.util
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,26 +98,34 @@ def close(got, want, tol=2e-5):
 
 # ----------------------------------------------------------------------
 # the gated delta rule
-def rule_operands(seed, b, t, h=2, dk=16, dv=12, fast=False):
+def rule_operands(seed, b, t, h=2, dk=16, dv=12, decay="slow"):
     """Unit q and k, a log decay between -5 and 0 (``fast``: mostly near
-    -5, 320 over a chunk, where e^-G leaves float32), beta in (0, 1)."""
+    -5, 320 over a chunk, where e^-G leaves float32; ``at-bound``: -5 on
+    every step and channel, the worst a bound of -5 allows, where the
+    product form's factors reach e^+-40), beta in (0, 1)."""
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa
     g = -5 * jax.nn.sigmoid(3 * rnd(seed + 3, b, t, h, dk)
-                            + (2.0 if fast else -3.0))
+                            + (2.0 if decay == "fast" else -3.0))
+    if decay == "at-bound":
+        g = jnp.full_like(g, -5.0)
     return (unit(rnd(seed, b, t, h, dk)), unit(rnd(seed + 1, b, t, h, dk)),
             rnd(seed + 2, b, t, h, dv), g,
             jax.nn.sigmoid(rnd(seed + 4, b, t, h)))
 
 
-@pytest.mark.parametrize("fast", [False, True], ids=["slow", "fast-decay"])
+@pytest.mark.parametrize("decay", ["slow", "fast", "at-bound"],
+                         ids=["slow", "fast-decay", "at-bound"])
 @pytest.mark.parametrize("chunks", [2, 3])
-def test_chunked_rule_is_the_token_by_token_recurrence(chunks, fast, ref):
+@pytest.mark.parametrize("bound", [None, -5.0], ids=["none", "bound-5"])
+def test_chunked_rule_is_the_token_by_token_recurrence(bound, chunks, decay,
+                                                       ref):
     """Value and the gradients of all five operands against the
     reference's scan over positions, at two and three chunks of 64, a
-    batch of two rows."""
+    batch of two rows; with no gate bound declared (the elementwise
+    diagonal blocks) and with -5 (the product form)."""
     t = 64 * chunks
-    args = rule_operands(10 * chunks, 2, t, fast=fast)
-    rule = op_fn("_contrib_GatedDeltaRule", scale=0.25)
+    args = rule_operands(10 * chunks, 2, t, decay=decay)
+    rule = op_fn("_contrib_GatedDeltaRule", scale=0.25, lower_bound=bound)
     want_fn = lambda q, *rest: ref.delta_rule(q * 0.25, *rest)  # noqa: E731
     close(rule(*args), want_fn(*args))
     seed = rnd(5, 2, t, 2, 12)
@@ -124,9 +133,79 @@ def test_chunked_rule_is_the_token_by_token_recurrence(chunks, fast, ref):
                    argnums=range(5))(*args)
     want = jax.grad(lambda *a: jnp.sum(want_fn(*a) * seed),
                     argnums=range(5))(*args)
+    # at -5 on every step the decay's gradient is small beside the terms
+    # it sums, and its error is the chunked form's with either diagonal:
+    # against a float64 recurrence 3.5e-5 and 4.1e-5 of its norm with no
+    # bound (PR 37's code), 7.7e-5 and 1.0e-4 with one (the token-by-token
+    # float32 reference: 1e-7)
+    tol = {"g": 2e-4} if decay == "at-bound" else {}
     for name, a, b in zip(("q", "k", "v", "g", "beta"), got, want):
         assert np.isfinite(np.asarray(a)).all(), name
-        assert np.linalg.norm(a - b) <= 2e-5 * np.linalg.norm(b), name
+        assert np.linalg.norm(a - b) \
+            <= tol.get(name, 2e-5) * np.linalg.norm(b), name
+
+
+def _parents_decayed_products(rows, k, G):
+    """``op/delta_rule.py: _decayed_products`` as it stood before the
+    gate bound (PR 37's tree), line for line."""
+    from mxnet_tpu.op.delta_rule import _BLOCK, _mm
+    lead, (C, K) = G.shape[:-2], G.shape[-2:]
+    nb = C // _BLOCK
+    blocks = lambda x: x.reshape(lead + (nb, _BLOCK, K))    # noqa: E731
+    Gb, rb, kb = blocks(G), blocks(rows), blocks(k)
+    # a block on the diagonal, from the differences themselves
+    low = jnp.tril(jnp.ones((_BLOCK, _BLOCK), bool))[:, :, None]
+    diff = jnp.where(low, Gb[..., :, None, :] - Gb[..., None, :, :],
+                     -jnp.inf)
+    diag = jnp.sum(rb[..., :, None, :] * kb[..., None, :, :]
+                   * jnp.exp(diff), axis=-1)              # [.., nb, B, B]
+    out = (diag[..., :, :, None, :]
+           * jnp.eye(nb, dtype=G.dtype)[:, None, :, None]
+           ).reshape(lead + (C, C))
+    if nb == 1:
+        return out
+    # the blocks below: both factors against the running sum at the
+    # rows' block start, which lies between the two positions
+    start = jnp.concatenate([jnp.zeros_like(Gb[..., :1, 0, :]),
+                             Gb[..., :-1, -1, :]], axis=-2)   # [.., nb, K]
+    rfac = rb * jnp.exp(Gb - start[..., :, None, :])
+    before = (jnp.arange(C)[None, :]
+              < _BLOCK * jnp.arange(nb)[:, None])[:, :, None]  # [nb, C, 1]
+    cfac = k[..., None, :, :] * jnp.exp(jnp.where(
+        before, start[..., :, None, :] - G[..., None, :, :], -jnp.inf))
+    below = _mm("...isc,...irc->...isr", rfac, cfac)          # [.., nb, B, C]
+    return out + below.reshape(lead + (C, C))
+
+
+def _rule_jaxpr(**params):
+    """The jaxpr of the op's value and the gradients of its five
+    operands, 2 x 128 positions (chunks of 64: 4 blocks) of 2 heads of
+    24 channels, as text."""
+    args = rule_operands(70, 2, 128, dk=24)
+    rule = op_fn("_contrib_GatedDeltaRule", **params)
+    fn = jax.value_and_grad(lambda *a: jnp.sum(rule(*a)), argnums=range(5))
+    return str(jax.make_jaxpr(fn)(*args))
+
+
+def test_a_bound_takes_the_16_x_16_x_d_tiles_out_of_value_and_gradient(
+        monkeypatch):
+    """With a gate bound, no [.., 4 blocks, 16, 16, 24] array is left in
+    the jaxpr of the value or of the gradients; with none the tiles are
+    there and the jaxpr is the parent's, equation for equation; a bound
+    under -9 is no bound."""
+    from mxnet_tpu.op import delta_rule
+    tile = re.compile(r"\[[\d,]*4,16,16,24\]")
+    bounded = _rule_jaxpr(lower_bound=-5.0)
+    assert not tile.search(bounded) and "dot_general" in bounded
+    assert tile.search(_rule_jaxpr(lower_bound=-9.0)) is None
+    plain = _rule_jaxpr()
+    assert tile.search(plain)
+    assert _rule_jaxpr(lower_bound=-12.0) == plain
+    monkeypatch.setattr(
+        delta_rule, "_decayed_products",
+        lambda rows, k, G, lower_bound=None:
+        _parents_decayed_products(rows, k, G))
+    assert _rule_jaxpr() == plain and len(plain) > 1000
 
 
 def test_no_state_crosses_the_rows_of_a_batch(ref):
@@ -695,14 +774,17 @@ def test_tiny_model_bfloat16_stays_inside_the_float8_controls_gap(
 
 
 def test_obs_counters_of_the_rule_after_two_steps(tiny):
-    """``attention.kda.nodes`` rises by one for each rule node traced
-    and ``attention.kda.chunks`` by that node's chunk steps, batch x t /
-    64; steps of a compiled program trace, and count, nothing."""
+    """``attention.kda.nodes`` rises by one for each rule node traced,
+    ``attention.kda.bounded_nodes`` with it (the model's gate is bounded
+    and says so) and ``attention.kda.chunks`` by that node's chunk
+    steps, batch x t / 64; steps of a compiled program trace, and count,
+    nothing."""
     cfg, params, aux, feed, _ = tiny
 
     def read():
         c = obs.snapshot()["counters"]
         return (c.get("attention.kda.nodes", 0),
+                c.get("attention.kda.bounded_nodes", 0),
                 c.get("attention.kda.chunks", 0))
 
     start = read()
@@ -715,8 +797,35 @@ def test_obs_counters_of_the_rule_after_two_steps(tiny):
         mod.update()
 
     step(*feed[0])
-    nodes, chunks = (a - b for a, b in zip(read(), start))
+    nodes, bounded, chunks = (a - b for a, b in zip(read(), start))
     assert nodes >= 2 and nodes % 2 == 0       # two rule nodes a trace
+    assert bounded == nodes
     assert chunks == nodes * B * T // 64
     step(*feed[1])
-    assert read() == (start[0] + nodes, start[1] + chunks)
+    assert read() == tuple(a + b for a, b in zip(start,
+                                                 (nodes, bounded, chunks)))
+
+
+def test_every_rule_node_declares_the_configurations_gate_bound():
+    """The configuration's gate is the safe one, bounded below by
+    ``kda_lower_bound`` by construction; every ``kda_core`` node of the
+    builder says so, at the bound it is given, and a rule node with no
+    bound counts as a node and not as a bounded one."""
+    pub = published()
+    assert pub["kda_safe_gate"] is True and pub["kda_lower_bound"] == -5
+    for bound in (-5.0, -2.5):
+        attrs = models.get_symbol("bailing-hybrid", vocab_size=512,
+                                  seq_len=T, kda_lower_bound=bound,
+                                  layer_types="kda,mla,kda").attr_dict()
+        cores = {n: a for n, a in attrs.items() if n.endswith("kda_core")}
+        assert sorted(cores) == ["l0_kda_core", "l2_kda_core"]
+        assert {float(a["lower_bound"]) for a in cores.values()} == {bound}
+
+    def read():
+        c = obs.snapshot()["counters"]
+        return (c.get("attention.kda.nodes", 0),
+                c.get("attention.kda.bounded_nodes", 0))
+
+    before = read()
+    op_fn("_contrib_GatedDeltaRule")(*rule_operands(80, 1, 64))
+    assert read() == (before[0] + 1, before[1])

@@ -473,6 +473,15 @@ G("_contrib_GatedDeltaRule",
    "value": randn(1, 32, 2, 3), "gate": -pos(1, 32, 2, 4) * 0.3,
    "beta": R.uniform(0.2, 0.8, (1, 32, 2)).astype("f")},
   {"chunk": 16}, rtol=8e-2, atol=2e-2)
+# and with a gate bound declared and held: the diagonal blocks as the
+# product, two chunks of two blocks
+G("_contrib_GatedDeltaRule",
+  {"query": unit(1, 64, 2, 4), "key": unit(1, 64, 2, 4),
+   "value": randn(1, 64, 2, 3),
+   "gate": -R.uniform(0.0, 3.0, (1, 64, 2, 4)).astype("f"),
+   "beta": R.uniform(0.2, 0.8, (1, 64, 2)).astype("f")},
+  {"chunk": 32, "lower_bound": -3.0}, rtol=8e-2, atol=2e-2,
+  id_suffix="bounded")
 G("_contrib_ShortConv", {"data": randn(2, 6, 3), "weight": randn(3, 4)})
 # a loss a row, its reverse mode its own; and the exit distribution of
 # three gates, whose rows sum to 1
