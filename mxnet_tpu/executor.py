@@ -174,20 +174,21 @@ class _GraphProgram:
         aux_slots = [self._aux_index["%s_%s" % (n.name, a)]
                      for a in aux_names]
         node_aux = [aux_vals[s] for s in aux_slots]
-        if aux_names:
-            node_aux = [jax.lax.stop_gradient(v) for v in node_aux]
-        rng = None
-        if n.op.uses_rng:
-            rng = jax.random.fold_in(
-                rng_key, len(env) if rng_index is None else rng_index)
-        ctx = OpContext(is_train=is_train, rng=rng,
-                        platform=self.platform,
-                        dtype_policy=self.dtype_policy)
         # the named scope stamps the symbol name into the XLA metadata
         # (op_name="jit(..)/<node>/..") of every primitive this node
-        # traces — benchmark/lib/tracered.py joins a traced device op
-        # back to its symbol-level layer through it
+        # traces, its key's derivation included — benchmark/lib/
+        # tracered.py joins a traced device op back to its symbol-level
+        # layer through it
         with jax.named_scope(scope or n.name):
+            if aux_names:
+                node_aux = [jax.lax.stop_gradient(v) for v in node_aux]
+            rng = None
+            if n.op.uses_rng:
+                rng = jax.random.fold_in(
+                    rng_key, len(env) if rng_index is None else rng_index)
+            ctx = OpContext(is_train=is_train, rng=rng,
+                            platform=self.platform,
+                            dtype_policy=self.dtype_policy)
             outs, aux_updates = n.op.apply(n.params, ctx,
                                            *(in_vals + node_aux))
         dev = self.placement.get(n.name)

@@ -509,7 +509,7 @@ class BaseModule(object):
                               if trainer is not None
                               else self._obs_steps)) if on else None
             try:
-                with _obs.span("fit.fetch", corr=ncorr, parent=None):
+                with _obs.phase("fit.fetch", corr=ncorr, parent=None):
                     data_batch = retry_io(lambda: next(data_iter),
                                           what="train batch fetch",
                                           logger=self.logger)

@@ -28,6 +28,9 @@ built-in-monitoring design, PAPERS.md):
 
 ``tools/obs_report.py`` turns a log into per-request / per-step latency
 breakdowns (p50/p99 per segment) and gates span-site closure.
+:func:`phase` sites (the training step's and the compile layer's) are
+also profiler annotations, armed or not, so a ``jax.profiler`` capture
+names its host time in the program's own words.
 """
 from __future__ import annotations
 
@@ -35,6 +38,8 @@ import atexit
 import os
 import threading
 from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .registry import (REGISTRY, Counter, CounterDict,       # noqa: F401
                        DEFAULT_MS_BUCKETS, Gauge, Histogram, Registry)
@@ -44,7 +49,7 @@ from .export import chrome_trace, dump_chrome, parse_log      # noqa: F401
 
 __all__ = [
     "OBS", "enabled", "enable", "disable", "scoped", "recorder",
-    "span", "current_span", "flush", "dump",
+    "span", "phase", "current_span", "flush", "dump",
     "counter", "gauge", "histogram", "snapshot",
     "REGISTRY", "Registry", "Counter", "Gauge", "Histogram",
     "CounterDict", "DEFAULT_MS_BUCKETS",
@@ -146,6 +151,41 @@ def span(name: str, corr: Optional[str] = None,
     if not OBS:
         return NULL_SPAN
     return _REC.start(name, corr=corr, attrs=attrs, parent=parent)
+
+
+class _Phase:
+    """An armed :func:`phase`: the span inside a profiler annotation of
+    its name."""
+
+    __slots__ = ("_span", "_ann")
+
+    def __init__(self, sp: Span, name: str):
+        self._span = sp
+        self._ann = _TraceAnnotation(name)
+
+    def __enter__(self) -> Span:
+        self._ann.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+
+def phase(name: str, corr: Optional[str] = None,
+          attrs: Optional[Dict] = None, parent=AUTO_PARENT):
+    """A same-thread :func:`span` that is also a
+    ``jax.profiler.TraceAnnotation`` of the same name, armed or not: a
+    profiler capture shows it on the host's line, on the device
+    operations' clock.  Enter it as a context manager.  When recording
+    is off no span is made and no recorder is touched; the annotation
+    costs the profiler's own check, which does nothing unless a trace is
+    being collected."""
+    if not OBS:
+        return _TraceAnnotation(name)
+    return _Phase(_REC.start(name, corr=corr, attrs=attrs, parent=parent),
+                  name)
 
 
 def current_span() -> Optional[Span]:

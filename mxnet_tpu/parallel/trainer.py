@@ -17,6 +17,8 @@ weights; the compiler places the matching collectives.  bf16: pass
 """
 from __future__ import annotations
 
+import collections
+import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -49,6 +51,16 @@ __all__ = ["Trainer", "remat_policy"]
 _LS_INIT = 2.0 ** 15
 _LS_MAX = 2.0 ** 24
 _LS_GROWTH_INTERVAL = 200
+
+# the device scopes of the fused step's own work outside the graph walk:
+# the compute-dtype casts and their float32 way back, the cotangent
+# seeds, and the in-step state fingerprint.  Like the optimizer's
+# ``optimizer_update`` they never enclose a node's scope: a device trace
+# gives an operation to its outermost scope
+_CAST_SCOPE = "trainer_cast"
+_INTEGRITY_SCOPE = "trainer_integrity"
+# ``train.host_ms_p50`` is the median of this many steps' host times
+_HOST_STEPS = 64
 
 # MXNet-style output ops whose custom vjp INJECTS the loss gradient and
 # (with out_grad left False) discards the upstream cotangent — seed-side
@@ -387,6 +399,10 @@ class Trainer:
         self._lr_cache = None
         self._step_check_fn = None     # fingerprint-fused check program
         self._key = jax.random.key(0)
+        # host time of each step but the first (which compiles), in ms
+        self._host_ms = collections.deque(maxlen=_HOST_STEPS)
+        self._stepped = False
+        _obs.REGISTRY.pull(self._pull_host_gauge)
 
     def _data_axis_size(self) -> int:
         """Mesh ``data`` axis degree (1 without a mesh or data axis)."""
@@ -595,6 +611,16 @@ class Trainer:
         for gauge, values in read.items():
             _obs.gauge(gauge).set(float(np.mean(values)))
 
+    def _pull_host_gauge(self):
+        """``train.host_ms_p50``: the median host time of the last
+        ``_HOST_STEPS`` steps, from :meth:`step`'s entry to its return
+        (placement, integrity, dispatch, bookkeeping), the first step
+        left out.  The device's time is not in it: a step returns once
+        its program is dispatched."""
+        if self._host_ms:
+            _obs.gauge("train.host_ms_p50").set(
+                float(np.median(self._host_ms)))
+
     def __del__(self):
         # a trainer that goes away leaves its last reading in the gauges
         try:
@@ -602,6 +628,7 @@ class Trainer:
                 self._pull_op_gauges()
             if getattr(self, "_moe_layers", None):
                 self._pull_moe_gauges()
+            self._pull_host_gauge()
         except Exception:          # noqa: BLE001 — never raise from a finalizer
             pass
 
@@ -818,7 +845,8 @@ class Trainer:
                         "agree": agree.astype(jnp.int32),
                         "step": jnp.asarray(t, jnp.int32)}
 
-            return lax.cond(check, compute, lambda _: integ, 0)
+            with jax.named_scope(_INTEGRITY_SCOPE):
+                return lax.cond(check, compute, lambda _: integ, 0)
 
         return integ_update
 
@@ -919,24 +947,27 @@ class Trainer:
         self._update_fn = update_fn
 
         def _forward(params, aux_vals, batch, key, is_train):
-            # raw-uint8 input batches (NativeImageRecordIter
-            # dtype="uint8"): the float cast happens HERE, on device —
-            # the caller shipped quarter-size bytes over the host link
-            # and the graph still sees float input
-            batch = {n: (v.astype(compute_dtype or jnp.float32)
-                         if v.dtype == jnp.uint8 else v)
-                     for n, v in batch.items()}
-            if compute_dtype is not None:
-                params = {n: (v.astype(compute_dtype)
-                              if jnp.issubdtype(v.dtype, jnp.floating) else v)
-                          for n, v in params.items()}
-                batch = {n: (v.astype(compute_dtype)
-                             if jnp.issubdtype(v.dtype, jnp.floating)
-                             and n not in index_inputs else v)
+            with jax.named_scope(_CAST_SCOPE):
+                # raw-uint8 input batches (NativeImageRecordIter
+                # dtype="uint8"): the float cast happens HERE, on device
+                # — the caller shipped quarter-size bytes over the host
+                # link and the graph still sees float input
+                batch = {n: (v.astype(compute_dtype or jnp.float32)
+                             if v.dtype == jnp.uint8 else v)
                          for n, v in batch.items()}
-                aux_vals = [(v.astype(compute_dtype)
-                             if jnp.issubdtype(v.dtype, jnp.floating) else v)
-                            for v in aux_vals]
+                if compute_dtype is not None:
+                    params = {n: (v.astype(compute_dtype)
+                                  if jnp.issubdtype(v.dtype, jnp.floating)
+                                  else v)
+                              for n, v in params.items()}
+                    batch = {n: (v.astype(compute_dtype)
+                                 if jnp.issubdtype(v.dtype, jnp.floating)
+                                 and n not in index_inputs else v)
+                             for n, v in batch.items()}
+                    aux_vals = [(v.astype(compute_dtype)
+                                 if jnp.issubdtype(v.dtype, jnp.floating)
+                                 else v)
+                                for v in aux_vals]
             vals = [params[n] if n in param_set else batch[n]
                     for n in arg_names]
             outs, new_aux = prog._eval(vals, list(aux_vals), key, is_train)
@@ -994,20 +1025,23 @@ class Trainer:
             # in the f32 master-weight grad cast below.  The loss scale
             # rides the seeds: small bf16 cotangents stay out of
             # flush-to-zero.
-            if scale is None:
-                seeds = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
-            else:
-                seeds = tuple(jnp.full(o.shape, scale.astype(o.dtype),
-                                       o.dtype) for o in outs)
-            cot = (seeds,
-                   tuple(jnp.zeros(a.shape, a.dtype) for a in new_aux))
+            with jax.named_scope(_CAST_SCOPE):
+                if scale is None:
+                    seeds = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
+                else:
+                    seeds = tuple(jnp.full(o.shape, scale.astype(o.dtype),
+                                           o.dtype) for o in outs)
+                cot = (seeds,
+                       tuple(jnp.zeros(a.shape, a.dtype) for a in new_aux))
             grads = vjp(cot)[0]
-            grads = {n: g.astype(jnp.float32) for n, g in grads.items()}
-            # aux (BN moving stats) keep fp32 master copies like params do
-            new_aux = tuple(
-                v.astype(jnp.float32)
-                if jnp.issubdtype(v.dtype, jnp.floating) else v
-                for v in new_aux)
+            with jax.named_scope(_CAST_SCOPE):
+                grads = {n: g.astype(jnp.float32) for n, g in grads.items()}
+                # aux (BN moving stats) keep fp32 master copies like
+                # params do
+                new_aux = tuple(
+                    v.astype(jnp.float32)
+                    if jnp.issubdtype(v.dtype, jnp.floating) else v
+                    for v in new_aux)
             return outs, new_aux, grads
 
         def _accum_backward(params, aux_vals, batch, key, scale, spmd):
@@ -1022,37 +1056,42 @@ class Trainer:
             if K == 1:
                 return _micro_backward(params, tuple(aux_vals), batch, key,
                                        scale)
-            mb = {}
-            for nm, v in batch.items():
-                m = v.shape[0] // K
-                v = v.reshape((K, m) + v.shape[1:])
-                if spmd and self._batch_shardings is not None \
-                        and "data" in mesh.axis_names:
-                    # keep each MICROBATCH row-sharded over the data axis
-                    # (the reshape would otherwise tempt the partitioner
-                    # to shard the scan dim)
-                    v = jax.lax.with_sharding_constraint(
-                        v, NamedSharding(mesh,
-                                         PartitionSpec(None, "data")))
-                mb[nm] = v
+            with jax.named_scope(_CAST_SCOPE):
+                mb = {}
+                for nm, v in batch.items():
+                    m = v.shape[0] // K
+                    v = v.reshape((K, m) + v.shape[1:])
+                    if spmd and self._batch_shardings is not None \
+                            and "data" in mesh.axis_names:
+                        # keep each MICROBATCH row-sharded over the data
+                        # axis (the reshape would otherwise tempt the
+                        # partitioner to shard the scan dim)
+                        v = jax.lax.with_sharding_constraint(
+                            v, NamedSharding(mesh,
+                                             PartitionSpec(None, "data")))
+                    mb[nm] = v
+                gsum0 = {nm: jnp.zeros(params[nm].shape, jnp.float32)
+                         for nm in params}
+                steps = jnp.arange(K)
 
             def body(carry, xs):
                 aux_c, gsum = carry
                 batch_i, i = xs
-                k = jax.random.fold_in(key, i) if has_rng else key
+                with jax.named_scope(_CAST_SCOPE):
+                    k = jax.random.fold_in(key, i) if has_rng else key
                 outs, new_aux, g = _micro_backward(params, aux_c, batch_i,
                                                    k, scale)
-                gsum = jax.tree.map(jnp.add, gsum, g)
+                with jax.named_scope(_CAST_SCOPE):
+                    gsum = jax.tree.map(jnp.add, gsum, g)
                 return (new_aux, gsum), outs
 
-            gsum0 = {nm: jnp.zeros(params[nm].shape, jnp.float32)
-                     for nm in params}
             (aux_fin, gsum), outs_k = jax.lax.scan(
-                body, (tuple(aux_vals), gsum0), (mb, jnp.arange(K)))
-            # microbatch k produced rows [k*m, (k+1)*m): flattening the
-            # (K, m, ...) stack restores the original batch order
-            outs = tuple(o.reshape((o.shape[0] * o.shape[1],)
-                                   + o.shape[2:]) for o in outs_k)
+                body, (tuple(aux_vals), gsum0), (mb, steps))
+            with jax.named_scope(_CAST_SCOPE):
+                # microbatch k produced rows [k*m, (k+1)*m): flattening
+                # the (K, m, ...) stack restores the original batch order
+                outs = tuple(o.reshape((o.shape[0] * o.shape[1],)
+                                       + o.shape[2:]) for o in outs_k)
             return outs, aux_fin, gsum
 
         if lowp_on:
@@ -1073,8 +1112,9 @@ class Trainer:
                 def local(params, aux_vals, batch, key, *maybe_scale):
                     sc = maybe_scale[0] if maybe_scale else None
                     if has_rng:
-                        key2 = jax.random.fold_in(
-                            key, jax.lax.axis_index("data"))
+                        with jax.named_scope(_CAST_SCOPE):
+                            key2 = jax.random.fold_in(
+                                key, jax.lax.axis_index("data"))
                     else:
                         key2 = key
                     outs, new_aux, g = _accum_backward(
@@ -1084,10 +1124,10 @@ class Trainer:
                                                 jnp.bfloat16,
                                                 keep_shard=keep_shard[nm])
                              for nm, gl in g.items()}
-                    new_aux = tuple(
-                        jax.lax.pmean(v, "data")
-                        if jnp.issubdtype(v.dtype, jnp.floating) else v
-                        for v in new_aux)
+                        new_aux = tuple(
+                            jax.lax.pmean(v, "data")
+                            if jnp.issubdtype(v.dtype, jnp.floating) else v
+                            for v in new_aux)
                     return outs, new_aux, g
 
                 P = PartitionSpec
@@ -1120,8 +1160,9 @@ class Trainer:
                                                        batch, key, scale,
                                                        spmd=True)
             if scale is not None:
-                inv = 1.0 / scale
-                grads = {n: g * inv for n, g in grads.items()}
+                with jax.named_scope(_CAST_SCOPE):
+                    inv = 1.0 / scale
+                    grads = {n: g * inv for n, g in grads.items()}
             if zero_on:
                 with jax.named_scope("zero_grad_shard"):
                     grads = {n: jax.lax.with_sharding_constraint(
@@ -1158,8 +1199,9 @@ class Trainer:
                                                  None)
             new_params, new_state = _apply_update(params, grads, opt_state,
                                                   lr, t)
-            return (new_params, dict(zip(aux_names, new_aux)), new_state,
-                    tuple(o.astype(jnp.float32) for o in outs))
+            with jax.named_scope(_CAST_SCOPE):
+                outs = tuple(o.astype(jnp.float32) for o in outs)
+            return new_params, dict(zip(aux_names, new_aux)), new_state, outs
 
         param_names_sorted = list(self.param_names)
 
@@ -1182,7 +1224,7 @@ class Trainer:
                 for n in param_names_sorted:
                     finite = jnp.logical_and(
                         finite, jnp.all(jnp.isfinite(grads[n])))
-            t_eff = sent["t"] + 1
+                t_eff = sent["t"] + 1
             new_params, new_state = _apply_update(params, grads, opt_state,
                                                   lr, t_eff)
             with jax.named_scope("sentinel_select"):
@@ -1191,38 +1233,43 @@ class Trainer:
                 new_state = jax.tree.map(keep, new_state, opt_state)
                 new_aux = tuple(keep(v, aux[n])
                                 for n, v in zip(aux_names, new_aux))
-            good = jnp.where(finite, sent["good"] + 1, jnp.int32(0))
-            new_scale = sent["scale"]
-            if dynamic_ls:
-                grown = good >= growth
-                new_scale = jnp.where(
-                    finite,
-                    jnp.where(grown,
-                              jnp.minimum(new_scale * 2.0,
-                                          jnp.float32(_LS_MAX)),
-                              new_scale),
-                    jnp.maximum(new_scale * 0.5, jnp.float32(1.0)))
-                good = jnp.where(grown, jnp.int32(0), good)
-            new_sent = {
-                "skips": sent["skips"] + jnp.where(finite, 0, 1),
-                "consec": jnp.where(finite, jnp.int32(0),
-                                    sent["consec"] + 1),
-                "good": good,
-                "t": jnp.where(finite, t_eff, sent["t"]),
-                "scale": new_scale,
-            }
+                # the skip counters and the loss scale's schedule
+                good = jnp.where(finite, sent["good"] + 1, jnp.int32(0))
+                new_scale = sent["scale"]
+                if dynamic_ls:
+                    grown = good >= growth
+                    new_scale = jnp.where(
+                        finite,
+                        jnp.where(grown,
+                                  jnp.minimum(new_scale * 2.0,
+                                              jnp.float32(_LS_MAX)),
+                                  new_scale),
+                        jnp.maximum(new_scale * 0.5, jnp.float32(1.0)))
+                    good = jnp.where(grown, jnp.int32(0), good)
+                new_sent = {
+                    "skips": sent["skips"] + jnp.where(finite, 0, 1),
+                    "consec": jnp.where(finite, jnp.int32(0),
+                                        sent["consec"] + 1),
+                    "good": good,
+                    "t": jnp.where(finite, t_eff, sent["t"]),
+                    "scale": new_scale,
+                }
+            with jax.named_scope(_CAST_SCOPE):
+                outs = tuple(o.astype(jnp.float32) for o in outs)
             return (new_params, dict(zip(aux_names, new_aux)), new_state,
-                    new_sent, tuple(o.astype(jnp.float32) for o in outs))
+                    new_sent, outs)
 
         def evaluate(params, aux, batch, key):
             aux_vals = [aux[n] for n in aux_names]
             outs, _ = _forward(params, aux_vals, batch, key, False)
-            return tuple(o.astype(jnp.float32) for o in outs)
+            with jax.named_scope(_CAST_SCOPE):
+                return tuple(o.astype(jnp.float32) for o in outs)
 
         def evaluate_train(params, aux, batch, key):
             aux_vals = [aux[n] for n in aux_names]
             outs, _ = _forward(params, aux_vals, batch, key, True)
-            return tuple(o.astype(jnp.float32) for o in outs)
+            with jax.named_scope(_CAST_SCOPE):
+                return tuple(o.astype(jnp.float32) for o in outs)
 
         # --- integrity fingerprint + vote, fused into the step
         # (docs/how_to/resilience.md "Silent data corruption"): every
@@ -1375,7 +1422,22 @@ class Trainer:
         return out
 
     def step(self, batch: Dict, lr: Optional[float] = None) -> List[NDArray]:
-        """One fused train step.  Returns the graph outputs."""
+        """One fused train step.  Returns the graph outputs.
+
+        The step is a ``jax.profiler.StepTraceAnnotation`` named
+        ``train`` with the update's number, so that a profiler capture
+        marks the step boundaries; its host time feeds
+        ``train.host_ms_p50``."""
+        t0 = time.perf_counter()
+        with jax.profiler.StepTraceAnnotation("train",
+                                              step_num=self.num_update + 1):
+            outs = self._step(batch, lr)
+        if self._stepped:
+            self._host_ms.append((time.perf_counter() - t0) * 1e3)
+        self._stepped = True
+        return outs
+
+    def _step(self, batch: Dict, lr: Optional[float]) -> List[NDArray]:
         if self.params is None:
             raise MXNetError("call bind() + init_params() first")
         self.num_update += 1
@@ -1397,7 +1459,7 @@ class Trainer:
             import os
             os._exit(137)
         corr = ("s%d" % self.num_update) if _obs.OBS else None
-        with _obs.span("train.h2d", corr=corr):
+        with _obs.phase("train.h2d", corr=corr):
             dev_batch = self._device_batch(batch)
         # fault injection (docs/how_to/resilience.md): poison the staged
         # batch so the backward materializes non-finite grads and the
@@ -1419,8 +1481,8 @@ class Trainer:
             # ZeRO-1: the standalone vote reads THIS update's incoming
             # state (same bits the fused check would have hashed) before
             # the step's all-gather can launder a divergent replica
-            with _obs.span("train.integrity", corr=corr,
-                           attrs={"mode": self._integ_mode}):
+            with _obs.phase("train.integrity", corr=corr,
+                            attrs={"mode": self._integ_mode}):
                 self._external_vote()
                 self._integrity_after_check()
             check_now = False
@@ -1433,15 +1495,9 @@ class Trainer:
         if use_check:
             args += (self._integ,)
         args += (dev_batch, self._lr_cache[1], t_dev, key)
-        with _obs.span("train.dispatch", corr=corr):
+        with _obs.phase("train.dispatch", corr=corr):
             out = (self._step_check_fn if use_check
                    else self._step_fn)(*args)
-        if _obs.OBS:
-            # an armed run buys an honest dispatch-vs-device split: the
-            # sync span holds until the step's outputs materialize
-            # (off-mode keeps the normal async pipelining)
-            with _obs.span("train.sync", corr=corr):
-                jax.block_until_ready(out)
         self.params, self.aux, self.opt_state = out[0], out[1], out[2]
         i = 3
         if self._sent is not None:
@@ -1470,12 +1526,12 @@ class Trainer:
         if _faults.active("bitflip"):
             self._apply_bitflip_faults()
         if audit_now:
-            with _obs.span("train.integrity", corr=corr,
-                           attrs={"mode": "audit"}):
+            with _obs.phase("train.integrity", corr=corr,
+                            attrs={"mode": "audit"}):
                 self._audit_check(saved, t_dev, key)
         if check_now:
-            with _obs.span("train.integrity", corr=corr,
-                           attrs={"mode": self._integ_mode}):
+            with _obs.phase("train.integrity", corr=corr,
+                            attrs={"mode": self._integ_mode}):
                 self._integrity_after_check()
         return [NDArray(self._local_rows(o)) for o in outs]
 

@@ -335,9 +335,9 @@ class CompiledProgram:
             platform, ids = entry["devices"]
             by_id = {d.id: d for d in jax.devices(platform)}
             devices = [by_id[i] for i in ids]
-            with _obs.span("compile.load",
-                           attrs={"kind": self.kind,
-                                  "bytes": len(payload)}):
+            with _obs.phase("compile.load",
+                            attrs={"kind": self.kind,
+                                   "bytes": len(payload)}):
                 comp = _se.deserialize_and_load(
                     payload, entry["in_tree"], entry["out_tree"],
                     backend=platform, execution_devices=devices)
@@ -405,9 +405,9 @@ class CompiledProgram:
         """``.lower().compile()`` with spans + counters; the resulting
         executable also lands in jax's own jit cache, so a later
         ``self._jit(*args)`` at this signature is a pure cache hit."""
-        with _obs.span("compile.trace", attrs={"kind": self.kind}):
+        with _obs.phase("compile.trace", attrs={"kind": self.kind}):
             lowered = self._jit.lower(*args)
-        with _obs.span("compile.compile", attrs={"kind": self.kind}):
+        with _obs.phase("compile.compile", attrs={"kind": self.kind}):
             compiled = lowered.compile()
         _COMPILES.inc()
         return compiled

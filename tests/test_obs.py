@@ -312,9 +312,10 @@ def test_fit_step_span_tree(monkeypatch, tmp_path):
     assert [s.corr for s in steps] == ["s%d" % i for i in range(1, 9)]
     first = steps[0]
     kids = sorted({s.name for s in spans if s.parent == first.sid})
-    # h2d/dispatch/sync recorded INSIDE Trainer.step nest under fit's
-    # root via the thread-local stack, sharing its correlation ID
-    assert kids == ["train.dispatch", "train.h2d", "train.sync"]
+    # h2d/dispatch recorded INSIDE Trainer.step nest under fit's root
+    # via the thread-local stack, sharing its correlation ID; an armed
+    # step no longer waits for the device, so there is no sync span
+    assert kids == ["train.dispatch", "train.h2d"]
     assert all(s.corr == first.corr for s in spans
                if s.parent == first.sid)
     fetches = [s for s in by["fit.fetch"] if s.corr == first.corr]
@@ -337,6 +338,165 @@ def test_sentinel_gauge_updates_on_read(monkeypatch):
             if k.startswith("train.trainer") and
             k.endswith(".sentinel_skips")]
     assert mine and gauges[tr._obs_skips_gauge.name] == skips
+
+
+# ----------------------------------------------------------------------
+# the fused step seen from a profiler: device scopes, step markers,
+# host annotations, the host-time gauge
+TRAINER_SCOPES = ("trainer_cast", "optimizer_update", "sentinel_finite",
+                  "sentinel_select")
+
+
+def _bf16_trainer(monkeypatch, sentinel="off"):
+    """A bound bf16 Module's fused trainer: BatchNorm for auxiliary
+    state, a residual sum so a cotangent fans in."""
+    monkeypatch.setenv("MXTPU_MODULE_FUSED", "always")
+    monkeypatch.setenv("MXTPU_SENTINEL", sentinel)
+    data = mx.sym.Variable("data")
+    h = mx.sym.FullyConnected(data, num_hidden=8, name="fc1")
+    h = mx.sym.BatchNorm(h, name="bn1")
+    a = mx.sym.Activation(h, act_type="relu", name="relu1")
+    h = a + mx.sym.FullyConnected(a, num_hidden=8, name="fc1b")
+    h = mx.sym.FullyConnected(h, num_hidden=3, name="fc2")
+    sym = mx.sym.SoftmaxOutput(h, name="softmax")
+    mod = mx.mod.Module(sym, context=mx.cpu(), compute_dtype="bfloat16")
+    mod.bind(data_shapes=[("data", (8, 10))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params(mx.init.Xavier())
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1,
+                                         "momentum": 0.9})
+    assert mod._trainer is not None
+    return mod._trainer
+
+
+def _batch():
+    rng = np.random.RandomState(0)
+    return {"data": rng.randn(8, 10).astype("f"),
+            "softmax_label": rng.randint(0, 3, 8).astype("f")}
+
+
+def _outermost(stack):
+    """The first scope of a name stack, autodiff's wrappers taken off:
+    ``transpose(jvp(fc1))/...`` gives ``fc1``."""
+    first = stack.split("/")[0]
+    while first.startswith(("transpose(", "jvp(")):
+        first = first[first.index("(") + 1:-1]
+    return first
+
+
+@pytest.mark.parametrize("sentinel", ["off", "skip"])
+def test_fused_step_ops_each_have_an_owner(monkeypatch, sentinel):
+    """Every equation of the fused step lies under a Symbol node's scope
+    or one of the trainer's own, outermost; no node's scope lies under
+    the trainer's; the compute-dtype casts and their way back are
+    ``trainer_cast``'s."""
+    from mxnet_tpu.analysis.jaxpr_passes import (_eqn_stack, _sub_jaxprs,
+                                                 iter_eqns_scoped)
+    tr = _bf16_trainer(monkeypatch, sentinel)
+    nodes = {n.name for n in tr.prog.nodes if not n.is_variable}
+    owners = nodes | set(TRAINER_SCOPES)
+    casts = {}
+    for eqn, prefix, _ in iter_eqns_scoped(tr.step_jaxpr()):
+        if list(_sub_jaxprs(eqn)):
+            continue
+        own = _eqn_stack(eqn)
+        stack = "%s/%s" % (prefix, own) if prefix and own else own or prefix
+        top = _outermost(stack)
+        if not top:
+            # autodiff's instantiated zero cotangent of a parameter no
+            # gradient reaches (BatchNorm's fixed gamma): a broadcast of
+            # a literal zero that the compiler folds into its reader
+            assert eqn.primitive.name == "broadcast_in_dim" \
+                and float(eqn.invars[0].val) == 0.0, (stack, eqn)
+            continue
+        assert top in owners, (stack, eqn)
+        if top in TRAINER_SCOPES:
+            assert not nodes & set(_outermost(p) for p in stack.split("/")), \
+                stack
+        if eqn.primitive.name == "convert_element_type":
+            casts.setdefault(stack.split("/")[0], []).append(eqn)
+    floats = len(tr.param_names)
+    # masters to bfloat16 on the way in, and the cast's transpose on the
+    # gradients' way back; outputs, gradients and aux back to float32
+    assert len(casts.get("jvp(trainer_cast)", ())) >= floats
+    assert len(casts.get("transpose(jvp(trainer_cast))", ())) >= 1
+    assert any(e.params["new_dtype"] == np.float32
+               for e in casts.get("trainer_cast", ()))
+    if sentinel == "skip":
+        assert tr._sent is not None
+
+
+def test_step_markers_and_dispatch_annotations_in_a_capture(monkeypatch,
+                                                            tmp_path):
+    """Three steps under ``jax.profiler.trace``, recording off: three
+    ``train`` step markers numbered 1 to 3 on the host's line, each
+    holding one ``train.dispatch`` annotation."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    assert not obs.enabled()
+    tr = _bf16_trainer(monkeypatch)
+    batch = _batch()
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            tr.step(batch)
+        jax.block_until_ready(tr.params)
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    events = [ev for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:CPU")
+              for line in plane.lines for ev in line.events]
+    marks = sorted((ev for ev in events if ev.name == "train"),
+                   key=lambda ev: ev.start_ns)
+    assert [dict(ev.stats)["step_num"] for ev in marks] == [1, 2, 3]
+    dispatches = [ev for ev in events if ev.name == "train.dispatch"]
+    assert len(dispatches) == 3
+    for mark in marks:
+        inside = [d for d in dispatches if mark.start_ns <= d.start_ns
+                  and d.start_ns + d.duration_ns
+                  <= mark.start_ns + mark.duration_ns]
+        assert len(inside) == 1
+
+
+def test_armed_step_does_not_wait_for_the_device(monkeypatch):
+    import jax
+    tr = _bf16_trainer(monkeypatch)
+    batch = _batch()
+    tr.step(batch)
+    waits = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waits.append(1) or real(x))
+    with obs.scoped() as rec:
+        for _ in range(3):
+            tr.step(batch)
+        names = [s.name for s in rec.finished()]
+    assert waits == []
+    assert names.count("train.dispatch") == 3
+    assert "train.sync" not in names
+
+
+def test_host_ms_gauge_follows_the_step(monkeypatch):
+    """``train.host_ms_p50`` is read through the registry's pull hook
+    and rises with time spent inside ``Trainer.step`` on the host."""
+    tr = _bf16_trainer(monkeypatch)
+    batch = _batch()
+    for _ in range(4):
+        tr.step(batch)
+    before = obs.snapshot()["gauges"]["train.host_ms_p50"]
+    assert before > 0
+    place = tr._device_batch
+
+    def slow(b):
+        time.sleep(0.02)
+        return place(b)
+
+    monkeypatch.setattr(tr, "_device_batch", slow)
+    for _ in range(5):          # 5 slow of the 8 steps timed: the median
+        tr.step(batch)
+    after = obs.snapshot()["gauges"]["train.host_ms_p50"]
+    assert after - before >= 20.0
 
 
 # ----------------------------------------------------------------------
@@ -482,9 +642,8 @@ def test_acceptance_single_log_serving_and_fit(tmp_path, monkeypatch):
                      if "train.dispatch" in r["segments_ms"]]
     assert len(with_dispatch) == 8
     for row in with_dispatch:
-        assert "train.h2d" in row["segments_ms"]
-        assert "train.sync" in row["segments_ms"]
-        assert "fit.fetch" in row["segments_ms"]
+        assert {"fit.fetch", "train.h2d"} <= set(row["segments_ms"])
+        assert "train.sync" not in row["segments_ms"]
     # chrome export: the loader/scheduler/main rows are distinct
     out = str(tmp_path / "trace.json")
     assert obs_report.main([log, "--chrome", out, "--check",

@@ -14,8 +14,11 @@ reconstructs:
   (``--tol`` gates the residual; default 5%).
 * **per-step training breakdowns** — spans sharing one ``s<n>``
   correlation ID (``fit.fetch``, ``elastic.guard``, ``train.h2d``,
-  ``train.dispatch``, ``train.sync``, ``train.integrity``,
-  ``io.wait``) fold into one row per update.
+  ``train.dispatch``, ``train.integrity``, ``io.wait``) fold into one
+  row per update.  They are the host's side of a step: a step returns
+  once its program is dispatched, and the device's time is read from a
+  profiler capture, where the same sites are annotations of the same
+  names inside a ``train`` step marker.
 
 Aggregates are p50/p99 per segment.  ``--chrome OUT`` additionally
 renders the spans to Chrome tracing JSON (open in Perfetto).
@@ -45,7 +48,7 @@ from mxnet_tpu.obs import export as _export                 # noqa: E402
 
 SERVE_SEGMENTS = ("queue", "pad", "dispatch", "execute", "slice")
 STEP_SEGMENTS = ("fit.fetch", "elastic.guard", "train.h2d",
-                 "train.dispatch", "train.sync", "train.integrity")
+                 "train.dispatch", "train.integrity")
 
 
 def _pcts(vals):
