@@ -111,22 +111,26 @@ relu_save_output.defvjp(_relu_fwd, _relu_bwd)
 # variance would be carved out of less than 1/64 of d2, costing ≥6 of
 # f32's 24 mantissa bits — fall back to exact two-pass statistics via
 # lax.cond (the second pass only executes in that regime; steady state
-# keeps the one-read fast path).
+# keeps the one-read fast path).  The conditional takes the activation in
+# its own dtype and only ``two_pass`` widens it: an operand of a
+# conditional is a buffer, so a widened operand would be an f32 copy of
+# every BN input written each step and read only in the fallback.
 _CANCEL_FRAC = 63.0 / 64.0
 
 
 def bn_batch_stats(data, center32, reduce_axes):
     """Single-pass f32 batch statistics of ``data`` over ``reduce_axes``
     centered on ``center32`` (per-channel f32), with the catastrophic-
-    cancellation fallback.  Returns (mean32, var32) per channel."""
-    stat_in = data.astype(jnp.float32) \
-        if data.dtype in (jnp.bfloat16, jnp.float16) else data
+    cancellation fallback.  Returns (mean32, var32) per channel.  The
+    statistics are one read of ``data`` in its own dtype, widened inside
+    the reduction; no widened copy of it crosses the branch boundary."""
+    acc = jnp.promote_types(data.dtype, jnp.float32)
     ndim = data.ndim
     ax = [i for i in range(ndim) if i not in reduce_axes]
     assert len(ax) == 1
     bshape = tuple(data.shape[i] if i == ax[0] else 1 for i in range(ndim))
     n_red = float(np.prod([data.shape[i] for i in reduce_axes]))
-    xc = stat_in - center32.reshape(bshape)
+    xc = data.astype(acc) - center32.reshape(bshape)
     d1 = jnp.sum(xc, axis=tuple(reduce_axes)) / n_red
     d2 = jnp.sum(xc * xc, axis=tuple(reduce_axes)) / n_red
     mean32 = d1 + center32
@@ -135,12 +139,12 @@ def bn_batch_stats(data, center32, reduce_axes):
         return jnp.maximum(d2 - d1 * d1, 0.0)
 
     def two_pass(operand):
-        s, m = operand
-        xm = s - m.reshape(bshape)
+        x, m = operand
+        xm = x.astype(acc) - m.reshape(bshape)
         return jnp.sum(xm * xm, axis=tuple(reduce_axes)) / n_red
 
     cancels = jnp.any(d1 * d1 > _CANCEL_FRAC * d2)
-    var32 = lax.cond(cancels, two_pass, fast, (stat_in, mean32))
+    var32 = lax.cond(cancels, two_pass, fast, (data, mean32))
     return mean32, var32
 
 

@@ -517,11 +517,12 @@ def _batch_norm(p, c, data, gamma, beta, moving_mean, moving_var):
     use_batch_stats = c.is_train and not p["use_global_stats"]
     if use_batch_stats:
         # SINGLE-PASS statistics with f32 accumulation: sum(x-c) and
-        # sum((x-c)^2) reduce together over ONE read of the bf16
-        # activation (jnp.var's (x-mean)^2 formulation needs a second
-        # full pass — on a byte-bound step the extra read of the
-        # widened activation is the cost; the f32 convert_reduce
-        # fusions that topped the step's HLO walk through round 4).
+        # sum((x-c)^2) reduce together over ONE read of the activation
+        # in its own dtype, widened inside the reduction (jnp.var's
+        # (x-mean)^2 formulation needs a second full pass — on a
+        # byte-bound step the extra read is the cost).  No widened copy
+        # of the activation is written: the fallback below takes it as
+        # it is and widens it inside its own branch.
         # Centering on the RUNNING mean c (an aux input — free) keeps
         # the E[.]-mean^2 subtraction benign at steady state, and
         # bytediet.bn_batch_stats guards the catastrophic regime (batch

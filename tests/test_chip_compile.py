@@ -237,3 +237,58 @@ def test_expert_layer_compiles_to_the_chips_grouped_kernels(
         and "[%d,%d]" % (t * k, h) not in text
     rows = moe.held_chunk_rows(t * k, held, experts)
     assert "bf16[%d,%d]" % (rows, d) in text
+
+
+# (activation, policy, with its gradient): ResNet-50's stage-1 and
+# stage-3 BatchNorm inputs, NHWC, in the trainer's compute dtype
+BN_CASES = [
+    pytest.param((256, 56, 56, 256), "bytediet", False, id="56x56-forward"),
+    pytest.param((256, 14, 14, 256), "bytediet", False, id="14x14-forward"),
+    pytest.param((256, 56, 56, 256), "legacy", False, id="56x56-legacy"),
+    pytest.param((256, 14, 14, 256), "legacy", False, id="14x14-legacy"),
+    pytest.param((256, 56, 56, 256), "bytediet", True, id="56x56-gradient"),
+    pytest.param((256, 14, 14, 256), "bytediet", True, id="14x14-gradient"),
+]
+
+
+@pytest.mark.parametrize("shape,policy,grad", BN_CASES)
+def test_batchnorm_statistics_write_no_float32_copy(v5e, shape, policy, grad):
+    """Train-mode ``BatchNorm`` through the op registry, then ``relu``,
+    compiled for the v5e in bfloat16: the statistics read the activation
+    as it is, so the program's entry holds no float32 array of its shape
+    (an operand of the cancellation fallback's conditional is a buffer:
+    widened there, it is a copy written every step, 1.2 GB of traffic
+    at 56x56).  The entry holds the bfloat16 output of that shape: the
+    detector reads the text."""
+    from mxnet_tpu.op import bytediet
+    from mxnet_tpu.op.registry import OpContext, get
+
+    op = get("BatchNorm")
+    params = op.parse_params(dict(eps=2e-5, momentum=0.9, fix_gamma=False,
+                                  axis=3))
+    ctx = OpContext(is_train=True, dtype_policy=policy)
+
+    def forward(x, gamma, beta, moving_mean, moving_var):
+        (out,), aux = op.apply(params, ctx, x, gamma, beta, moving_mean,
+                               moving_var)
+        return bytediet.relu_save_output(out), aux
+
+    def step(x, gamma, beta, moving_mean, moving_var):
+        def loss(x, gamma, beta):
+            y, aux = forward(x, gamma, beta, moving_mean, moving_var)
+            return jnp.sum(y.astype(jnp.float32) ** 2), aux
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            x, gamma, beta)
+
+    def spec(s):
+        return jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=v5e)
+
+    c = (shape[-1],)
+    text = jax.jit(step if grad else forward).lower(
+        spec(shape), spec(c), spec(c), spec(c), spec(c)).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    dims = ",".join(str(n) for n in shape)
+    assert re.search(r"= bf16\[%s\]" % dims, entry)
+    widened = [line.strip()[:120] for line in entry.splitlines()
+               if re.search(r"= f32\[%s\]" % dims, line)]
+    assert widened == []

@@ -479,7 +479,9 @@ def test_armed_step_does_not_wait_for_the_device(monkeypatch):
 
 def test_host_ms_gauge_follows_the_step(monkeypatch):
     """``train.host_ms_p50`` is read through the registry's pull hook
-    and rises with time spent inside ``Trainer.step`` on the host."""
+    and rises with time spent inside ``Trainer.step`` on the host: by
+    the 50 ms slept, less a fifth for a loaded host, whose fast steps
+    wander by a few milliseconds."""
     tr = _bf16_trainer(monkeypatch)
     batch = _batch()
     for _ in range(4):
@@ -489,14 +491,14 @@ def test_host_ms_gauge_follows_the_step(monkeypatch):
     place = tr._device_batch
 
     def slow(b):
-        time.sleep(0.02)
+        time.sleep(0.05)
         return place(b)
 
     monkeypatch.setattr(tr, "_device_batch", slow)
     for _ in range(5):          # 5 slow of the 8 steps timed: the median
         tr.step(batch)
     after = obs.snapshot()["gauges"]["train.host_ms_p50"]
-    assert after - before >= 20.0
+    assert after - before >= 40.0
 
 
 # ----------------------------------------------------------------------

@@ -335,6 +335,77 @@ def test_batchnorm_train_and_moments():
     assert_almost_equal(mm, 0.1 * mean, rtol=1e-3, atol=1e-3)
 
 
+@pytest.mark.parametrize("policy", ["bytediet", "legacy"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("regime", ["fallback", "steady"])
+def test_batchnorm_statistics_against_float64(regime, dtype, policy):
+    """Train-mode ``BatchNorm`` on channels whose means are about 10^3
+    times their deviation.  With ``moving_mean`` 0 the single-pass
+    moments cancel (d1² > 63/64·d2 on every channel) and the op takes
+    its two-pass fallback; with ``moving_mean`` at the batch mean they
+    do not.  Either way mean, variance, the updated aux and the output
+    match float64 two-pass statistics of the data as the op sees it.
+    The variance's tolerance (1e-5 in float32, 1e-2 in bfloat16, whose
+    outputs round to 2^-9) is one that the single-pass formula alone
+    misses in the fallback regime: on this data it is off by 56% in
+    float32 and 43% in bfloat16 on its worst channel, 56,000 and 43
+    times the tolerances.  The output is held to four of the dtype's
+    epsilons at the channel's magnitude over its deviation: 5e-4 of a
+    deviation in float32, where the single-pass variance puts it off by
+    up to a fifth of its value; in bfloat16 that is tens of deviations,
+    and the statistics decide."""
+    import jax.numpy as jnp
+    from mxnet_tpu.op.registry import OpContext, get
+
+    rng = np.random.RandomState(0)
+    mean = np.array([1000.0, -2000.0, 3000.0, 500.0])
+    x = rng.randn(16, 4, 8, 8) * (np.abs(mean) / 1000)[:, None, None] \
+        + mean[:, None, None]
+    data = jnp.asarray(x, dtype)
+    x64 = np.asarray(data.astype(jnp.float32), np.float64)
+    axes = (0, 2, 3)
+    m64 = x64.mean(axis=axes)
+    v64 = ((x64 - m64[:, None, None]) ** 2).mean(axis=axes)
+    center = np.zeros(4) if regime == "fallback" else m64
+    moving_mean = jnp.asarray(center, dtype)
+    c = np.asarray(moving_mean.astype(jnp.float32), np.float64)
+    d1 = (x64 - c[:, None, None]).mean(axis=axes)
+    d2 = ((x64 - c[:, None, None]) ** 2).mean(axis=axes)
+    assert ((d1 * d1 > 63 / 64 * d2) == (regime == "fallback")).all()
+
+    tol = 1e-5 if dtype == "float32" else 1e-2
+
+    def got(a):
+        return np.asarray(jnp.asarray(a).astype(jnp.float32), np.float64)
+
+    if regime == "fallback":
+        xc = data.astype(jnp.float32)
+        fast = jnp.sum(xc * xc, axis=axes) / 1024 \
+            - (jnp.sum(xc, axis=axes) / 1024) ** 2
+        assert np.max(np.abs(got(fast) - v64) / v64) > 10 * tol
+
+    op = get("BatchNorm")
+    ctx = OpContext(is_train=True, dtype_policy=policy)
+    ones, zeros = jnp.ones(4, dtype), jnp.zeros(4, dtype)
+    want = (x64 - m64[:, None, None]) / np.sqrt(v64[:, None, None] + 1e-5)
+    out_tol = 4 * float(jnp.finfo(dtype).eps) \
+        * np.abs(x64).max(axis=axes) / np.sqrt(v64)
+    for output_mean_var in (True, False):
+        params = op.parse_params(dict(eps=1e-5, momentum=0.9,
+                                      fix_gamma=True,
+                                      output_mean_var=output_mean_var))
+        outs, (new_mean, new_var) = op.apply(params, ctx, data, ones, zeros,
+                                             moving_mean, zeros)
+        if output_mean_var:
+            np.testing.assert_allclose(got(outs[1]), m64, rtol=tol)
+            np.testing.assert_allclose(got(outs[2]), v64, rtol=tol)
+        np.testing.assert_allclose(got(new_mean), 0.9 * c + 0.1 * m64,
+                                   rtol=tol)
+        np.testing.assert_allclose(got(new_var), 0.1 * v64, rtol=tol)
+        err = np.abs(got(outs[0]) - want).max(axis=axes)
+        assert (err <= out_tol).all(), (err, out_tol)
+
+
 def test_dropout_modes():
     x = mx.sym.Variable("x")
     sym = mx.symbol.Dropout(x, p=0.5)
