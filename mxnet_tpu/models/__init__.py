@@ -8,7 +8,9 @@ latent-attention mixture-of-experts language model (``glm-moe``) and a
 hybrid whose blocks mix by a delta rule with a state along the sequence
 or by latent attention (``bailing-hybrid``), and a looped language model
 whose one stack of layers runs several times on shared weights, with an
-exit gate after every pass (``loop-lm``).
+exit gate after every pass (``loop-lm``), and a hybrid whose blocks mix
+by a gated short convolution or by grouped-query attention, with routed
+experts and no shared one (``lfm2-moe``).
 Architectures are standard published networks, written fresh in
 mxnet_tpu Symbol idiom; the graphs compile to single XLA computations.
 
@@ -31,11 +33,12 @@ from . import transformer
 from . import glm_moe
 from . import bailing_hybrid
 from . import loop_lm
+from . import lfm2_moe
 
 __all__ = ["get_symbol", "mlp", "lenet", "alexnet", "vgg", "resnet",
            "resnext", "googlenet", "inception_bn", "inception_v3",
            "inception_resnet_v2", "lstm_lm", "transformer", "glm_moe",
-           "bailing_hybrid", "loop_lm"]
+           "bailing_hybrid", "loop_lm", "lfm2_moe"]
 
 _BUILDERS = {
     "mlp": mlp.get_symbol,
@@ -50,6 +53,7 @@ _BUILDERS = {
     "glm-moe": glm_moe.get_symbol,
     "bailing-hybrid": bailing_hybrid.get_symbol,
     "loop-lm": loop_lm.get_symbol,
+    "lfm2-moe": lfm2_moe.get_symbol,
 }
 
 

@@ -15,7 +15,8 @@ all heads; expanded, it is ordinary causal attention at head dimension
 (``v_head_dim`` may differ from it: the attention op pads the narrower
 operands with zero columns and cuts the result, see ``op/attention.py``).
 ``_linear``, ``_gated_ffn``, ``_expert_layer`` and ``_block`` (which
-takes its mixer) also build ``models/bailing_hybrid.py``, the first two
+takes its mixer) also build ``models/bailing_hybrid.py`` and
+``models/lfm2_moe.py`` (which has no shared expert), the first two
 ``models/loop_lm.py``.
 
 The multi-token-prediction module (arXiv:2412.19437 sec. 2.2, depth 1)
@@ -88,7 +89,8 @@ def _attention(x, cfg):
 
 
 def _expert_layer(x, cfg):
-    """Shared expert plus this chip's share of the routed experts."""
+    """Shared expert plus this chip's share of the routed experts; with
+    ``cfg["n_shared"]`` 0 the routed share alone."""
     router = sym.MoERouter(x, num_experts=cfg["n_experts"],
                            top_k=cfg["top_k"], scale=cfg["scaling"],
                            n_group=cfg.get("n_group", 1),
@@ -100,6 +102,8 @@ def _expert_layer(x, cfg):
                             first_expert=cfg["first_expert"],
                             num_hidden=cfg["moe_width"],
                             name="moe_experts")
+    if not cfg.get("n_shared", 1):
+        return routed
     return _gated_ffn(x, cfg["moe_width"], cfg["hidden"],
                       "moe_shared_") + routed
 

@@ -9,18 +9,44 @@ jnp oracle with identical semantics.  Value heads may be narrower or wider
 than query/key heads (latent attention at 192 / 128): the kernels take one
 head dimension, so the three operands are padded with zero columns to the
 next whole lane tile and the result is cut to the value width, which is
-exact.  Sequence parallelism over a mesh is
-``mx.parallel.ring_attention`` — same math, K/V rotated over ICI.
+exact.  Keys and values may carry fewer heads than queries (grouped
+key/value heads): ``h_kv`` dividing the query's ``h``, query head j
+reads key/value head j // (h / h_kv).  Neither path takes a key/value
+head count, so k and v are repeated along the head axis to ``h`` first;
+autodiff sums dk and dv over each group, which is exact.  Sequence
+parallelism over a mesh is ``mx.parallel.ring_attention`` — same math,
+K/V rotated over ICI.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from .. import obs as _obs
+from ..base import MXNetError
 from .registry import Param, register
 
 
 _LANES = 128
+
+# nodes traced with fewer key/value heads than query heads, and the
+# bytes of k and v their repetition to the query's heads writes
+_GROUPED = _obs.counter("attention.grouped_kv.nodes")
+_REPEAT_BYTES = _obs.counter("attention.grouped_kv.repeat_bytes")
+
+
+def _kv_groups(q_shape, k_shape, v_shape):
+    """How many query heads read one key/value head; an error where key
+    and value differ in heads or their heads do not divide the query's."""
+    h, h_kv = q_shape[2], k_shape[2]
+    if v_shape[2] != h_kv:
+        raise MXNetError("_contrib_DotProductAttention: key and value "
+                         "carry %d and %d heads" % (h_kv, v_shape[2]))
+    if h_kv < 1 or h % h_kv:
+        raise MXNetError(
+            "_contrib_DotProductAttention: %d key/value heads do not "
+            "divide %d query heads" % (h_kv, h))
+    return h // h_kv
 
 
 def _attention_infer_shape(p, in_shapes):
@@ -28,7 +54,8 @@ def _attention_infer_shape(p, in_shapes):
     # Symbol's shapes has to trace the kernel to learn it
     if any(s is None or 0 in s for s in in_shapes):
         return None
-    q, _, v = in_shapes
+    q, k, v = in_shapes
+    _kv_groups(q, k, v)
     return ([tuple(s) for s in in_shapes],
             [tuple(q[:-1]) + (v[-1],)], [])
 
@@ -45,7 +72,16 @@ def _attention_infer_shape(p, in_shapes):
           hint="dotproductattention",
           infer_shape=_attention_infer_shape)
 def _dot_product_attention(p, c, q, k, v):
+    """query [b, t, h, d], key [b, t_kv, h_kv, d], value [b, t_kv,
+    h_kv, d_v] -> [b, t, h, d_v]; ``h_kv`` divides ``h`` and query head
+    j reads key/value head j // (h / h_kv)."""
     scale = None if p["scale"] <= 0 else p["scale"]
+    groups = _kv_groups(q.shape, k.shape, v.shape)
+    if groups > 1:
+        _GROUPED.inc()
+        _REPEAT_BYTES.inc(groups * (k.size * k.dtype.itemsize
+                                    + v.size * v.dtype.itemsize))
+        k, v = (jnp.repeat(x, groups, axis=2) for x in (k, v))
     if p["flash"]:
         from .pallas import flash_attention
         plat = c.platform or jax.default_backend()

@@ -58,6 +58,8 @@ SHAPES = [
                  id="glm-4.7-flash"),
     pytest.param((1, 4096, 16, 128), jnp.bfloat16, True, True,
                  id="ouro-2.6b"),
+    pytest.param((1, 8192, 32, 64), jnp.bfloat16, True, True,
+                 id="lfm2-24b-a2b"),
 ]
 
 
@@ -128,6 +130,33 @@ def test_flash_gradient_compiles_for_v5e(v5e, shape, dtype, causal, in_place):
     assert " while(" not in text
     passes = _layout_passes(text, shape)
     assert bool(passes) != in_place, passes
+
+
+def test_grouped_kv_heads_reach_the_kernels_on_the_v5e(v5e):
+    """The attention op at lfm2-24b-a2b's shapes, 32 query heads of 64
+    over 8 key/value heads and 8,192 positions, on its compiled path:
+    value and gradient compile for the v5e, k and v are repeated to the
+    query's heads before the two kernels, and dk and dv come back at 8
+    heads."""
+    from mxnet_tpu.op import registry
+    op = registry.get("_contrib_DotProductAttention")
+    params = op.parse_params({"causal": True, "scale": 0.125})
+    ctx = registry.OpContext(is_train=True, platform="tpu")
+
+    def loss(q, k, v):
+        out = op.fn(params, ctx, q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def spec(heads):
+        return jax.ShapeDtypeStruct((1, 8192, heads, 64), jnp.bfloat16,
+                                    sharding=v5e)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec(32), spec(8), spec(8)).compile()
+    assert _kernels(compiled.as_text()) == ["flash_attention_fwd",
+                                            "flash_attention_bwd"]
+    assert [tuple(o.shape) for o in compiled.out_info] \
+        == [(1, 8192, 32, 64), (1, 8192, 8, 64), (1, 8192, 8, 64)]
 
 
 def test_transformer_step_feeds_the_kernels_with_no_layout_pass(v5e):
