@@ -300,11 +300,35 @@ def sigmoid_topk_route(logits, bias, top_k, scale=1.0, n_group=1,
 
 # A chunk of sorted entries meets the tokens' rows through two moves that
 # are each other's reverse mode: a gather of the chunk's rows one way, a
-# product with the chunk's one-hot (rows x tokens, built by comparison)
-# the other.  The scatter autodiff would write runs serially on the chip.
-def _sum_by_token(rows, tok, live, tokens):
+# sum of the chunk's rows by token the other.  The scatter autodiff would
+# write runs serially on the chip, so the sum is a sort by token and a
+# gather for each row a run may hold, or a product with the chunk's
+# one-hot (rows x tokens, built by comparison) where that is cheaper.
+_SUMS_SORTED = _obs.counter("moe.sum_by_token.sorted")
+_SUMS_ONEHOT = _obs.counter("moe.sum_by_token.onehot")
+
+
+def _sorted_route(rows, tokens):
+    """Whether a sum of ``rows`` sorted rows over ``tokens`` tokens takes
+    the sorted route.  The product's work grows as rows x tokens, the
+    sorted route's as tokens x the run's bound.  As the v5e read them
+    (PERF.md, section 6): alone on bf16 rows 8,192 x 8,192 x 2,048 0.96 ms
+    against 1.54 by the product, 4,096 x 4,096 x 2,048 0.44 against 0.39,
+    1,024 x 4,096 x 2,560 at 8 rows a run 1.10 against 0.22; in the step,
+    ``lfm2_24b_train`` (8,192 x 8,192) +1.0% and ``glm47flash_train``
+    (4,096 x 4,096) +0.4% with every sum sorted.  So the crossover lies
+    at or below 4,096 x 4,096 and above 1,024 x 4,096."""
+    return rows * tokens >= 4096 * 4096
+
+
+def _sum_by_token(rows, tok, live, tokens, most):
     """``rows`` (R, c) -> (tokens, c) float32: row t is the sum of the
-    rows s that are live and whose token ``tok[s]`` is t."""
+    rows s that are live and whose token ``tok[s]`` is t, of which there
+    are at most ``most``."""
+    if _sorted_route(rows.shape[0], tokens):
+        _SUMS_SORTED.inc()
+        return _sum_sorted(rows, tok, live, tokens, most)
+    _SUMS_ONEHOT.inc()
     onehot = (tok[:, None] == jnp.arange(tokens)) & live
     exact = jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
     return jax.lax.dot_general(
@@ -312,29 +336,60 @@ def _sum_by_token(rows, tok, live, tokens):
         precision=exact, preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, tok, live, tokens):
+def _sum_sorted(rows, tok, live, tokens, most):
+    """:func:`_sum_by_token` with no (R, tokens) operand: the row indices
+    sorted by token (dead rows keyed past the last), so that a token's
+    rows are one run of at most ``most`` ending where the sorted keys
+    pass it; then ``most`` gathers of one row a token, the run's last,
+    the one before it, ..., each added in float32 where it is the
+    token's.  The rows are read where they lie: nothing of R rows is
+    written in sorted order."""
+    n = rows.shape[0]
+    key = jnp.where(live.reshape(n), tok, tokens).astype(jnp.int32)
+    perm = jnp.argsort(key, stable=True)
+    key = key[perm]
+    # sorted rows keyed at most t: where token t lands in a stable sort
+    # of the keys followed by the tokens, less the t tokens before it (the
+    # landing places as the inverse permutation, a second sort: the
+    # searchsorted of jax.numpy would scatter them or loop)
+    token = jnp.arange(tokens, dtype=jnp.int32)
+    ranks = jnp.argsort(jnp.argsort(jnp.concatenate([key, token]),
+                                    stable=True))
+    end = ranks[n:] - token
+    total = jnp.zeros((tokens, rows.shape[1]), jnp.float32)
+    for j in range(1, min(most, n) + 1):
+        at = jnp.maximum(end - j, 0)
+        own = (end - j >= 0) & (key[at] == token)
+        total = total + jnp.where(own[:, None],
+                                  rows[perm[at]].astype(jnp.float32), 0.0)
+    return total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _take_rows(x, tok, live, tokens, most):
     """(tokens, c) -> (R, c): sorted row s gets the row of its token."""
     return x[tok]
 
 
 _take_rows.defvjp(
-    lambda x, tok, live, tokens: (x[tok], (tok, live)),
-    lambda tokens, res, g: (_sum_by_token(g, *res, tokens).astype(g.dtype),
-                            None, None))
+    lambda x, tok, live, tokens, most: (x[tok], (tok, live)),
+    lambda tokens, most, res, g: (
+        _sum_by_token(g, *res, tokens, most).astype(g.dtype), None, None))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sum_rows(ys, tok, live, tokens):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _sum_rows(ys, tok, live, tokens, most):
     """(R, c) -> (tokens, c) float32: a token's live rows summed."""
-    return _sum_by_token(ys, tok, live, tokens)
+    return _sum_by_token(ys, tok, live, tokens, most)
 
 
 # (an empty array carries the rows' type to the reverse mode)
 _sum_rows.defvjp(
-    lambda ys, tok, live, tokens: (_sum_by_token(ys, tok, live, tokens),
-                                   (tok, jnp.zeros((0,), ys.dtype))),
-    lambda tokens, res, g: (g.astype(res[1].dtype)[res[0]], None, None))
+    lambda ys, tok, live, tokens, most: (
+        _sum_by_token(ys, tok, live, tokens, most),
+        (tok, jnp.zeros((0,), ys.dtype))),
+    lambda tokens, most, res, g: (g.astype(res[1].dtype)[res[0]], None,
+                                  None))
 
 
 def _grouped_matmul(lhs, rhs, sizes, live):
@@ -379,15 +434,16 @@ def _held_chunk(x, weight, w_gate, w_up, w_down, ent, lo, ends):
     tok = ent // k
     # the select on xs is for the way back: what the transposes return
     # for rows past the groups must not reach the tokens' gradient
-    xs = jnp.where(live, _take_rows(x, tok, live, tokens),
+    # a token's choices are distinct experts: at most k of its rows here
+    xs = jnp.where(live, _take_rows(x, tok, live, tokens, k),
                    jnp.zeros((), x.dtype))
     h = jax.nn.silu(_grouped_matmul(xs, w_gate, sizes, live)) \
         * _grouped_matmul(xs, w_up, sizes, live)
     ys = _grouped_matmul(h.astype(x.dtype), w_down, sizes, live)
     chosen = (ent % k)[:, None] == jnp.arange(k)
-    ws = jnp.sum(jnp.where(chosen, _take_rows(weight, tok, live, tokens), 0.0),
-                 axis=1, keepdims=True)
-    return _sum_rows(ys * ws.astype(ys.dtype), tok, live, tokens)
+    ws = jnp.sum(jnp.where(chosen, _take_rows(weight, tok, live, tokens, k),
+                           0.0), axis=1, keepdims=True)
+    return _sum_rows(ys * ws.astype(ys.dtype), tok, live, tokens, k)
 
 
 def _chunk_of(order, ends, c, rows):

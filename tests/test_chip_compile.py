@@ -229,10 +229,11 @@ def test_a_segments_forward_runs_again_on_the_v5e(v5e, segments, again):
     assert _layout_passes(text, (1, 1024, 4, 128), scope="_attn_attn") == []
 
 
-# (tokens, entries a token, experts, held, d, h) of the two expert cells
+# (tokens, entries a token, experts, held, d, h) of the three expert cells
 EXPERT_SHAPES = [
     pytest.param(4096, 4, 64, 8, 2048, 1536, id="glm-4.7-flash"),
     pytest.param(4096, 8, 512, 8, 2560, 768, id="ling-3.0-flash"),
+    pytest.param(8192, 4, 64, 8, 2048, 1536, id="lfm2-24b-a2b"),
 ]
 
 
@@ -240,12 +241,14 @@ EXPERT_SHAPES = [
 def test_expert_layer_compiles_to_the_chips_grouped_kernels(
         v5e, t, k, experts, held, d, h):
     """``MoEExperts``' mathematics at the cells' widths (4,096 rows x 4
-    entries, 8 of 64 experts held; x 8 entries, 8 of 512): value and
+    entries, 8 of 64 experts held; x 8 entries, 8 of 512; 8,192 rows x 4
+    entries, 8 of 64): value and
     gradient compile for the v5e, and the three grouped products are the
     compiler's own ragged-dot kernels, which walk the live row tiles,
     not a dense product an expert.  The sorted entries are walked in
     chunks by one loop a pass, and no buffer of all of their rows by d
-    columns exists."""
+    columns exists; where the chunk's sums by token take the sorted route
+    no array of the chunk's rows by the tokens does either."""
     from mxnet_tpu.parallel import moe
 
     def loss(x, idx, w, wg, wu, wd):
@@ -266,6 +269,8 @@ def test_expert_layer_compiles_to_the_chips_grouped_kernels(
         and "[%d,%d]" % (t * k, h) not in text
     rows = moe.held_chunk_rows(t * k, held, experts)
     assert "bf16[%d,%d]" % (rows, d) in text
+    # where the chunk's sums take the sorted route, no rows x tokens array
+    assert ("[%d,%d]" % (rows, t) in text) != moe._sorted_route(rows, t)
 
 
 # (activation, policy, with its gradient): ResNet-50's stage-1 and

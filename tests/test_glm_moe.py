@@ -413,35 +413,160 @@ def test_walk_in_row_chunks_is_the_masked_dense_loop(case, ref):
         close(a, b, jnp.float32)
 
 
+# a chunk's rows summed by token: (rows, tokens, which rows): every row
+# an entry of its own, so that a token holds at most K of them
+K = 4
+SUMS = {
+    "k_rows_a_token": (24, 16, "whole"),          # tokens 0..5, 4 rows each
+    "tokens_with_none": (12, 40, "live"),         # R < T: most have none
+    "dead_rows_interleaved": (32, 16, "half"),
+    "every_row_dead": (16, 8, "dead"),
+    "more_rows_than_tokens": (56, 12, "padded"),  # R > T, 8 dead at the end
+}
+ROUTES = ["sorted", "onehot"]
+
+
+def sum_case(case):
+    """``tok`` (R,) int32 and ``live`` (R, 1) of a chunk, and its tokens."""
+    rows, tokens, which = SUMS[case]
+    rng = np.random.default_rng(60)
+    if which == "whole":
+        ent = rng.permutation(rows)
+    else:
+        ent = np.pad(rng.permutation(tokens * K)[:rows],
+                     (0, max(0, rows - tokens * K)))
+    live = {"whole": np.ones(rows, bool), "live": np.ones(rows, bool),
+            "half": rng.random(rows) < 0.5, "dead": np.zeros(rows, bool),
+            "padded": np.arange(rows) < tokens * K}[which]
+    return (jnp.asarray(ent // K, jnp.int32), jnp.asarray(live)[:, None],
+            tokens)
+
+
+def summed_by_onehot(rows, tok, live, tokens):
+    """The form the sorted sum replaced, kept as its oracle: the product
+    of the rows with the chunk's (rows x tokens) one-hot."""
+    onehot = (tok[:, None] == jnp.arange(tokens)) & live
+    exact = jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
+    return jax.lax.dot_general(
+        onehot.astype(rows.dtype), rows, (((0,), (0,)), ((), ())),
+        precision=exact, preferred_element_type=jnp.float32)
+
+
+def sums_taken():
+    c = obs.snapshot()["counters"]
+    return {r: c.get("moe.sum_by_token." + r, 0) for r in ROUTES}
+
+
+def take_route(monkeypatch, route):
+    from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(moe, "_sorted_route",
+                        lambda rows, tokens: route == "sorted")
+    return moe
+
+
+@pytest.mark.parametrize("route", ROUTES)
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("case", sorted(SUMS))
+def test_sum_by_token_is_the_onehot_product(monkeypatch, case, dtype, route):
+    """Both moves between a chunk's rows and its tokens, by either route:
+    the sum of a token's live rows (``_sum_rows``) in float32 equals the
+    one-hot product, its reverse mode hands each live row its token's
+    cotangent; the gather (``_take_rows``) reverses to that sum in the
+    rows' type.  Each sum is counted under the route it took."""
+    moe = take_route(monkeypatch, route)
+    tok, live, tokens = sum_case(case)
+    rows = rnd(61, tok.shape[0], 8, dtype=dtype)
+    before = sums_taken()
+    got, back = jax.vjp(lambda r: moe._sum_rows(r, tok, live, tokens, K),
+                        rows)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(
+        summed_by_onehot(rows, tok, live, tokens)), rtol=1e-6, atol=1e-6)
+    g = rnd(62, tokens, 8)
+    np.testing.assert_array_equal(
+        np.asarray(jnp.where(live, back(g)[0], 0).astype(jnp.float32)),
+        np.asarray(jnp.where(live, g.astype(dtype)[tok], 0)
+                   .astype(jnp.float32)))
+    x = rnd(63, tokens, 8, dtype=dtype)
+    took, back = jax.vjp(lambda x: moe._take_rows(x, tok, live, tokens, K), x)
+    np.testing.assert_array_equal(np.asarray(took, np.float32),
+                                  np.asarray(x[tok], np.float32))
+    g = rnd(64, tok.shape[0], 8, dtype=dtype)
+    (dx,) = back(g)
+    assert dx.dtype == dtype
+    close(dx, summed_by_onehot(g, tok, live, tokens), dtype)
+    if case == "every_row_dead":
+        assert not np.asarray(got).any() and not np.asarray(dx, np.float32).any()
+    after = sums_taken()
+    assert after[route] - before[route] == 2
+    assert {r: after[r] - before[r] for r in ROUTES if r != route} \
+        == {r: 0 for r in ROUTES if r != route}
+
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_sum_by_token_accumulates_in_float32(monkeypatch, route):
+    """bfloat16 rows summed in float32: 256 + 1 + 1 + 1 is 259, which no
+    bfloat16 holds (258 or 260), and 1 + 3 x 2^-9 keeps its last bits."""
+    moe = take_route(monkeypatch, route)
+    rows = jnp.array([256, 1, 1, 1, 1, 2 ** -9, 2 ** -9, 2 ** -9],
+                     jnp.bfloat16)[:, None]
+    tok = jnp.array([0, 0, 0, 0, 1, 1, 1, 1], jnp.int32)
+    got = moe._sum_by_token(rows[::-1], tok[::-1], jnp.ones((8, 1), bool),
+                            2, K)
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got)[:, 0],
+                                  [259.0, 1 + 3 * 2.0 ** -9])
+
+
+# (tokens, chunk rows, the route the rule takes for their sums)
+WALK_ROUTES = [(64, 32, "onehot"), (4096, 4096, "sorted")]
+
+
 def test_walk_builds_nothing_of_all_entries_rows_and_scatters_nothing():
-    """Value and gradient of the op, 64 x 4 entries walked 32 a trip:
-    no array of 256 rows by d = 32 or h = 24 columns, no scatter.  The
-    gather that autodiff would reverse has both, so the search sees."""
+    """Value and gradient of the op, tokens x 4 entries walked ``rows`` a
+    trip, at a shape of each route: no array of all the entries' rows by
+    d = 32 or h = 24 columns, no scatter, and on the sorted route no
+    product with an operand of rows x tokens; the route every sum took
+    is read off the counters.  The gather that autodiff would reverse
+    has the first two, and the one-hot route the third, so the search
+    sees."""
     from mxnet_tpu.analysis.jaxpr_passes import iter_eqns
     from mxnet_tpu.parallel import moe
-    x, wt = rnd(41, 64, 32), rnd(42, 64, 4)
-    idx = jax.random.randint(jax.random.key(43), (64, 4), 0, 16, jnp.int32)
     wg, wu, wd = expert_weights(jnp.float32)
+    for tokens, rows, route in WALK_ROUTES:
+        entries = tokens * 4
+        x, wt = rnd(41, tokens, 32), rnd(42, tokens, 4)
+        idx = jax.random.randint(jax.random.key(43), (tokens, 4), 0, 16,
+                                 jnp.int32)
 
-    def held(x, wt, wg, wu, wd):
-        return jnp.sum(moe.moe_apply_held(x, idx, wt, wg, wu, wd, 4, 16,
-                                          chunk_rows=32)[0] ** 2)
+        def held(x, wt, wg, wu, wd):
+            return jnp.sum(moe.moe_apply_held(
+                x, idx, wt, wg, wu, wd, 4, 16, chunk_rows=rows)[0] ** 2)
 
-    def spread(x, wt, wg, wu, wd):
-        return jnp.sum(x[jnp.argsort(idx.reshape(-1)) // 4] ** 2)
+        def spread(x, wt, wg, wu, wd):
+            return jnp.sum(x[jnp.argsort(idx.reshape(-1)) // 4] ** 2)
 
-    def found(fn):
-        eqns = list(iter_eqns(jax.make_jaxpr(jax.value_and_grad(
-            fn, argnums=(0, 1, 2, 3, 4)))(x, wt, wg, wu, wd)))
-        shapes = {tuple(v.aval.shape) for e in eqns for v in e.outvars}
-        names = {e.primitive.name for e in eqns}
-        return ({s for s in shapes if len(s) == 2 and s[0] == 256
-                 and s[1] in (32, 24)},
-                {n for n in names if "scatter" in n},
-                {"while", "ragged_dot_general"} <= names)
+        def found(fn):
+            before = sums_taken()
+            eqns = list(iter_eqns(jax.make_jaxpr(jax.value_and_grad(
+                fn, argnums=(0, 1, 2, 3, 4)))(x, wt, wg, wu, wd)))
+            after = sums_taken()
+            shapes = {tuple(v.aval.shape) for e in eqns for v in e.outvars}
+            names = {e.primitive.name for e in eqns}
+            products = {tuple(v.aval.shape) for e in eqns
+                        if e.primitive.name == "dot_general"
+                        for v in e.invars}
+            return ({s for s in shapes if len(s) == 2 and s[0] == entries
+                     and s[1] in (32, 24)},
+                    {n for n in names if "scatter" in n},
+                    {"while", "ragged_dot_general"} <= names,
+                    (rows, tokens) in products,
+                    {r for r in ROUTES if after[r] > before[r]})
 
-    assert found(spread) == ({(256, 32)}, {"scatter-add"}, False)
-    assert found(held) == (set(), set(), True)
+        assert found(spread) == ({(entries, 32)}, {"scatter-add"}, False,
+                                 False, set())
+        assert found(held) == (set(), set(), True, route == "onehot",
+                               {route})
 
 
 def test_all_absent_leaves_the_shared_experts_part(ref):
