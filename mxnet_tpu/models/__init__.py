@@ -10,7 +10,9 @@ or by latent attention (``bailing-hybrid``), and a looped language model
 whose one stack of layers runs several times on shared weights, with an
 exit gate after every pass (``loop-lm``), and a hybrid whose blocks mix
 by a gated short convolution or by grouped-query attention, with routed
-experts and no shared one (``lfm2-moe``).
+experts and no shared one (``lfm2-moe``), and one whose layers attend in
+a sliding window or over the whole sequence, gated a head, with routed
+experts and a shared one (``laguna``).
 Architectures are standard published networks, written fresh in
 mxnet_tpu Symbol idiom; the graphs compile to single XLA computations.
 
@@ -34,11 +36,12 @@ from . import glm_moe
 from . import bailing_hybrid
 from . import loop_lm
 from . import lfm2_moe
+from . import laguna
 
 __all__ = ["get_symbol", "mlp", "lenet", "alexnet", "vgg", "resnet",
            "resnext", "googlenet", "inception_bn", "inception_v3",
            "inception_resnet_v2", "lstm_lm", "transformer", "glm_moe",
-           "bailing_hybrid", "loop_lm", "lfm2_moe"]
+           "bailing_hybrid", "loop_lm", "lfm2_moe", "laguna"]
 
 _BUILDERS = {
     "mlp": mlp.get_symbol,
@@ -54,6 +57,7 @@ _BUILDERS = {
     "bailing-hybrid": bailing_hybrid.get_symbol,
     "loop-lm": loop_lm.get_symbol,
     "lfm2-moe": lfm2_moe.get_symbol,
+    "laguna": laguna.get_symbol,
 }
 
 

@@ -16,8 +16,8 @@ all heads; expanded, it is ordinary causal attention at head dimension
 operands with zero columns and cuts the result, see ``op/attention.py``).
 ``_linear``, ``_gated_ffn``, ``_expert_layer`` and ``_block`` (which
 takes its mixer) also build ``models/bailing_hybrid.py`` and
-``models/lfm2_moe.py`` (which has no shared expert), the first two
-``models/loop_lm.py``.
+``models/lfm2_moe.py`` (which has no shared expert) and
+``models/laguna.py``, the first two ``models/loop_lm.py``.
 
 The multi-token-prediction module (arXiv:2412.19437 sec. 2.2, depth 1)
 joins the trunk's last hidden state at position i with the embedding of

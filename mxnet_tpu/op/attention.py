@@ -13,7 +13,10 @@ exact.  Keys and values may carry fewer heads than queries (grouped
 key/value heads): ``h_kv`` dividing the query's ``h``, query head j
 reads key/value head j // (h / h_kv).  Neither path takes a key/value
 head count, so k and v are repeated along the head axis to ``h`` first;
-autodiff sums dk and dv over each group, which is exact.  Sequence
+autodiff sums dk and dv over each group, which is exact.  A ``window``
+(causal only) lets query t see the keys t - window < s <= t: the
+kernels visit only the tiles such a window reaches, the oracle masks
+the same pairs.  Sequence
 parallelism over a mesh is ``mx.parallel.ring_attention`` — same math,
 K/V rotated over ICI.
 """
@@ -33,6 +36,8 @@ _LANES = 128
 # bytes of k and v their repetition to the query's heads writes
 _GROUPED = _obs.counter("attention.grouped_kv.nodes")
 _REPEAT_BYTES = _obs.counter("attention.grouped_kv.repeat_bytes")
+# nodes traced under a causal window
+_WINDOWED = _obs.counter("attention.window.nodes")
 
 
 def _kv_groups(q_shape, k_shape, v_shape):
@@ -49,6 +54,11 @@ def _kv_groups(q_shape, k_shape, v_shape):
     return h // h_kv
 
 
+def _blocks(p):
+    """The blocks a node asks of the kernels; none: the kernels' own."""
+    return {name: p[name] for name in ("block_q", "block_k") if p[name]}
+
+
 def _attention_infer_shape(p, in_shapes):
     # the query's shape with the value's last dimension: no walk of a
     # Symbol's shapes has to trace the kernel to learn it
@@ -56,6 +66,11 @@ def _attention_infer_shape(p, in_shapes):
         return None
     q, k, v = in_shapes
     _kv_groups(q, k, v)
+    if p["window"] > 0 and p["causal"] and p["flash"]:
+        # the live share a traced node leaves, left here as well: a
+        # program loaded from the program cache is never traced
+        from .pallas.flash_attention import note_window
+        note_window(q[1], k[1], p["window"], **_blocks(p))
     return ([tuple(s) for s in in_shapes],
             [tuple(q[:-1]) + (v[-1],)], [])
 
@@ -68,15 +83,24 @@ def _attention_infer_shape(p, in_shapes):
                        # default 0 = inherit the kernel's tuned blocks
                        # (512x512, measured 2-3x over 128x128 at 8k+)
                        Param("block_q", int, 0),
-                       Param("block_k", int, 0)),
+                       Param("block_k", int, 0),
+                       # 0: every earlier key; else the last `window`
+                       Param("window", int, 0)),
           hint="dotproductattention",
           infer_shape=_attention_infer_shape)
 def _dot_product_attention(p, c, q, k, v):
     """query [b, t, h, d], key [b, t_kv, h_kv, d], value [b, t_kv,
     h_kv, d_v] -> [b, t, h, d_v]; ``h_kv`` divides ``h`` and query head
-    j reads key/value head j // (h / h_kv)."""
+    j reads key/value head j // (h / h_kv); a ``window`` > 0 (causal
+    only) keeps the keys t - window < s <= t of query t."""
     scale = None if p["scale"] <= 0 else p["scale"]
     groups = _kv_groups(q.shape, k.shape, v.shape)
+    window = p["window"]
+    if window < 0 or (window and not p["causal"]):
+        raise MXNetError("_contrib_DotProductAttention: a window (%d) is "
+                         "causal and positive" % window)
+    if window:
+        _WINDOWED.inc()
     if groups > 1:
         _GROUPED.inc()
         _REPEAT_BYTES.inc(groups * (k.size * k.dtype.itemsize
@@ -86,11 +110,6 @@ def _dot_product_attention(p, c, q, k, v):
         from .pallas import flash_attention
         plat = c.platform or jax.default_backend()
         interpret = plat != "tpu"
-        kw = {}
-        if p["block_q"]:
-            kw["block_q"] = p["block_q"]
-        if p["block_k"]:
-            kw["block_k"] = p["block_k"]
         d_qk, d_v = q.shape[-1], v.shape[-1]
         if d_qk != d_v:
             # the kernels take one head dimension: zero columns up to
@@ -101,7 +120,9 @@ def _dot_product_attention(p, c, q, k, v):
                        for x in (q, k, v))
             scale = scale or d_qk ** -0.5
         out = flash_attention(q, k, v, causal=p["causal"], scale=scale,
-                              interpret=interpret, **kw)
+                              interpret=interpret, window=window,
+                              **_blocks(p))
         return out if d_qk == d_v else out[..., :d_v]
     from ..parallel.ring_attention import attention_reference
-    return attention_reference(q, k, v, causal=p["causal"], scale=scale)
+    return attention_reference(q, k, v, causal=p["causal"], scale=scale,
+                               window=window)

@@ -17,6 +17,7 @@ TPU-first choices:
 from __future__ import annotations
 
 from functools import partial
+import math
 
 import numpy as np
 
@@ -384,27 +385,74 @@ def _rms_norm(p, c, data, gamma):
     return out.astype(data.dtype)
 
 
+def _yarn_inv_freq(base, n, factor, original, beta_fast, beta_slow):
+    """YaRN's inverse frequencies over ``n`` rotated dims (arXiv:2309.00071
+    sec. 3.2, as transformers' ``_compute_yarn_parameters`` computes
+    them): the dims that turn fewer than ``beta_slow`` times over the
+    ``original`` context are interpolated (divided by ``factor``), those
+    that turn more than ``beta_fast`` times keep their frequency, and a
+    linear ramp blends the dims between, its ends floored and ceiled."""
+    def dim_of(turns):
+        return n * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), n - 1)
+    if low == high:
+        high += 0.001
+    extrapolated = 1.0 / base ** (jnp.arange(0, n, 2, dtype=jnp.float32) / n)
+    ramp = jnp.clip((jnp.arange(n // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extrapolated / factor * ramp + extrapolated * (1.0 - ramp)
+
+
 @register("RotaryEmbedding",
           params_spec=(Param("base", float, 10000.0),
                        Param("offset", int, 0),
                        Param("dim", int, 0),
-                       Param("interleaved", bool, False)),
+                       Param("interleaved", bool, False),
+                       Param("rope_type", str, "default",
+                             enum=("default", "yarn")),
+                       Param("factor", float, 1.0),
+                       Param("original_max_position", int, 0),
+                       Param("beta_fast", float, 32.0),
+                       Param("beta_slow", float, 1.0),
+                       # yarn's; 0: 0.1 ln(factor) + 1
+                       Param("attention_factor", float, 0.0)),
           hint="rotaryembedding")
 def _rotary_embedding(p, c, data):
-    """Rotary position embedding on a slice of the head dimension.
+    """Rotary position embedding on a slice of the head dimension; with
+    ``rope_type`` ``yarn`` YaRN's frequencies (arXiv:2309.00071), cos
+    and sin times ``attention_factor``.
 
     ``data`` (batch, time, heads, head_dim); position t is the index
     along axis 1.  Dims ``offset .. offset + dim`` of the last axis
     (``dim`` 0: to the end) are rotated by the angle t * base**(-2i/dim)
     in pairs: dim i with dim i + dim/2 (rotate-half), or with
     ``interleaved`` dim 2i with dim 2i + 1; the other dims pass through.
+    ``rope_type`` ``yarn`` takes YaRN's frequencies over the rotated dims
+    (``factor``, ``original_max_position``, ``beta_fast``,
+    ``beta_slow``: :func:`_yarn_inv_freq`) and scales cos and sin by
+    ``attention_factor``; the dims passed through stay as they are.
     Angles and the rotation in float32."""
     lo = p["offset"]
     n = p["dim"] or data.shape[-1] - lo
     half = n // 2
-    inv_freq = p["base"] ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / n)
+    if p["rope_type"] == "yarn":
+        if p["original_max_position"] <= 0 or p["factor"] <= 0:
+            raise MXNetError("RotaryEmbedding: yarn needs a positive factor "
+                             "and original_max_position")
+        inv_freq = _yarn_inv_freq(p["base"], n, p["factor"],
+                                  p["original_max_position"],
+                                  p["beta_fast"], p["beta_slow"])
+        mscale = p["attention_factor"] or 0.1 * math.log(p["factor"]) + 1.0
+    else:
+        inv_freq = p["base"] ** (-jnp.arange(half, dtype=jnp.float32)
+                                 * 2.0 / n)
+        mscale = 1.0
     ang = jnp.arange(data.shape[1], dtype=jnp.float32)[:, None] * inv_freq
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x = data[..., lo:lo + n].astype(jnp.float32)
     if p["interleaved"]:
         # a pair's partner by two rolls and a select, which keep the

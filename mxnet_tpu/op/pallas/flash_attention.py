@@ -58,6 +58,19 @@ shape).  One fused backward kernel beat a dK/dV kernel plus a dQ kernel (seven
 products and every elementwise pass twice), 1.33 against 1.82 ms and
 3.13 against 4.46 ms (PERF.md §6, PR 31).
 
+Under a causal window (``window``: query t sees the keys
+t - window < s <= t) both grids step over the blocks of the other side
+that one block's window reaches, from the first live one, and never the
+whole sequence: the forward's k-steps start at a q-block's first live
+k-block, the backward's q-steps at a k-block's diagonal, and a q-block's
+dq is whole, and written, at its own diagonal k-block.  Tiles outside
+the window are neither computed nor fetched.  On a v5e, bf16, 64 heads
+of 128 over 8,192 positions in a window of 512, forward and backward:
+12.09 ms at blocks 512 x 512, 16.61 at 256 x 256, 29.82 at 128 x 128,
+13.99 and 15.44 at 512 x 256 and 256 x 512, against 37.54 for the causal
+kernels over the whole sequence; the default blocks stand, though half
+of each 512 x 512 tile they visit is dead (``window_live_share``).
+
 The 2017-era reference has no attention op at all (SURVEY.md §5
 long-context); this is greenfield capability required for parity with
 modern workloads.  Layout convention matches ``parallel.ring_attention``:
@@ -84,6 +97,10 @@ _LANES = 128  # VPU lane width: a block's last dimension is a multiple
 # lie, or a fold and a pad of them
 _IN_PLACE = _obs.counter("attention.flash.in_place")
 _FOLDED = _obs.counter("attention.flash.folded")
+# of the last node traced, or whose shapes were inferred, under a
+# window: its live (query, key) pairs over all the pairs of the tiles
+# the forward kernel visits
+_LIVE_SHARE = _obs.gauge("attention.window.live_share")
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -104,11 +121,13 @@ def _only(hd, x, heads, d):
 
 def _live(j, kb, block_q, block_k, causal):
     """Whether the causal mask leaves the tile of q-block ``j`` and
-    k-block ``kb`` a single score."""
+    k-block ``kb`` a single score.  (Under a window the grid visits no
+    k-block before a q-block's first live one, so this bound is the
+    only one left to test.)"""
     return (kb * block_k <= j * block_q + block_q - 1) if causal else True
 
 
-def _tile_mask(j, kb, block_q, block_k, causal, t_kv_real):
+def _tile_mask(j, kb, block_q, block_k, causal, t_kv_real, window=0):
     """[block_k, block_q]: the scores of the tile that live."""
     k_pos = kb * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_k, block_q), 0)
@@ -117,22 +136,78 @@ def _tile_mask(j, kb, block_q, block_k, causal, t_kv_real):
         q_pos = j * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_k, block_q), 1)
         mask = jnp.logical_and(mask, q_pos >= k_pos)
+        if window:
+            mask = jnp.logical_and(mask, k_pos > q_pos - window)
     return mask
+
+
+# Under a causal window a query at t sees the keys t - window < s <= t.
+# The kernels' grids then step over the blocks that one block of the
+# other side can reach, from the first live one, and never the whole
+# sequence; these give the first and last live block either way.
+def _first_kb(j, block_q, block_k, window):
+    """The first k-block a window lets q-block ``j`` see."""
+    return jnp.maximum(j * block_q - window + 1, 0) // block_k
+
+
+def _last_kb(j, block_q, block_k, n_kb):
+    """The last k-block q-block ``j`` sees (the causal diagonal)."""
+    return jnp.minimum(((j + 1) * block_q - 1) // block_k, n_kb - 1)
+
+
+def _first_qb(kb, block_q, block_k):
+    """The first q-block that sees k-block ``kb`` (the causal diagonal)."""
+    return kb * block_k // block_q
+
+
+def _last_qb(kb, block_q, block_k, window, n_qb):
+    """The last q-block a window lets see k-block ``kb``."""
+    return jnp.minimum(((kb + 1) * block_k + window - 2) // block_q,
+                       n_qb - 1)
+
+
+def _k_blocks_seen(t_q, t_kv, block_q, block_k, window):
+    """For each q-block, how many k-blocks its window reaches."""
+    return [min((j + 1) * block_q - 1, t_kv - 1) // block_k
+            - max(j * block_q - window + 1, 0) // block_k + 1
+            for j in range(-(-t_q // block_q))]
+
+
+def _window_steps(t_q, t_kv, block_q, block_k, window):
+    """(forward's k-steps, backward's q-steps): the most blocks of the
+    other side one block's window reaches, counted over the blocks,
+    within ``ceil((window + block - 1) / other block) + 1``."""
+    n_qb, n_kb = -(-t_q // block_q), -(-t_kv // block_k)
+    bwd = max(min(((kb + 1) * block_k + window - 2) // block_q, n_qb - 1)
+              - kb * block_k // block_q + 1 for kb in range(n_kb))
+    return max(_k_blocks_seen(t_q, t_kv, block_q, block_k, window)), bwd
+
+
+def window_live_share(t, window, block_q, block_k):
+    """The live (query, key) pairs of a causal window over ``t``
+    positions, over all the pairs of the tiles the forward kernel
+    visits: 1.0 where no tile work is wasted."""
+    tiles = sum(_k_blocks_seen(t, t, block_q, block_k, window))
+    w = min(window, t)
+    live = t * w - w * (w - 1) // 2
+    return live / float(tiles * block_q * block_k)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, block_q, block_k, causal, masked, scale, t_kv_real,
-                heads, d):
+                heads, d, window=0):
     # Grid is (batch, groups of heads, n_qb, n_kb), keys innermost: K/V
     # stream through VMEM a [block_k, g*d] tile a step while the online
     # softmax's state carries in scratch.  The tile is [bk, bq], as in
     # the backward: the running maximum and denominator are lane-dense
     # rows [1, bq], a head a row, that broadcast over sublanes, and the
-    # reductions over keys run down the sublanes.
-    j, kb = pl.program_id(2), pl.program_id(3)
-    n_kb = pl.num_programs(3)
+    # reductions over keys run down the sublanes.  Under a window the
+    # k-steps start at the q-block's first live k-block.
+    j, step = pl.program_id(2), pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    kb = step + _first_kb(j, block_q, block_k, window) if window else step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -146,7 +221,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # the P.V product only (it is in [0, 1]: 2^-9 relative in bf16).
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
         if masked:
-            mask = _tile_mask(j, kb, block_q, block_k, causal, t_kv_real)
+            mask = _tile_mask(j, kb, block_q, block_k, causal, t_kv_real,
+                              window)
         for hd in range(heads):
             row = slice(hd, hd + 1)
             band = slice(hd * d, (hd + 1) * d)
@@ -171,7 +247,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 preferred_element_type=jnp.float32)
             acc_ref[band, :] = acc_ref[band, :] * corr + pv_t[band, :]
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(step == n_steps - 1)
     def _finalize():
         m, l = m_ref[...], l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -186,22 +262,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, delta_acc, *,
                 block_q, block_k, causal, masked, scale, t_kv_real,
-                heads, d):
-    # Grid is (batch, groups of heads, n_kb, n_qb), queries innermost:
+                heads, d, window=0):
+    # Grid is (batch, groups of heads, n_kb, q-steps), queries innermost:
     # one K/V tile stays in VMEM while the Q/dO tiles stream past it,
     # dk/dv accumulate in scratch over the q-blocks, dq in a scratch row
-    # per q-block that lives across the k-blocks of this group.
-    kb, j = pl.program_id(2), pl.program_id(3)
-    n_kb, n_qb = pl.num_programs(2), pl.num_programs(3)
+    # per q-block that lives across the k-blocks of this group.  With no
+    # window the q-steps are the q-blocks; under one they start at the
+    # k-block's diagonal and a q-block's dq is whole at its own.
+    kb, step = pl.program_id(2), pl.program_id(3)
+    n_kb, n_steps = pl.num_programs(2), pl.num_programs(3)
+    if window:
+        n_qb = dq_acc.shape[0]
+        j = _first_qb(kb, block_q, block_k) + step
+        live = j <= _last_qb(kb, block_q, block_k, window, n_qb)
+        first = jnp.logical_and(live,
+                                kb == _first_kb(j, block_q, block_k, window))
+        last = jnp.logical_and(live,
+                               kb == _last_kb(j, block_q, block_k, n_kb))
+    else:
+        j = step
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init_dkv():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(kb == 0)
+    @pl.when(first if window else kb == 0)
     def _first_pass():
-        # the group's first pass over the q-blocks (every one is live
+        # the q-block's first pass (with no window every one is live
         # against the first keys): dq starts, and delta = sum(dO * O)
         # over a head's lanes is taken as the row it is used as
         dq_acc[j] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
@@ -211,14 +299,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             delta_acc[j, hd:hd + 1, :] = jnp.sum(
                 prod_t[hd * d:(hd + 1) * d, :], axis=0, keepdims=True)
 
-    @pl.when(_live(j, kb, block_q, block_k, causal))
+    @pl.when(live if window else _live(j, kb, block_q, block_k, causal))
     def _update():
         # same contract as the forward: MXU operands in the storage
         # dtype, float32 accumulation, the scale, the exponent, delta
         # and the ds combination in float32.  The tile is [bk, bq].
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         if masked:
-            mask = _tile_mask(j, kb, block_q, block_k, causal, t_kv_real)
+            mask = _tile_mask(j, kb, block_q, block_k, causal, t_kv_real,
+                              window)
         for hd in range(heads):
             q_h, k_h, do_h = (_only(hd, x, heads, d) for x in (q, k, do))
             s_t = jax.lax.dot_general(
@@ -237,12 +326,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             dq_acc[j] += jax.lax.dot_general(
                 ds_t, k_h, _TN, preferred_element_type=jnp.float32)
 
-    @pl.when(j == n_qb - 1)
+    @pl.when(step == n_steps - 1)
     def _finalize_dkv():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(last if window else kb == n_kb - 1)
     def _finalize_dq():
         dq_ref[0] = (dq_acc[j] * scale).astype(dq_ref.dtype)
 
@@ -279,7 +368,7 @@ def _compiler_params(interpret, semantics, vmem):
 
 
 def _fwd_call(q, k, v, causal, scale, block_q, block_k, g, in_place,
-              interpret):
+              interpret, window=0):
     """q/k/v: [b, t, h, d] -> (o [b, t_q, h, d], lse [b*h/g, g, t_q_pad])."""
     d = q.shape[3]
     t_kv = k.shape[1]
@@ -291,10 +380,16 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, g, in_place,
     gd = g * d
     n_hg = width // gd          # groups along the lanes: 1 when folded
     n_qb, n_kb = t_qp // block_q, t_kvp // block_k
+    n_steps = _window_steps(t_qp, t_kv, block_q, block_k, window)[0] \
+        if window else n_kb
 
     def k_blk(j, kb):
         # a dead tile (keys after this q-block) asks for the block the
-        # last live one did, so nothing is fetched for it
+        # last live one did, so nothing is fetched for it; under a
+        # window the steps start at the first live block
+        if window:
+            return jnp.minimum(kb + _first_kb(j, block_q, block_k, window),
+                               _last_kb(j, block_q, block_k, n_kb))
         if causal:
             kb = jnp.minimum(kb, ((j + 1) * block_q - 1) // block_k)
         return kb
@@ -307,14 +402,14 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, g, in_place,
     kernel = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
         masked=causal or t_kvp != t_kv, scale=scale, t_kv_real=t_kv,
-        heads=g, d=d)
+        heads=g, d=d, window=window)
     lanes = -(-gd // _LANES) * _LANES
     vmem = (4 * block_q * lanes                                  # acc
             + 4 * (2 * block_q + 2 * block_k) * lanes * q.dtype.itemsize
             + 6 * 4 * block_q * block_k)              # the tile's values
     o, lse = pl.pallas_call(
         kernel,
-        grid=(n, n_hg, n_qb, n_kb),
+        grid=(n, n_hg, n_qb, n_steps),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[
@@ -336,7 +431,7 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, g, in_place,
 
 
 def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, g,
-              in_place, interpret):
+              in_place, interpret, window=0):
     """q/k/v/o/do: [b, t, h, d], lse: [b*h/g, g, t_q_pad] -> (dq, dk, dv)."""
     d = q.shape[3]
     t_kv = k.shape[1]
@@ -350,10 +445,17 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, g,
     gd = g * d
     n_hg = width // gd
     n_qb, n_kb = t_qp // block_q, t_kvp // block_k
+    n_steps = _window_steps(t_qp, t_kv, block_q, block_k, window)[1] \
+        if window else n_qb
 
     def q_blk(kb, j):
         # a dead tile (queries before this k-block) asks for the block the
-        # first live one will, so nothing is fetched for it
+        # first live one will, so nothing is fetched for it; under a
+        # window the steps start at the diagonal and a dead one after
+        # the last live block asks for that block again
+        if window:
+            return jnp.minimum(_first_qb(kb, block_q, block_k) + j,
+                               _last_qb(kb, block_q, block_k, window, n_qb))
         if causal:
             j = jnp.minimum(jnp.maximum(j, kb * block_k // block_q),
                             n_qb - 1)
@@ -365,6 +467,16 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, g,
     o_spec = pl.BlockSpec(
         (1, block_q, gd),
         lambda i, hg, kb, j: (i, jnp.where(kb == 0, j, n_qb - 1), hg))
+    if window:
+        # a q-block's first pass is at the first k-block that reaches
+        # it; before it the block the last pass read is asked again
+        def o_blk(kb, j):
+            return jnp.where(
+                kb == 0, q_blk(kb, j),
+                jnp.maximum(q_blk(kb, j), _last_qb(kb - 1, block_q, block_k,
+                                                   window, n_qb)))
+        o_spec = pl.BlockSpec((1, block_q, gd),
+                              lambda i, hg, kb, j: (i, o_blk(kb, j), hg))
     row_spec = pl.BlockSpec(
         (1, g, block_q),
         lambda i, hg, kb, j: (i * n_hg + hg, 0, q_blk(kb, j)))
@@ -374,10 +486,21 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, g,
     dq_spec = pl.BlockSpec(
         (1, block_q, gd),
         lambda i, hg, kb, j: (i, jnp.where(kb == n_kb - 1, j, 0), hg))
+    if window:
+        # a q-block's dq is whole at its diagonal k-block: the block
+        # asked is the q-block being finished there, or else the last
+        # one finished, whose values the buffer still holds
+        def dq_blk(kb, j):
+            done = jnp.where(kb == n_kb - 1, n_qb - 1,
+                             jnp.clip((kb + 1) * block_k // block_q - 1,
+                                      0, n_qb - 1))
+            return jnp.minimum(q_blk(kb, j), done)
+        dq_spec = pl.BlockSpec((1, block_q, gd),
+                               lambda i, hg, kb, j: (i, dq_blk(kb, j), hg))
     kernel = functools.partial(
         _bwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
         masked=causal or t_kvp != t_kv, scale=scale, t_kv_real=t_kv,
-        heads=g, d=d)
+        heads=g, d=d, window=window)
     lanes = -(-gd // _LANES) * _LANES
     vmem = (4 * t_qp * lanes                          # dq, resident
             + 2 * 4 * block_k * lanes                 # dk, dv
@@ -385,7 +508,7 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, g,
             + 6 * 4 * block_q * block_k)              # the tile's values
     dq, dk, dv = pl.pallas_call(
         kernel,
-        grid=(n, n_hg, n_kb, n_qb),
+        grid=(n, n_hg, n_kb, n_steps),
         in_specs=[q_spec, kv_spec, kv_spec, o_spec, q_spec, row_spec],
         out_specs=[dq_spec, kv_spec, kv_spec],
         out_shape=[
@@ -410,44 +533,47 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, g,
             _unlay(dv, v.shape, g, in_place))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 10)))
-def _flash(q, k, v, causal, scale, block_q, block_k, g, in_place, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
+def _flash(q, k, v, causal, scale, block_q, block_k, g, in_place, interpret,
+           window):
     return _fwd_call(q, k, v, causal, scale, block_q, block_k, g, in_place,
-                     interpret)[0]
+                     interpret, window)[0]
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, g, in_place,
-               interpret):
+               interpret, window):
     o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, g,
-                       in_place, interpret)
+                       in_place, interpret, window)
     # what lives between the passes is q, k, v and o as the graph has
     # them, [b, t, h, d], and lse [b*h/g, g, t] float32
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, g, in_place, interpret, res,
-               do):
+def _flash_bwd(causal, scale, block_q, block_k, g, in_place, interpret,
+               window, res, do):
     q, k, v, o, lse = res
     return _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
-                     g, in_place, interpret)
+                     g, in_place, interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _clamp(block, t):
+    # clamp to the sequence but keep the block LANE-ALIGNED: a raw
+    # min(block, t) for 128 < t < block would hand Mosaic a
+    # non-tile-multiple block shape (t=300 -> (300, d) blocks);
+    # rounding t up to a 128 multiple keeps one aligned block and
+    # the pad in time makes the array match
+    return min(block, -(-max(t, 1) // _LANES) * _LANES)
 
 
 def _plan(t_q, t_kv, h, d, block_q, block_k):
     """(block_q, block_k, g, in_place) for these shapes: the blocks
     clamped to the sequences, how many heads go side by side, and
     whether the kernels can read the graph's arrays where they lie."""
-    def clamp(block, t):
-        # clamp to the sequence but keep the block LANE-ALIGNED: a raw
-        # min(block, t) for 128 < t < block would hand Mosaic a
-        # non-tile-multiple block shape (t=300 -> (300, d) blocks);
-        # rounding t up to a 128 multiple keeps one aligned block and
-        # the pad in time makes the array match
-        return min(block, -(-max(t, 1) // _LANES) * _LANES)
-    block_q = clamp(block_q, t_q)
-    block_k = clamp(block_k, t_kv)
+    block_q = _clamp(block_q, t_q)
+    block_k = _clamp(block_k, t_kv)
     # as many neighbouring heads a grid step as fit the lanes
     g = max(n for n in range(1, h + 1)
             if h % n == 0 and (n == 1 or n * d <= _LANES))
@@ -459,28 +585,47 @@ def _plan(t_q, t_kv, h, d, block_q, block_k):
 
 
 def flash_attention(q, k, v, causal=False, scale=None,
-                    block_q=512, block_k=512, interpret=None):
+                    block_q=512, block_k=512, interpret=None, window=0):
     """Memory-efficient exact attention.
 
     Args: ``q`` [b, t_q, h, d], ``k``/``v`` [b, t_kv, h, d] (the
     ``ring_attention`` layout).  Returns [b, t_q, h, d] in ``q.dtype``.
+    ``window`` > 0 (with ``causal``) lets query t see the keys
+    t - window < s <= t alone; the grids then visit only the tiles such
+    a window reaches.
 
     ``interpret=None`` auto-selects: compiled Pallas on TPU, interpreter
     elsewhere (bit-accurate, used by the CPU test mesh).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if window < 0 or (window and not causal):
+        raise ValueError("flash_attention: a window (%r) is causal and "
+                         "positive" % (window,))
     _, t_q, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     block_q, block_k, g, in_place = _plan(t_q, k.shape[1], h, d,
                                           block_q, block_k)
     (_IN_PLACE if in_place else _FOLDED).inc()
+    if window:
+        note_window(t_q, k.shape[1], window, block_q, block_k)
     return _flash(q, k, v, causal, float(scale), block_q, block_k, g,
-                  in_place, interpret)
+                  in_place, interpret, int(window))
 
 
-def flash_attention_reference(q, k, v, causal=False, scale=None):
+def note_window(t_q, t_kv, window, block_q=512, block_k=512):
+    """Leave in the gauge ``attention.window.live_share`` the live share
+    of the tiles the kernels visit for a window over ``t_q`` queries at
+    these blocks, clamped as the kernels clamp them.  A traced node sets
+    it, and so does the op's shape inference: a program loaded from the
+    program cache is never traced."""
+    _LIVE_SHARE.set(window_live_share(t_q, window, _clamp(block_q, t_q),
+                                      _clamp(block_k, t_kv)))
+
+
+def flash_attention_reference(q, k, v, causal=False, scale=None, window=0):
     """O(T^2) jnp oracle (same layout), for tests and tiny shapes."""
     from ...parallel.ring_attention import attention_reference
-    return attention_reference(q, k, v, causal=causal, scale=scale)
+    return attention_reference(q, k, v, causal=causal, scale=scale,
+                               window=window)

@@ -131,8 +131,9 @@ def ring_attention_sharded(q, k, v, mesh, axis="seq", causal=False,
     return fn(q, k, v)
 
 
-def attention_reference(q, k, v, causal=False, scale=None):
-    """Single-device exact attention (correctness oracle for the ring)."""
+def attention_reference(q, k, v, causal=False, scale=None, window=0):
+    """Single-device exact attention (correctness oracle for the ring).
+    ``window`` > 0 (causal) keeps the keys t - window < s <= t."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -141,6 +142,9 @@ def attention_reference(q, k, v, causal=False, scale=None):
     if causal:
         t_q, t_k = s.shape[-2], s.shape[-1]
         mask = jnp.arange(t_q)[:, None] >= jnp.arange(t_k)[None, :]
+        if window:
+            mask = mask & (jnp.arange(t_k)[None, :]
+                           > jnp.arange(t_q)[:, None] - window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bhqd", p, v.astype(jnp.float32))

@@ -60,6 +60,8 @@ SHAPES = [
                  id="ouro-2.6b"),
     pytest.param((1, 8192, 32, 64), jnp.bfloat16, True, True,
                  id="lfm2-24b-a2b"),
+    pytest.param((1, 8192, 48, 128), jnp.bfloat16, True, True,
+                 id="laguna-xs.2"),
 ]
 
 
@@ -157,6 +159,39 @@ def test_grouped_kv_heads_reach_the_kernels_on_the_v5e(v5e):
                                             "flash_attention_bwd"]
     assert [tuple(o.shape) for o in compiled.out_info] \
         == [(1, 8192, 32, 64), (1, 8192, 8, 64), (1, 8192, 8, 64)]
+
+
+@pytest.mark.parametrize("heads,window", [(48, 0), (64, 512)],
+                         ids=["full", "window"])
+def test_laguna_attention_nodes_compile_for_v5e(v5e, heads, window):
+    """The attention op at laguna-xs.2's shapes on its compiled path: 48
+    (full) or 64 (window of 512) query heads of 128 over 8 key/value
+    heads and 8,192 positions.  Value and gradient compile for the v5e
+    to the two kernels and no loop, and the window's grids step over the
+    2 blocks a 512 x 512 tile's window reaches, not the 16 of the
+    sequence."""
+    from mxnet_tpu.op import registry
+    op = registry.get("_contrib_DotProductAttention")
+    params = op.parse_params({"causal": True, "scale": 128 ** -0.5,
+                              "window": window})
+    ctx = registry.OpContext(is_train=True, platform="tpu")
+
+    def loss(q, k, v):
+        out = op.fn(params, ctx, q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def spec(h):
+        return jax.ShapeDtypeStruct((1, 8192, h, 128), jnp.bfloat16,
+                                    sharding=v5e)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    text = grad.lower(spec(heads), spec(8), spec(8)).compile().as_text()
+    assert _kernels(text) == ["flash_attention_fwd", "flash_attention_bwd"]
+    assert " while(" not in text
+    jaxpr = str(jax.make_jaxpr(grad)(spec(heads), spec(8), spec(8)))
+    steps = 2 if window else 16
+    assert re.findall(r"grid=\(([\d, ]+)\)", jaxpr) \
+        == ["1, %d, 16, %d" % (heads, steps)] * 2
 
 
 def test_transformer_step_feeds_the_kernels_with_no_layout_pass(v5e):
